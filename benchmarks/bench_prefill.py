@@ -56,9 +56,8 @@ def _measure(model, params, policy, mode: str, iters: int):
                                             prefill_mode=mode))
     compiled = fn.lower(params, batch).compile()
     peak = _peak_bytes(compiled)
-    # time the AOT executable directly — on jax 0.4.x the lowered/compiled
-    # program never enters the jit dispatch cache, so calling fn() here
-    # would silently recompile the whole 4k-token prefill
+    # time the AOT executable directly: calling fn() here could compile
+    # the whole 4k-token prefill again through jit's own dispatch path
     jax.block_until_ready(compiled(params, batch))
     ts = []
     for _ in range(iters):

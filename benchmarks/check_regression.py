@@ -44,9 +44,7 @@ Refresh the baseline after an intentional change with::
 counts stay exact) so a baseline measured on a fast dev machine does not
 false-fail on slower CI runners.  The committed baseline keeps the absolute
 floors aggressively derated (~0.4) as a catastrophic-collapse backstop; the
-``*_over_*`` ratio rows are the sensitive, machine-independent guard, and
-the smoke-bench CI job installs the ``jax04`` pin so runs compare like with
-like.
+``*_over_*`` ratio rows are the sensitive, machine-independent guard.
 
 Exit status: 0 clean, 1 on any regression (CI fails the step).
 """

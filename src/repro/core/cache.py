@@ -55,6 +55,7 @@ from repro.core import gear as gear_lib
 from repro.core import lowrank as lr_lib
 from repro.core import outlier as ol_lib
 from repro.core import packing
+from repro.core import quant as q_lib
 from repro.core.policy import CompressionPolicy
 
 __all__ = [
@@ -323,8 +324,11 @@ def _compress_chunks_fused(cfg: CacheConfig, k: jnp.ndarray, v: jnp.ndarray,
             force_kernel=force, interpret=force)
         lead = (B, H, C)
         out[f"{name}_packed"] = packed.reshape(lead + packed.shape[1:])
-        out[f"{name}_scale"] = scale.reshape(lead + scale.shape[1:]).astype(jnp.bfloat16)
-        out[f"{name}_zero"] = zero.reshape(lead + zero.shape[1:]).astype(jnp.bfloat16)
+        # rounded as quant.quantize rounds them, so readers in this program
+        # see the stored stats (see quant.round_to_dtype)
+        for field, stat in (("scale", scale), ("zero", zero)):
+            out[f"{name}_{field}"] = q_lib.round_to_dtype(
+                stat.reshape(lead + stat.shape[1:]), jnp.bfloat16).astype(jnp.bfloat16)
         if pol.use_lowrank:
             a, b = lr_lib.power_iteration(resid.reshape(lead + (nb, Dh)), rank,
                                           pol.power_iters, key)

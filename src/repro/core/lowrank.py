@@ -34,7 +34,7 @@ def _mgs_q(x: jnp.ndarray) -> jnp.ndarray:
 
     Slightly weaker numerically than LAPACK QR, but free of custom calls:
     XLA's SPMD partitioner cannot handle LAPACK custom calls inside a
-    partially-manual ``shard_map`` region (jaxlib 0.4.x aborts with
+    partially-manual ``shard_map`` region (it aborts with
     ``IsManualSubgroup`` check failures), so the gradient compressor uses
     this path.  Ranks are small (<= 16); the unrolled loop is cheap.
     """

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["SparseOutliers", "outlier_count", "filter_outliers",
-           "filter_outliers_k", "densify", "iterative_topk"]
+           "filter_outliers_k", "densify"]
 
 
 @functools.partial(
@@ -72,31 +72,6 @@ def _scatter_last(shape, idx: jnp.ndarray, vals: jnp.ndarray, dtype) -> jnp.ndar
     out = jnp.zeros((flat_rows, length), dtype=dtype)
     out = out.at[rows, fidx].set(fval)
     return out.reshape(shape)
-
-
-def iterative_topk(x: jnp.ndarray, k: int, axis: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Top-``k`` of ``x`` along ``axis`` via ``k`` masked max sweeps.
-
-    Returns (values, indices) with the reduced axis removed and ``k``
-    appended last, in :func:`jax.lax.top_k` order (values descending, ties
-    broken by lower index).  Built from vectorized max / compare-iota ops
-    only, so the same routine runs inside Pallas TPU kernels (no gather or
-    sort hardware needed) — the kernel-side twin of the ``lax.top_k`` call
-    in :func:`filter_outliers_k`.
-    """
-    axis = axis % x.ndim
-    work = x.astype(jnp.float32)
-    n = x.shape[axis]
-    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
-    vals, idxs = [], []
-    for _ in range(k):
-        v = jnp.max(work, axis=axis)
-        ve = jnp.expand_dims(v, axis)
-        i = jnp.min(jnp.where(work == ve, iota, n), axis=axis)
-        vals.append(v)
-        idxs.append(i)
-        work = jnp.where(iota == jnp.expand_dims(i, axis), -3.4e38, work)
-    return jnp.stack(vals, axis=-1), jnp.stack(idxs, axis=-1)
 
 
 def filter_outliers(x: jnp.ndarray, s: float, axis: str) -> tuple[SparseOutliers, jnp.ndarray]:

@@ -32,6 +32,7 @@ __all__ = [
     "QuantizedTensor",
     "quantize",
     "dequantize",
+    "round_to_dtype",
     "quant_error",
     "SCHEMES",
 ]
@@ -71,6 +72,26 @@ class QuantizedTensor:
     def size_bytes(self) -> int:
         """Total compressed bytes (codes + scales + zeros)."""
         return self.nbytes_packed + self.scale.size * 2 + self.zero.size * 2
+
+
+def round_to_dtype(x: jnp.ndarray, dtype) -> jnp.ndarray:
+    """``x.astype(dtype).astype(float32)``, in a form no compiler drops.
+
+    XLA may fold a narrowing convert followed by a widening one into a
+    no-op (excess precision), while a Pallas kernel keeps both, so the two
+    would disagree on what a stat rounded for storage is.  bfloat16 is
+    rounded to nearest even on the int32 view of ``x`` (finite values);
+    float32 is returned as is.  Runs in XLA and inside Pallas kernels.
+    """
+    x = x.astype(jnp.float32)
+    dtype = jnp.dtype(dtype)
+    if dtype == jnp.float32:
+        return x
+    if dtype != jnp.bfloat16:
+        raise ValueError(f"stat dtype must be bfloat16 or float32, got {dtype}")
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    bits = (bits + 0x7FFF + ((bits >> 16) & 1)) & jnp.int32(-0x10000)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
 def _group_minmax(x: jnp.ndarray, scheme: str, group: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -141,8 +162,12 @@ def quantize(
         jnp.round((xf - mn_full) / scale_full), 0, 2**bits - 1
     ).astype(jnp.int32)
     packed = packing.pack(codes, bits)
-    scale = _compact_groups(scale_full, scheme, group).astype(stat_dtype)
-    zero = _compact_groups(mn_full, scheme, group).astype(stat_dtype)
+    # rounded explicitly first, so a caller that dequantizes in the same
+    # program sees the stored stats even where XLA skips the convert pair
+    scale = round_to_dtype(_compact_groups(scale_full, scheme, group),
+                           stat_dtype).astype(stat_dtype)
+    zero = round_to_dtype(_compact_groups(mn_full, scheme, group),
+                          stat_dtype).astype(stat_dtype)
     return QuantizedTensor(
         packed=packed, scale=scale, zero=zero,
         bits=bits, scheme=scheme, group=group, n=n, d=d,

@@ -2,8 +2,8 @@
 
 ``repro.dist.sharding`` holds the SPMD sharding rules (PartitionSpec
 legalization + pytree rules for params / optimizer state / batches / GEAR
-caches); ``repro.dist.compat`` papers over ``shard_map`` API drift between
-jax releases.
+caches); ``repro.dist.compat`` holds the partially manual ``shard_map``
+the train loop runs PowerSGD under.
 """
 
 from repro.dist import compat, sharding

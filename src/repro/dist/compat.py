@@ -1,22 +1,12 @@
-"""shard_map across jax API generations.
+"""Partially manual ``shard_map``.
 
 The train loop runs PowerSGD under a *partially manual* ``shard_map``: the
 ``pod`` axis is manual (the compressor issues explicit ``pmean`` over it)
-while ``data``/``model`` stay with the SPMD partitioner.  The spelling of
-"manual only over these axes" has changed across jax releases:
-
-* newer jax: ``jax.shard_map(..., axis_names={...}, check_vma=False)``
-* jax 0.4.x: ``jax.experimental.shard_map.shard_map(..., auto=<complement>,
-  check_rep=False)``
-
-:func:`manual_shard_map` accepts the *manual* axis set and picks whichever
-spelling the installed jax provides (by signature inspection, so a genuine
-``TypeError`` from bad caller arguments is never masked).
+while ``data``/``model`` stay with the SPMD partitioner.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import Iterable
 
 import jax
@@ -26,15 +16,5 @@ __all__ = ["manual_shard_map"]
 
 def manual_shard_map(fn, mesh, in_specs, out_specs, manual_axes: Iterable[str]):
     """``shard_map(fn)`` manual over ``manual_axes``, auto over the rest."""
-    manual = set(manual_axes)
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    if "axis_names" in params:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  axis_names=manual, check_vma=False)
-    auto = frozenset(mesh.axis_names) - manual
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False, auto=auto)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=set(manual_axes), check_vma=False)

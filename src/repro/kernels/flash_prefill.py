@@ -129,7 +129,7 @@ def _block_kernel(len_ref, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
         s = softcap * jnp.tanh(s / softcap)
     qi = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
     ki = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-    ok = (ki <= qi) & (ki < len_ref[0])
+    ok = (ki <= qi) & (ki < len_ref[pl.program_id(0)])
     s = jnp.where(ok, s, NEG_INF)
     m = jnp.max(s, axis=-1)                       # [T]
     p = jnp.exp(s - m[:, None])
@@ -156,12 +156,12 @@ def flash_prefill_block(q, k, v, kv_len, *, scale: float, softcap: float = 0.0,
     N, T, Dh = q.shape
     f32 = jnp.float32
     kernel = functools.partial(_block_kernel, scale=scale, softcap=softcap)
-    n = lambda i: (i, 0, 0)
-    return pl.pallas_call(
-        kernel,
+    n = lambda i, lens: (i, 0, 0)
+    # kv_len rides scalar prefetch (SMEM): one length per grid program
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(N,),
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),
             pl.BlockSpec((1, T, Dh), n),
             pl.BlockSpec((1, T, Dh), n),
             pl.BlockSpec((1, T, Dh), n),
@@ -171,10 +171,16 @@ def flash_prefill_block(q, k, v, kv_len, *, scale: float, softcap: float = 0.0,
             pl.BlockSpec((1, T, 128), n),
             pl.BlockSpec((1, T, 128), n),
         ),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((N, T, Dh), f32),
             jax.ShapeDtypeStruct((N, T, 128), f32),
             jax.ShapeDtypeStruct((N, T, 128), f32),
         ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(jnp.asarray(kv_len, jnp.int32), q, k, v)
+    )(jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (N,)), q, k, v)

@@ -47,14 +47,38 @@ __all__ = ["gear_decode", "gear_decode_paged"]
 
 NEG_INF = -1e30
 
+# rows (slot-heads) are independent; the chunk axis carries the
+# online-softmax accumulator, so only rows may be split across cores
+_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+# K stats ([BH, C, Dh], bf16) are read through a window of this many chunk
+# rows: Mosaic cannot index one bf16 sublane at a traced offset, so the
+# BlockSpec DMAs an aligned window (re-fetched once per window, not per
+# chunk) and the kernel selects the chunk's row from it.
+STAT_WINDOW = 16
+
 
 def _unpack(packed, bits: int, d: int):
-    """packed [n, d//per] int32 -> codes f32 [n, d]."""
+    """packed [n, d//per] int32 -> codes f32 [n, d].
+
+    int32 throughout (Mosaic has no unsigned<->float casts); the arithmetic
+    shift's sign extension only reaches bits the mask clears."""
     per = 32 // bits
     n = packed.shape[0]
-    shifts = (jnp.arange(per, dtype=jnp.uint32) * bits)[None, None, :]
-    codes = (packed.astype(jnp.uint32)[:, :, None] >> shifts) & jnp.uint32(2**bits - 1)
+    shifts = (jnp.arange(per, dtype=jnp.int32) * bits)[None, None, :]
+    codes = (packed[:, :, None] >> shifts) & (2**bits - 1)
     return codes.reshape(n, d).astype(jnp.float32)
+
+
+def _window_row(ref, c):
+    """Row ``c % rows`` of a [1, rows, Dh] stat window, as f32 [1, Dh]."""
+    w = ref[0].astype(jnp.float32)
+    rows = w.shape[0]
+    if rows == 1:
+        return w
+    sel = jax.lax.broadcasted_iota(jnp.int32, w.shape, 0) == c % rows
+    return jnp.sum(jnp.where(sel, w, 0.0), axis=0, keepdims=True)
 
 
 def _kernel(n_comp_ref, q_ref, kp_ref, ks_ref, kz_ref, vp_ref, vs_ref, vz_ref,
@@ -63,6 +87,7 @@ def _kernel(n_comp_ref, q_ref, kp_ref, ks_ref, kz_ref, vp_ref, vs_ref, vz_ref,
             acc_ref, m_ref, l_ref,
             *, bits: int, chunk: int, scale_factor: float,
             use_lr: bool, use_sp: bool):
+    bh = pl.program_id(0)
     c = pl.program_id(1)
     nb = chunk
     q = q_ref[0].astype(jnp.float32)                       # [G, Dh]
@@ -70,13 +95,14 @@ def _kernel(n_comp_ref, q_ref, kp_ref, ks_ref, kz_ref, vp_ref, vs_ref, vz_ref,
 
     # ---- K chunk: dequant + outliers --------------------------------------
     k_tile = _unpack(kp_ref[0], bits, Dh)                  # [nb, Dh]
-    k_tile = k_tile * ks_ref[0].astype(jnp.float32) + kz_ref[0].astype(jnp.float32)
+    k_tile = k_tile * _window_row(ks_ref, c) + _window_row(kz_ref, c)
     if use_sp:
-        ksv = ksv_ref[0, 0].astype(jnp.float32)            # [Dh, Ks]
-        ksi = ksi_ref[0, 0]
+        # [Dh, Ks] -> [Ks, Dh]: channel on lanes, like the tile
+        ksv = ksv_ref[0, 0].astype(jnp.float32).T
+        ksi = ksi_ref[0, 0].T
         row = jax.lax.broadcasted_iota(jnp.int32, (nb, Dh), 0)
-        for j in range(ksv.shape[-1]):
-            k_tile += jnp.where(row == ksi[None, :, j], ksv[None, :, j], 0.0)
+        for j in range(ksv.shape[0]):
+            k_tile += jnp.where(row == ksi[j:j + 1], ksv[j:j + 1], 0.0)
 
     s = jax.lax.dot_general(q, k_tile, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [G, nb]
@@ -90,7 +116,7 @@ def _kernel(n_comp_ref, q_ref, kp_ref, ks_ref, kz_ref, vp_ref, vs_ref, vz_ref,
     s = s * scale_factor
 
     tok = c * nb + jax.lax.broadcasted_iota(jnp.int32, (G, nb), 1)
-    s = jnp.where(tok < n_comp_ref[0], s, NEG_INF)
+    s = jnp.where(tok < n_comp_ref[bh], s, NEG_INF)
 
     # ---- V chunk ------------------------------------------------------------
     v_tile = _unpack(vp_ref[0], bits, Dh)
@@ -167,46 +193,54 @@ def gear_decode(
         v_sp_val = jnp.zeros((BH, S, 1), f32)
         v_sp_idx = jnp.full((BH, S, 1), -1, jnp.int32)
 
-    # scalar extents broadcast to one row per (batch, head) grid program
+    # scalar extents broadcast to one row per (batch, head) grid program;
+    # they ride scalar prefetch (SMEM) so each program reads its own row
     n_comp_arr = jnp.broadcast_to(jnp.asarray(n_comp, jnp.int32), (BH,))
+    sw = min(C, STAT_WINDOW)
 
-    grid = (BH, C)
     kernel = functools.partial(
         _kernel, bits=bits, chunk=chunk, scale_factor=scale_factor,
         use_lr=use_lr, use_sp=use_sp)
-    out_shape = (
-        jax.ShapeDtypeStruct((BH, G, Dh), f32),
-        jax.ShapeDtypeStruct((BH, G, 128), f32),
-        jax.ShapeDtypeStruct((BH, G, 128), f32),
-    )
-    bh = lambda x, c: (x, 0, 0)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    bh = lambda x, c, n: (x, 0, 0)
+    tile = lambda x, c, n: (x, c, 0)
+    tile4 = lambda x, c, n: (x, c, 0, 0)
+    stat = lambda x, c, n: (x, c // sw, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(BH, C),
         in_specs=[
-            pl.BlockSpec((1,), lambda x, c: (x,)),                       # n_comp[bh]
-            pl.BlockSpec((1, G, Dh), bh),                                # q
-            pl.BlockSpec((1, chunk, Lp), lambda x, c: (x, c, 0)),        # k_packed
-            pl.BlockSpec((1, 1, Dh), lambda x, c: (x, c, 0)),            # k_scale
-            pl.BlockSpec((1, 1, Dh), lambda x, c: (x, c, 0)),            # k_zero
-            pl.BlockSpec((1, chunk, Lp), lambda x, c: (x, c, 0)),        # v_packed
-            pl.BlockSpec((1, chunk, gv), lambda x, c: (x, c, 0)),        # v_scale
-            pl.BlockSpec((1, chunk, gv), lambda x, c: (x, c, 0)),        # v_zero
-            pl.BlockSpec((1, chunk, r), lambda x, c: (x, c, 0)),         # k_a
-            pl.BlockSpec((1, 1, Dh, r), lambda x, c: (x, c, 0, 0)),      # k_b
-            pl.BlockSpec((1, chunk, r), lambda x, c: (x, c, 0)),         # v_a
-            pl.BlockSpec((1, 1, Dh, r), lambda x, c: (x, c, 0, 0)),      # v_b
-            pl.BlockSpec((1, 1, Dh, ks2), lambda x, c: (x, c, 0, 0)),    # k_sp_val
-            pl.BlockSpec((1, 1, Dh, ks2), lambda x, c: (x, c, 0, 0)),    # k_sp_idx
-            pl.BlockSpec((1, chunk, kv2), lambda x, c: (x, c, 0)),       # v_sp_val
-            pl.BlockSpec((1, chunk, kv2), lambda x, c: (x, c, 0)),       # v_sp_idx
+            pl.BlockSpec((1, G, Dh), bh),                  # q
+            pl.BlockSpec((1, chunk, Lp), tile),            # k_packed
+            pl.BlockSpec((1, sw, Dh), stat),               # k_scale
+            pl.BlockSpec((1, sw, Dh), stat),               # k_zero
+            pl.BlockSpec((1, chunk, Lp), tile),            # v_packed
+            pl.BlockSpec((1, chunk, gv), tile),            # v_scale
+            pl.BlockSpec((1, chunk, gv), tile),            # v_zero
+            pl.BlockSpec((1, chunk, r), tile),             # k_a
+            pl.BlockSpec((1, 1, Dh, r), tile4),            # k_b
+            pl.BlockSpec((1, chunk, r), tile),             # v_a
+            pl.BlockSpec((1, 1, Dh, r), tile4),            # v_b
+            pl.BlockSpec((1, 1, Dh, ks2), tile4),          # k_sp_val
+            pl.BlockSpec((1, 1, Dh, ks2), tile4),          # k_sp_idx
+            pl.BlockSpec((1, chunk, kv2), tile),           # v_sp_val
+            pl.BlockSpec((1, chunk, kv2), tile),           # v_sp_idx
         ],
         out_specs=(
             pl.BlockSpec((1, G, Dh), bh),
             pl.BlockSpec((1, G, 128), bh),
             pl.BlockSpec((1, G, 128), bh),
         ),
+    )
+    out_shape = (
+        jax.ShapeDtypeStruct((BH, G, Dh), f32),
+        jax.ShapeDtypeStruct((BH, G, 128), f32),
+        jax.ShapeDtypeStruct((BH, G, 128), f32),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(n_comp_arr, q, k_packed, k_scale, k_zero, v_packed, v_scale, v_zero,
       k_a, k_b, v_a, v_b, k_sp_val, k_sp_idx, v_sp_val, v_sp_idx)
@@ -255,12 +289,12 @@ def gear_decode_paged(
     # page-row index map shared by every pool operand: the chunk coordinate
     # is consumed by the block-table lookup, the block covers the whole page
     def prow(*tail):
-        return lambda x, c, bt: ((bt[x // H, c] * H + x % H).astype(jnp.int32),
-                                 *tail)
+        return lambda x, c, bt, n: (
+            (bt[x // H, c] * H + x % H).astype(jnp.int32), *tail)
 
     # dummy single-page operands when the policy has no low-rank / sparse
     # fields; their index maps pin to row 0 so no table lookup happens
-    zrow = lambda *tail: (lambda x, c, bt: (0, *tail))
+    zrow = lambda *tail: (lambda x, c, bt, n: (0, *tail))
     if not use_lr:
         k_a = jnp.zeros((1, nb, 1), f32); k_b = jnp.zeros((1, 1, Dh, 1), f32)
         v_a = jnp.zeros((1, nb, 1), f32); v_b = jnp.zeros((1, 1, Dh, 1), f32)
@@ -280,12 +314,11 @@ def gear_decode_paged(
         _kernel(*refs, bits=bits, chunk=chunk, scale_factor=scale_factor,
                 use_lr=use_lr, use_sp=use_sp)
 
-    bh = lambda x, c, bt: (x, 0, 0)
+    bh = lambda x, c, bt, n: (x, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(BH, C),
         in_specs=[
-            pl.BlockSpec((1,), lambda x, c, bt: (x,)),             # n_comp[bh]
             pl.BlockSpec((1, G, Dh), bh),                          # q
             pl.BlockSpec((1, chunk, Lp), prow(0, 0)),              # k_packed
             pl.BlockSpec((1, 1, Dh), prow(0, 0)),                  # k_scale
@@ -317,6 +350,7 @@ def gear_decode_paged(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(bt, n_comp_arr, q, k_packed, k_scale, k_zero, v_packed, v_scale,
       v_zero, k_a, k_b, v_a, v_b, k_sp_val, k_sp_idx, v_sp_val, v_sp_idx)

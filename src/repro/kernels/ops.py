@@ -9,20 +9,23 @@ design where only compressed history pays the dequantization path.
 
 from __future__ import annotations
 
-import functools
+import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.cache import (CacheConfig, PagedGEARLayerCache,
                               streaming_supported)
+from repro.launch.mesh import MODEL, dp_axes
 from repro.kernels import ref as ref_ops
 from repro.kernels.flash_prefill import flash_prefill, flash_prefill_block
 from repro.kernels.gear_compress import gear_compress
 from repro.kernels.gear_decode import gear_decode, gear_decode_paged
 from repro.kernels.quant_pack import quant_pack
 
-__all__ = ["on_tpu", "fused_supported",
+__all__ = ["on_tpu", "fused_supported", "shard_over_heads",
            "gear_attend", "gear_attend_paged", "gear_attend_block",
            "gear_compress_chunks", "flash_attention", "quantize_chunk"]
 
@@ -31,6 +34,37 @@ NEG_INF = -1e30
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def shard_over_heads(fn, args, *, head_axis: int, n_heads: int):
+    """Run ``fn(*args)`` once per head shard of the context mesh.
+
+    A ``pallas_call`` is opaque to the SPMD partitioner, so a kernel traced
+    under a mesh (:meth:`repro.serving.Engine.mesh_context`) is wrapped in
+    ``shard_map``: every array's leading (slot) dim splits over the data
+    axes and its ``head_axis`` dim over ``model`` — where they divide, the
+    rule :func:`repro.dist.sharding.cache_pspecs` lays the cache out by —
+    and ``fn`` runs on one shard's slots and heads.  The output is one
+    array in the first argument's layout.  Without a context mesh, ``fn``
+    simply runs on the whole arrays.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return fn(*args)
+    dp = dp_axes(mesh)
+    batch = jax.tree.leaves(args)[0].shape[0]
+    b_ax = dp if dp and batch % math.prod(mesh.shape[a] for a in dp) == 0 else None
+    h_ax = MODEL if MODEL in mesh.shape and n_heads % mesh.shape[MODEL] == 0 else None
+
+    def spec(x):
+        ent = [b_ax] + [None] * (x.ndim - 1)
+        if x.ndim > head_axis:
+            ent[head_axis] = h_ax
+        return P(*ent)
+
+    return jax.shard_map(fn, mesh=mesh, in_specs=jax.tree.map(spec, args),
+                         out_specs=spec(jax.tree.leaves(args)[0]),
+                         check_vma=False)(*args)
 
 
 def _flat(x, bh):
@@ -247,8 +281,24 @@ def gear_attend(cfg: CacheConfig, cache, q: jnp.ndarray, scale: float,
     Ragged-aware: ``cache.length`` is the per-slot ``[B]`` length vector and
     every slot attends over exactly its own compressed extent and buffer
     fill, inside the kernel — mixed-length continuous batches take this
-    path directly (DESIGN.md §ragged fused decode).
+    path directly (DESIGN.md §ragged fused decode).  Under a mesh the
+    kernel runs per head shard (:func:`shard_over_heads`).
     """
+    if not (on_tpu() or force_kernel):
+        return _gear_attend(cfg, cache, q, scale, False, False)
+    G = q.shape[1] // cfg.kv_heads
+
+    def local(qq, c):
+        lcfg = dataclasses.replace(cfg, batch=qq.shape[0],
+                                   kv_heads=qq.shape[1] // G)
+        return _gear_attend(lcfg, c, qq, scale, True, interpret or not on_tpu())
+
+    return shard_over_heads(local, (q, cache), head_axis=1,
+                            n_heads=cfg.kv_heads)
+
+
+def _gear_attend(cfg: CacheConfig, cache, q, scale: float, kernel: bool,
+                 interpret: bool):
     pol = cfg.policy
     B, Hq, Dh = q.shape
     H = cfg.kv_heads
@@ -265,8 +315,8 @@ def gear_attend(cfg: CacheConfig, cache, q: jnp.ndarray, scale: float,
     kwargs = dict(bits=pol.bits, chunk=nb, scale_factor=scale)
     arrays, lr, sp = _gear_operands(cfg, cache, BH)
     common = (qf, *arrays, n_comp)
-    if on_tpu() or force_kernel:
-        acc, m, l = gear_decode(*common, interpret=interpret or not on_tpu(),
+    if kernel:
+        acc, m, l = gear_decode(*common, interpret=interpret,
                                 **kwargs, **lr, **sp)
         m, l = m[..., 0], l[..., 0]
     else:

@@ -210,9 +210,8 @@ def gear_compress_ref(x: jnp.ndarray, *, bits: int, scheme: str,
         dense = ol.densify(sp)
     qt = q_lib.quantize(remainder, bits, scheme, group,
                         stat_dtype=jnp.float32)
-    sd = jnp.dtype(stat_dtype)
-    qt_r = dataclasses.replace(qt, scale=qt.scale.astype(sd),
-                               zero=qt.zero.astype(sd))
+    qt_r = dataclasses.replace(qt, scale=q_lib.round_to_dtype(qt.scale, stat_dtype),
+                               zero=q_lib.round_to_dtype(qt.zero, stat_dtype))
     resid = x.astype(jnp.float32) - q_lib.dequantize(qt_r) - dense
     return qt.packed, qt.scale, qt.zero, sp_val, sp_idx, resid
 

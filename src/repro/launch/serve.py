@@ -15,6 +15,8 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,26 @@ from repro.launch.mesh import make_test_mesh
 from repro.models.model import build_model
 from repro.serving import (CacheLayout, Engine, EngineConfig, ObsConfig,
                            Request, Scheduler)
+
+
+# the checkout root: src/repro/launch/serve.py -> <checkout>
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (JAX reads it
+    itself).  Otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache``: a fixed path is what lets the next process
+    that runs from this checkout find what this one compiled.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def main():
@@ -56,6 +78,7 @@ def main():
     ap.add_argument("--trace-out", default="",
                     help="write Chrome trace_event JSON here (implies --obs)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.metrics_json or args.trace_out or args.fidelity_every:
         args.obs = True
 

@@ -125,18 +125,25 @@ def _sdpa_flash(cfg: ModelConfig, q, k, v, kind: str, prefix_len: int,
     points each group at its shared K/V row — no G-fold broadcast copy of
     K/V is ever materialized.  Same mask family as ``_sdpa_chunked``
     (causal / sliding window / bidirectional prefix, plus logit softcap).
+    Under a mesh the kernel runs per head shard
+    (:func:`repro.kernels.ops.shard_over_heads`).
     """
-    B, S, Hq, Dh = q.shape
-    Hkv = k.shape[2]
-    G = Hq // Hkv
+    G = q.shape[2] // k.shape[2]
     window = cfg.local_window if kind == "local" else 0
-    qh = jnp.moveaxis(q, 1, 2).reshape(B * Hq, S, Dh)   # (B, Hkv, G) rows
-    out = kernel_ops.flash_attention(
-        qh, jnp.moveaxis(k, 1, 2).reshape(B * Hkv, S, Dh),
-        jnp.moveaxis(v, 1, 2).reshape(B * Hkv, S, Dh),
-        window=window, prefix_len=prefix_len, softcap=cfg.attn_logit_softcap,
-        kv_repeat=G, interpret=interpret)
-    return jnp.moveaxis(out.reshape(B, Hq, S, Dh), 1, 2)
+
+    def local(q, k, v):
+        B, S, Hq, Dh = q.shape
+        Hkv = k.shape[2]
+        qh = jnp.moveaxis(q, 1, 2).reshape(B * Hq, S, Dh)   # (B, Hkv, G) rows
+        out = kernel_ops.flash_attention(
+            qh, jnp.moveaxis(k, 1, 2).reshape(B * Hkv, S, Dh),
+            jnp.moveaxis(v, 1, 2).reshape(B * Hkv, S, Dh),
+            window=window, prefix_len=prefix_len,
+            softcap=cfg.attn_logit_softcap, kv_repeat=G, interpret=interpret)
+        return jnp.moveaxis(out.reshape(B, Hq, S, Dh), 1, 2)
+
+    return kernel_ops.shard_over_heads(local, (q, k, v), head_axis=2,
+                                       n_heads=k.shape[2])
 
 
 def attention_train(cfg: ModelConfig, params, x, positions, kind: str = "global",

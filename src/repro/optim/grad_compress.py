@@ -53,11 +53,7 @@ def compressed_psum(grads: Any, ef: Any, cfg: CompressorConfig, key: jax.Array):
     MUST be called inside shard_map with ``cfg.axis`` a manual axis.
     Returns (mean grads, new error-feedback state, bytes metrics).
     """
-    # jax.lax.axis_size is missing on older jax; psum(1) is the same number.
-    if hasattr(jax.lax, "axis_size"):
-        n_dev = jax.lax.axis_size(cfg.axis)
-    else:
-        n_dev = jax.lax.psum(1, cfg.axis)
+    n_dev = jax.lax.axis_size(cfg.axis)
     exact_bytes = jnp.zeros((), jnp.float32)
     comp_bytes = jnp.zeros((), jnp.float32)
 

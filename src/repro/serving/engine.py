@@ -436,11 +436,13 @@ class Engine:
 
     @property
     def attend_path(self) -> str:
-        """Decode-attend path compiled into this engine's attention layers:
-        "fused" (gear_attend — Pallas kernel on TPU, jnp oracle elsewhere),
-        "fused-interpret" (kernel forced in interpret mode), or "xla"
-        (no layer qualifies: fp16/window caches, unsupported layouts, or
-        ``fused="off"``).  Checks every kind in the model's layer pattern —
+        """Decode-attend path compiled into this engine's attention layers,
+        named for what runs: "fused-kernel" (gear_attend through the Pallas
+        kernel — the TPU path), "fused-oracle" (gear_attend's jnp oracle —
+        ``fused="auto"`` off the TPU), "fused-interpret" (kernel forced in
+        interpret mode), or "xla" (no layer qualifies: fp16/window caches,
+        unsupported layouts, or ``fused="off"``).  Checks every kind in the
+        model's layer pattern —
         local/window layers never fuse, so a model needs at least one
         GEAR-layout attention layer to report a fused path.  The paged
         layout shares the dense kernel constraint (the paged kernel gathers
@@ -451,8 +453,9 @@ class Engine:
             for kind in self.cfg.layer_pattern if kind != "rwkv")
         if self.ecfg.fused is AttendPath.OFF or not fused_any:
             return "xla"
-        return ("fused-interpret" if self.ecfg.fused is AttendPath.INTERPRET
-                else "fused")
+        if self.ecfg.fused is AttendPath.INTERPRET:
+            return "fused-interpret"
+        return "fused-kernel" if kernel_ops.on_tpu() else "fused-oracle"
 
     # ------------------------------------------------------------------
     def attach_faults(self, injector) -> None:
@@ -558,12 +561,50 @@ class Engine:
             self.prefix_cache.bump_version()
 
     # ------------------------------------------------------------------
+    def mesh_context(self):
+        """Context the engine's model programs trace and run under: the
+        engine's mesh as JAX's context mesh, which is how the kernel
+        wrappers (:mod:`repro.kernels.ops`) find the head shards to run
+        each Pallas kernel on.  A no-op without a mesh."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
+
+    def compiled_decode_text(self) -> str:
+        """Optimized HLO of this engine's decode step, compiled for its own
+        devices and operand layouts (abstract operands — nothing is
+        allocated).  Tells a caller which kernels the step really runs:
+        a Pallas kernel shows up as a ``tpu_custom_call``."""
+        like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                              sharding=x.sharding)
+        ecfg, cap = self.ecfg, self._cap()
+        if self.layout is CacheLayout.PAGED:
+            caches = jax.eval_shape(lambda: self.model.init_caches(
+                ecfg.policy, ecfg.batch, cap, layout="paged",
+                pool_pages=self._n_pages))
+        else:
+            caches = jax.eval_shape(lambda: self.model.init_caches(
+                ecfg.policy, ecfg.batch, cap))
+            if self._cache_shard is not None:
+                caches = jax.tree.map(
+                    lambda c, s: jax.ShapeDtypeStruct(c.shape, c.dtype,
+                                                      sharding=s),
+                    caches, self._cache_shard)
+        args = (jax.tree.map(like, self.params),
+                {"tokens": jax.ShapeDtypeStruct((ecfg.batch, 1), jnp.int32)},
+                caches, jax.ShapeDtypeStruct((ecfg.batch,), jnp.int32))
+        if self.layout is CacheLayout.PAGED:
+            args += (jax.ShapeDtypeStruct(self._bt.shape, self._bt.dtype),)
+        with self.mesh_context():
+            return self._decode.lower(*args).compile().as_text()
+
     def prefill(self, batch: dict):
         if self.layout is CacheLayout.PAGED:
             raise NotImplementedError(
                 "full-batch wave prefill is dense-only; paged engines serve "
                 "through Engine.prefill_slot / Scheduler.run_continuous")
-        logits, caches = self._prefill(self.params, batch)
+        with self.mesh_context():
+            logits, caches = self._prefill(self.params, batch)
         if self._cache_shard is not None:
             caches = jax.device_put(caches, self._cache_shard)
         return logits, caches
@@ -580,7 +621,7 @@ class Engine:
         """
         n = batch1["tokens"].shape[1]
         nb = self.ecfg.policy.buffer_size
-        with profiler_span("gear.prefill", self._prof):
+        with profiler_span("gear.prefill", self._prof), self.mesh_context():
             if not self._can_bucket or n % nb == 0:
                 return self._prefill(self.params, batch1)
             n_bucket = (n + nb - 1) // nb * nb
@@ -590,7 +631,7 @@ class Engine:
 
     def decode(self, token_batch: dict, caches, pos):
         """One decode step.  ``pos``: scalar or per-slot [B] int32 vector."""
-        with profiler_span("gear.decode", self._prof):
+        with profiler_span("gear.decode", self._prof), self.mesh_context():
             if self.layout is CacheLayout.PAGED:
                 return self._decode(self.params, token_batch, caches,
                                     jnp.asarray(pos, jnp.int32), self._bt)
@@ -682,7 +723,8 @@ class Engine:
         nb = self.ecfg.policy.buffer_size
         suf = np.asarray(tokens[n_hit * nb:], np.int32)
         n_suf = suf.shape[0]
-        with profiler_span("gear.prefill_suffix", self._prof):
+        with (profiler_span("gear.prefill_suffix", self._prof),
+              self.mesh_context()):
             if n_suf % nb == 0:
                 suffix = {"tokens": jnp.asarray(suf[None], jnp.int32)}
                 return self._suffix_fn(n_hit)(self.params, suffix, one1)

@@ -49,8 +49,8 @@ def _accum_grads(model: Model, params: Any, batch: Any, run: RunConfig,
 
     ``shard_map_safe`` avoids ``lax.scan`` while-loops entirely (unrolled
     layer stack, Python-loop microbatches): XLA's SPMD partitioner aborts
-    on while loops inside partially-manual shard_map regions (jaxlib
-    0.4.x), which is where the PowerSGD step runs.
+    on while loops inside partially-manual shard_map regions, which is
+    where the PowerSGD step runs.
     """
     m = run.microbatches
 

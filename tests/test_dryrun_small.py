@@ -46,7 +46,9 @@ def test_dryrun_machinery_small_mesh(tmp_path):
             assert "collectives" in rec, shape
             print(shape, "OK", rec["roofline"]["bottleneck"])
     """)
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # pinned to the CPU: on a machine with a chip the parent holds it
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(REPO, "src"))
     out = subprocess.run([sys.executable, "-c", body], capture_output=True,
                          text=True, env=env, timeout=900)
     assert out.returncode == 0, out.stderr[-3000:]

@@ -32,6 +32,23 @@ def test_quant_8bit_accurate(scheme, group, rng):
     assert err < 0.01
 
 
+@pytest.mark.parametrize("jit", [False, True])
+def test_round_to_dtype_is_bf16_rounding(jit, rng):
+    """The int32-view rounding equals a real bf16 store bit for bit, ties
+    to even included, eagerly and inside one program."""
+    x = jax.random.normal(rng, (4096,)) * 10.0 ** jnp.arange(-4, 4).repeat(512)
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    ties = jax.lax.bitcast_convert_type((bits & -0x10000) | 0x8000, jnp.float32)
+    x = jnp.concatenate([x, ties, -ties, jnp.zeros(1)])
+    fn = jax.jit(quant.round_to_dtype, static_argnums=1) if jit else quant.round_to_dtype
+    want = x.astype(jnp.bfloat16).astype(jnp.float32)
+    got = fn(x, jnp.bfloat16)
+    assert got.dtype == jnp.float32
+    assert (jax.lax.bitcast_convert_type(got, jnp.int32)
+            == jax.lax.bitcast_convert_type(want, jnp.int32)).all()
+    assert (fn(x, jnp.float32) == x).all()
+
+
 def test_quant_monotone_in_bits(rng):
     x = jax.random.normal(rng, (4, 128, 64))
     errs = [float(quant.quant_error(x, b, "per_channel")) for b in (2, 4, 8)]

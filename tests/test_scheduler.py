@@ -176,7 +176,9 @@ def test_decode_dispatches_fused_gear_attend(monkeypatch):
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     eng = Engine(model, params, EngineConfig(batch=2, capacity=48, policy=pol))
-    assert eng.attend_path == "fused"
+    # fused="auto" names what runs: the jnp oracle off the TPU
+    assert eng.attend_path == ("fused-kernel" if kernel_ops.on_tpu()
+                               else "fused-oracle")
     caches = eng.init_caches()
     tb = {"tokens": jnp.zeros((2, 1), jnp.int32)}
     eng.decode(tb, caches, jnp.asarray([5, 17], jnp.int32))   # mixed lengths
