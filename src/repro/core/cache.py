@@ -616,11 +616,12 @@ def streaming_prefill_pipeline(cfg: CacheConfig, cache, n: int, chunk_xs,
                 v_c[:, :, None].astype(jnp.float32), pol.rank, key, fused=fused)
             return None, comp
 
-        _, comp_s = jax.lax.scan(body_compress, None, chunk_xs)
-        upd = {f.name: getattr(cache, f.name)
-               for f in dataclasses.fields(GEARLayerCache)}
-        cache = GEARLayerCache(**_assemble_scanned_chunks(cfg, upd, comp_s,
-                                                          n_full, start_chunk))
+        with jax.named_scope("cache_update"):
+            _, comp_s = jax.lax.scan(body_compress, None, chunk_xs)
+            upd = {f.name: getattr(cache, f.name)
+                   for f in dataclasses.fields(GEARLayerCache)}
+            cache = GEARLayerCache(**_assemble_scanned_chunks(
+                cfg, upd, comp_s, n_full, start_chunk))
 
         out_parts = []
         # Segment over the GLOBAL chunk range, then clip to the suffix: a

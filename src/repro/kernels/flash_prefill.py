@@ -114,6 +114,7 @@ def flash_prefill(q, k, v, *, bq: int = 128, bk: int = 128, window: int = 0,
             pltpu.VMEM((bq, Dh), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_prefill",
     )(q, k, v)
 
 
@@ -183,4 +184,5 @@ def flash_prefill_block(q, k, v, kv_len, *, scale: float, softcap: float = 0.0,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="flash_prefill_block",
     )(jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (N,)), q, k, v)

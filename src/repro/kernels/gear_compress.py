@@ -247,6 +247,7 @@ def gear_compress(x: jnp.ndarray, *, bits: int, scheme: str,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="gear_compress",
     )(x)
     if n_out:
         packed, scale, zero, spv, spi, resid = out

@@ -242,6 +242,7 @@ def gear_decode(
         out_shape=out_shape,
         compiler_params=_PARAMS,
         interpret=interpret,
+        name="gear_decode",
     )(n_comp_arr, q, k_packed, k_scale, k_zero, v_packed, v_scale, v_zero,
       k_a, k_b, v_a, v_b, k_sp_val, k_sp_idx, v_sp_val, v_sp_idx)
 
@@ -352,5 +353,6 @@ def gear_decode_paged(
         out_shape=out_shape,
         compiler_params=_PARAMS,
         interpret=interpret,
+        name="gear_decode_paged",
     )(bt, n_comp_arr, q, k_packed, k_scale, k_zero, v_packed, v_scale,
       v_zero, k_a, k_b, v_a, v_b, k_sp_val, k_sp_idx, v_sp_val, v_sp_idx)

@@ -111,5 +111,6 @@ def linear_scan_chunked(r, k, v, log_w, u=None, *, chunk: int = 64,
         ),
         scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)],
         interpret=interpret,
+        name="linear_scan_chunked",
     )(r, k, v, lw, u2)
     return y, state

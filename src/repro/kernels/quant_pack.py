@@ -61,4 +61,5 @@ def quant_pack(x: jnp.ndarray, bits: int, interpret: bool = False):
         ),
         out_shape=out_shapes,
         interpret=interpret,
+        name="quant_pack",
     )(x)

@@ -291,8 +291,9 @@ def attention_decode(cfg: ModelConfig, params, x_t, pos, cache, cache_cfg,
     if isinstance(cache, cache_lib.PagedGEARLayerCache):
         if block_tables is None:
             raise ValueError("paged cache decode needs block_tables")
-        new_cache = cache_lib.append_token_paged(cache_cfg, cache,
-                                                 block_tables, k_t, v_t)
+        with jax.named_scope("cache_update"):
+            new_cache = cache_lib.append_token_paged(cache_cfg, cache,
+                                                     block_tables, k_t, v_t)
         if fused != "off" and kernel_ops.fused_supported(cache_cfg):
             out = kernel_ops.gear_attend_paged(
                 cache_cfg, new_cache, block_tables, q_t, scale=scale,
@@ -303,7 +304,8 @@ def attention_decode(cfg: ModelConfig, params, x_t, pos, cache, cache_cfg,
                                          q_t, scale)
         out = out.reshape(B, 1, cfg.q_dim) @ params["wo"].astype(x_t.dtype)
         return out, new_cache
-    new_cache = cache_lib.append_token(cache_cfg, cache, k_t, v_t)
+    with jax.named_scope("cache_update"):
+        new_cache = cache_lib.append_token(cache_cfg, cache, k_t, v_t)
     if fused != "off" and kernel_ops.fused_supported(cache_cfg):
         out = kernel_ops.gear_attend(cache_cfg, new_cache, q_t,
                                      scale=scale,

@@ -245,15 +245,18 @@ def _apply_block_decode(cfg: ModelConfig, bp, x_t, kind, pos, cache, policy,
     attn_cache, ssm_state = (cache if hybrid else (cache, None))
     ccfg = cache_cfg_for(cfg, kind, policy, batch, capacity)
     xin = apply_norm(x_t, bp["ln1"], cfg.norm)
-    h, attn_cache = attn_lib.attention_decode(cfg, bp["attn"], xin, pos, attn_cache,
-                                              ccfg, kind, fused=fused,
-                                              block_tables=block_tables)
+    with jax.named_scope("attention"):
+        h, attn_cache = attn_lib.attention_decode(
+            cfg, bp["attn"], xin, pos, attn_cache, ccfg, kind, fused=fused,
+            block_tables=block_tables)
     if hybrid:
         h2, ssm_state = ssm_lib.ssm_decode(cfg, bp["ssm"], xin, ssm_state)
         h = (h + h2) * 0.5
     x_t = x_t + h
     xin2 = apply_norm(x_t, bp["ln2"], cfg.norm)
-    m = moe_apply(cfg, bp["moe"], xin2)[0] if cfg.moe else mlp_apply(cfg, bp["mlp"], xin2)
+    with jax.named_scope("mlp"):
+        m = (moe_apply(cfg, bp["moe"], xin2)[0] if cfg.moe
+             else mlp_apply(cfg, bp["mlp"], xin2))
     x_t = x_t + m
     new_cache = (attn_cache, ssm_state) if hybrid else attn_cache
     return x_t, new_cache
@@ -292,10 +295,11 @@ def _apply_block_prefill(cfg: ModelConfig, bp, x, kind, positions, prefix_len,
         return x, aux, _kv_to_cache(cfg, kind, kv, policy, batch, capacity,
                                     cache_dtype)
     xin = apply_norm(x, bp["ln1"], cfg.norm)
-    h, cache = attn_lib.attention_prefill_streaming(
-        cfg, bp["attn"], xin, positions, kind, ccfg, fused=fused,
-        dtype=cache_dtype, cache=cache, start_pos=start_pos,
-        padded_tail=padded_tail, true_len=true_len)
+    with jax.named_scope("attention"):
+        h, cache = attn_lib.attention_prefill_streaming(
+            cfg, bp["attn"], xin, positions, kind, ccfg, fused=fused,
+            dtype=cache_dtype, cache=cache, start_pos=start_pos,
+            padded_tail=padded_tail, true_len=true_len)
     ssm_state = None
     if cfg.ssm and cfg.hybrid_parallel:
         if start_pos or padded_tail:
@@ -306,10 +310,11 @@ def _apply_block_prefill(cfg: ModelConfig, bp, x, kind, positions, prefix_len,
     x = x + h
     xin2 = apply_norm(x, bp["ln2"], cfg.norm)
     aux = jnp.zeros((), jnp.float32)
-    if cfg.moe:
-        m, aux = moe_apply(cfg, bp["moe"], xin2)
-    else:
-        m = mlp_apply(cfg, bp["mlp"], xin2)
+    with jax.named_scope("mlp"):
+        if cfg.moe:
+            m, aux = moe_apply(cfg, bp["moe"], xin2)
+        else:
+            m = mlp_apply(cfg, bp["mlp"], xin2)
     x = x + m
     if ssm_state is not None:
         return x, aux, (cache, ssm_state)
@@ -424,7 +429,8 @@ def forward(cfg: ModelConfig, params, batch: dict, mode: str = "train",
                 x, jnp.asarray(true_len, jnp.int32) - 1, 1, axis=1)
         else:
             last = x[:, -1:, :]
-        logits = logits_from_hidden(cfg, params, last)
+        with jax.named_scope("logits"):
+            logits = logits_from_hidden(cfg, params, last)
         return logits, tuple(caches), aux
 
     def unit_body(carry, unit_params):
@@ -491,6 +497,7 @@ def decode_tokens(cfg: ModelConfig, params, token_batch: dict, caches,
         return x, tuple(new_caches)
 
     x, new_caches = jax.lax.scan(unit_body, x, (params["blocks"], caches))
-    x = apply_norm(x, params["final_norm"], cfg.norm)
-    logits = logits_from_hidden(cfg, params, x)
+    with jax.named_scope("logits"):
+        x = apply_norm(x, params["final_norm"], cfg.norm)
+        logits = logits_from_hidden(cfg, params, x)
     return logits, new_caches

@@ -13,6 +13,7 @@ export formats.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import json
 import time
@@ -20,14 +21,18 @@ import time
 from .catalog import METRICS, MetricSpec, build_registry
 from .registry import (CardinalityError, Counter, Gauge, Histogram, Registry,
                        parse_prometheus)
-from .tracing import RequestTrace, Span, Tracer, profiler_span
+from .tracing import NULL_PHASE, Phase, RequestTrace, Span, Tracer, no_phase
 
 __all__ = [
     "ObsConfig", "Observability",
     "Registry", "Counter", "Gauge", "Histogram", "CardinalityError",
-    "parse_prometheus", "Tracer", "Span", "RequestTrace", "profiler_span",
-    "MetricSpec", "METRICS", "build_registry",
+    "parse_prometheus", "Tracer", "Span", "RequestTrace", "Phase",
+    "NULL_PHASE", "no_phase", "MetricSpec", "METRICS", "build_registry",
 ]
+
+# step phases kept per Observability (the oldest drop first): some minutes
+# of a serving loop at tens of phases a second
+PHASE_LIMIT = 1 << 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,8 +44,11 @@ class ObsConfig:
     spans.  ``fidelity_every_n`` samples a compression-fidelity probe
     each time the running closed-chunk count crosses a multiple of N
     (0 = off); ``fidelity_budget_frac`` caps measured probe wall time at
-    that fraction of elapsed real time.  ``profiler`` wraps prefill and
-    decode jit calls in ``jax.profiler`` trace annotations.
+    that fraction of elapsed real time.  ``profiler`` records step phases
+    (:meth:`Observability.phase`: admissions, decode steps, token reads,
+    the host work between steps, and the engine's prefill / guard /
+    splice / decode dispatches) on ``time.perf_counter`` and annotates
+    each on the ``jax.profiler`` timeline under the same name.
     """
 
     metrics: bool = True
@@ -70,6 +78,20 @@ class Observability:
         self.fidelity = None  # attached by the engine when probes are on
         self._m = bool(cfg.metrics)
         self._synced: dict = {}
+        # step phases, (name, t0, t1, args) on perf_counter; None when off
+        self.phases: collections.deque | None = None
+        if cfg.profiler:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+            self.phases = collections.deque(maxlen=PHASE_LIMIT)
+
+    # -- step phases ---------------------------------------------------------
+    def phase(self, name: str, **args):
+        """Context manager timing one step phase (see ``ObsConfig.profiler``);
+        the shared null context when phases are off."""
+        if self.phases is None:
+            return NULL_PHASE
+        return Phase(self.phases, self._annotation, name, args)
 
     # -- scheduler lifecycle ----------------------------------------------
     def on_submit(self, rid: int) -> None:
@@ -114,6 +136,14 @@ class Observability:
     def observe_prefill(self, seconds: float) -> None:
         if self._m:
             self.registry.get("serving_prefill_seconds").observe(seconds)
+
+    def observe_ttft(self, seconds: float) -> None:
+        if self._m:
+            self.registry.get("serving_ttft_seconds").observe(seconds)
+
+    def observe_itl(self, seconds: float) -> None:
+        if self._m:
+            self.registry.get("serving_itl_seconds").observe(seconds)
 
     def observe_queue_wait(self, seconds: float) -> None:
         if self._m:

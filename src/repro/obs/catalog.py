@@ -68,6 +68,12 @@ METRICS = (
                buckets=_SECONDS),
     MetricSpec("serving_queue_wait_seconds", "histogram",
                "Submit-to-prefill queue wait.", buckets=_SECONDS),
+    MetricSpec("serving_ttft_seconds", "histogram",
+               "Submit to the request's first token on the host.",
+               buckets=_SECONDS),
+    MetricSpec("serving_itl_seconds", "histogram",
+               "Gap between consecutive tokens of one request on the host.",
+               buckets=_SECONDS),
     MetricSpec("serving_prefill_bucket_tokens", "histogram",
                "Padded prefill bucket size in tokens (raw length when "
                "bucketing is off).", buckets=_TOKENS),

@@ -108,7 +108,7 @@ class FidelityProbe:
         """Sample-and-measure hook; returns the report dict when a probe
         ran, else None.  Read-only on all arguments."""
         try:
-            n_tok = int(jnp.asarray(batch1["tokens"]).shape[-1])
+            n_tok = int(batch1["tokens"].shape[-1])
             n_closed = n_tok // self._pol.buffer_size
             if not self._due(n_closed):
                 return None

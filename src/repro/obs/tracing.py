@@ -2,8 +2,8 @@
 
 A :class:`Tracer` records one :class:`RequestTrace` per rid through
 ``submit → admit/shed → prefill [prefix-hit, bucket, pages reserved] →
-splice → decode → retire``, plus instant events for retries, fault
-injections, and numeric-quarantine hits.  The scheduler drives the
+splice → first token … last token → retire``, plus instant events for
+retries, fault injections, and numeric-quarantine hits.  The scheduler drives the
 lifecycle; the engine — which never sees rids — contributes via a
 *bound* rid (:meth:`Tracer.bind` around ``view.prefill_slot``), through
 which it annotates the open prefill span and wraps the splice.
@@ -21,10 +21,13 @@ Design rules:
 
 Export is Chrome ``trace_event`` JSON (:meth:`Tracer.to_chrome`, load in
 ``chrome://tracing`` / Perfetto): each request is a ``tid``, spans are
-complete (``"ph": "X"``) events, instants are ``"ph": "i"``.  For
-wall-clock profiling of the jitted calls themselves,
-:func:`profiler_span` optionally opens a ``jax.profiler``
-``TraceAnnotation`` so prefill/decode show up named in XLA profiles.
+complete (``"ph": "X"``) events, instants are ``"ph": "i"``.
+
+Step phases are the other half: :class:`Phase` times one stretch of the
+serving loop (an admission, a decode step, the host work between steps)
+on ``time.perf_counter`` and opens a ``jax.profiler`` ``TraceAnnotation``
+of the same name, so the phase shows up on the profiler's timeline next
+to the device programs it dispatched (``Observability.phase``).
 """
 
 from __future__ import annotations
@@ -33,7 +36,8 @@ import contextlib
 import json
 import time
 
-__all__ = ["Span", "RequestTrace", "Tracer", "profiler_span", "TRACE_SCHEMA"]
+__all__ = ["Span", "RequestTrace", "Tracer", "Phase", "NULL_PHASE",
+           "no_phase", "TRACE_SCHEMA"]
 
 TRACE_SCHEMA = "gear-repro/trace/v1"
 
@@ -150,17 +154,6 @@ class Tracer:
         finally:
             self.end(rid)
 
-    def add_span(self, rid: int, name: str, dur: float, **args) -> None:
-        """Record an already-elapsed interval ending now (used for the
-        aggregate decode span, whose per-step timing lives in the
-        histogram)."""
-        tr = self.active.get(rid)
-        if tr is not None:
-            t1 = self.clock()
-            sp = Span(name, t1 - float(dur), args)
-            sp.t1 = t1
-            tr.spans.append(sp)
-
     def event(self, rid: int, name: str, **args) -> None:
         tr = self.active.get(rid)
         if tr is not None:
@@ -255,15 +248,37 @@ class Tracer:
         return json.dumps(self.to_chrome(), indent=indent, sort_keys=True)
 
 
-def profiler_span(name: str, enabled: bool):
-    """Context manager: a ``jax.profiler.TraceAnnotation`` when enabled
-    (so prefill/decode jit calls are named in XLA profiles), else a
-    no-op.  Import is lazy and failure-tolerant — tracing must work in
-    environments where the profiler is unavailable."""
-    if not enabled:
-        return contextlib.nullcontext()
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+NULL_PHASE = contextlib.nullcontext()
+
+
+def no_phase(name: str, **args):
+    """The phase recorder of a path without telemetry: records nothing."""
+    return NULL_PHASE
+
+
+class Phase:
+    """One step phase: a ``jax.profiler.TraceAnnotation`` named ``name``
+    (with ``args`` as its metadata) around the block, and
+    ``(name, t0, t1, args)`` in ``perf_counter`` seconds appended to
+    ``out`` when the block exits.  The annotation opens before ``t0`` and
+    closes after ``t1``, so the two agree to a few microseconds."""
+
+    __slots__ = ("out", "name", "args", "ann", "t0")
+
+    def __init__(self, out, annotation, name: str, args: dict):
+        self.out = out
+        self.name = name
+        self.args = args
+        self.ann = annotation(name, **args)
+        self.t0 = 0.0
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
+        self.out.append((self.name, self.t0, t1, self.args))
+        return False

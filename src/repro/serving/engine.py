@@ -44,9 +44,8 @@ from repro.kernels import ops as kernel_ops
 from repro.models import attention as attn_lib
 from repro.models.model import Model
 from repro.models.transformer import cache_cfg_for
-from repro.obs import Observability, ObsConfig
+from repro.obs import NULL_PHASE, Observability, ObsConfig
 from repro.obs.fidelity import FidelityProbe
-from repro.obs.tracing import profiler_span
 from repro.prefixcache import PrefixCache
 from repro.prefixcache import store as pc_store
 from repro.serving.pagedpool import PagePool, PagePoolStore, pages_needed
@@ -202,6 +201,14 @@ class EngineConfig:
             raise ValueError("pool_pages/pool_bytes only apply to layout='paged'")
 
 
+def _pad_tokens(tokens, n: int, nb: int) -> np.ndarray:
+    """A batch-1 prompt of ``n`` tokens right-padded to the next ``nb``
+    multiple, on the host: the bucketed prefill then compiles one program
+    per bucket, and no device program runs per raw length."""
+    toks = np.asarray(tokens, np.int32)
+    return np.pad(toks, ((0, 0), (0, -(-n // nb) * nb - n)))
+
+
 def prefix_cache_unsupported_reason(cfg, policy: CompressionPolicy,
                                     capacity: int) -> str | None:
     """Why this model/policy cannot take the prefix cache (None = it can).
@@ -242,7 +249,6 @@ class Engine:
         self._clock = clock
         # chaos hook (serving/faults.py); attach_faults wires it + the pool
         self._faults = None
-        self._finite_fn = jax.jit(cache_lib.tree_finite)
         cap = self._cap()
         # telemetry hub (repro.obs): the scheduler discovers it via
         # `engine.obs`; None when the knob is off (zero hot-path work)
@@ -264,10 +270,26 @@ class Engine:
             self._cache_shard = None
             self.params = params
 
-        self._prefill = jax.jit(
-            lambda p, b: model.prefill(p, b, ecfg.policy, cap,
-                                       prefill_mode=ecfg.prefill_mode,
-                                       fused=ecfg.fused))
+        # Every device program the served path dispatches is a jax.jit of a
+        # function named gear_*, so a profiler trace names each module
+        # (jit_gear_decode_step, ...) and device time can be read by program.
+        def gear_prefill(p, b):
+            return model.prefill(p, b, ecfg.policy, cap,
+                                 prefill_mode=ecfg.prefill_mode,
+                                 fused=ecfg.fused)
+
+        def gear_finite_guard(tree):
+            return cache_lib.tree_finite(tree)
+
+        def gear_sample(logits, key, step, sampler):
+            if step is not None:
+                key = jax.random.fold_in(key, step)
+            return (sampler(logits[:, -1], key, ecfg.temperature,
+                            ecfg.top_k), key)
+
+        self._prefill = jax.jit(gear_prefill)
+        self._finite_fn = jax.jit(gear_finite_guard)
+        self._sample = jax.jit(gear_sample, static_argnames="sampler")
         # Mixed-length serving: prefill_slot buckets a raw-length prompt up
         # to the next n_b multiple (the padded tail lands in the FP16
         # streaming buffer, never in a compressed chunk), so jit compiles
@@ -282,23 +304,26 @@ class Engine:
             and prefix_cache_unsupported_reason(self.cfg, ecfg.policy, cap)
             is None)
         if self._can_bucket:
-            self._prefill_bucketed = jax.jit(
-                lambda p, b, tl: model.prefill(
-                    p, b, ecfg.policy, cap, prefill_mode="streaming",
-                    fused=ecfg.fused, padded_tail=True, true_len=tl))
+            def gear_prefill_padded(p, b, tl):
+                return model.prefill(p, b, ecfg.policy, cap,
+                                     prefill_mode="streaming",
+                                     fused=ecfg.fused, padded_tail=True,
+                                     true_len=tl)
+            self._prefill_bucketed = jax.jit(gear_prefill_padded)
         if self.layout is CacheLayout.PAGED:
             self._init_paged(cap)
-            self._decode = jax.jit(
-                lambda p, tok, caches, pos, bt: model.decode_step(
-                    p, tok, caches, pos, ecfg.policy, cap, fused=ecfg.fused,
-                    block_tables=bt),
-                donate_argnums=(2,))
+
+            def gear_decode_step(p, tok, caches, pos, bt):
+                return model.decode_step(p, tok, caches, pos, ecfg.policy,
+                                         cap, fused=ecfg.fused,
+                                         block_tables=bt)
         else:
             self.pool = None
-            self._decode = jax.jit(
-                lambda p, tok, caches, pos: model.decode_step(
-                    p, tok, caches, pos, ecfg.policy, cap, fused=ecfg.fused),
-                donate_argnums=(2,))
+
+            def gear_decode_step(p, tok, caches, pos):
+                return model.decode_step(p, tok, caches, pos, ecfg.policy,
+                                         cap, fused=ecfg.fused)
+        self._decode = jax.jit(gear_decode_step, donate_argnums=(2,))
         # Slot splice: write a batch-1 cache tree over batch row `slot` of the
         # live (donated) cache.  Cache leaves are stacked [R, B, ...], so the
         # batch dim is axis 1 on every leaf (incl. RWKV/SSM states); the
@@ -311,12 +336,14 @@ class Engine:
         # (wider geometries would just trip XLA's unusable-donation
         # warning).  reset_slot must NOT donate its batch-1 tree — that is
         # the reusable `_fresh1` zero cache.
-        splice = lambda full, one, slot: cache_lib.splice_slot(full, one, slot, axis=1)
+        def gear_splice(full, one, slot):
+            return cache_lib.splice_slot(full, one, slot, axis=1)
+
         shard_kw = ({"out_shardings": self._cache_shard}
                     if self._cache_shard is not None else {})
-        self._splice = jax.jit(splice, donate_argnums=(0,), **shard_kw)
+        self._splice = jax.jit(gear_splice, donate_argnums=(0,), **shard_kw)
         self._splice_donate_one = (
-            jax.jit(splice, donate_argnums=(0, 1), **shard_kw)
+            jax.jit(gear_splice, donate_argnums=(0, 1), **shard_kw)
             if ecfg.batch == 1 else self._splice)  # identical program otherwise
         self._fresh1 = None  # lazily-built batch-1 empty cache (for reset_slot)
 
@@ -345,9 +372,10 @@ class Engine:
             # structure, which jit re-specializes on by itself.
             self._suffix_fns: dict[tuple[int, bool], Any] = {}
             self._extract_fns: dict[tuple[int, int], Any] = {}
-            self._splice_prefix = jax.jit(
-                lambda fresh, payloads: pc_store.splice_tree_chunks(
-                    self._cache_cfgs, fresh, 0, payloads))
+            def gear_prefix_splice(fresh, payloads):
+                return pc_store.splice_tree_chunks(self._cache_cfgs, fresh,
+                                                   0, payloads)
+            self._splice_prefix = jax.jit(gear_prefix_splice)
 
         # online compression-fidelity probes (repro.obs.fidelity): an fp16
         # shadow prefill of sampled prompts is the exact reference the
@@ -358,7 +386,9 @@ class Engine:
         # modalities/policies have nothing to compare.
         if (self.obs is not None and ecfg.obs.fidelity_every_n > 0
                 and not ecfg.policy.is_fp16 and self.cfg.modality == "text"):
-            ref_jit = jax.jit(lambda p, b: model.prefill(p, b, FP16, cap))
+            def gear_fidelity_prefill(p, b):
+                return model.prefill(p, b, FP16, cap)
+            ref_jit = jax.jit(gear_fidelity_prefill)
             self.obs.fidelity = FidelityProbe(
                 ref_prefill=lambda b: ref_jit(self.params, b),
                 cache_cfgs=[None if kind == "rwkv"
@@ -481,7 +511,11 @@ class Engine:
         """
         if self._faults is not None:
             one = self._faults.corrupt_tree(one)
-        if self.ecfg.numeric_guard and not bool(self._finite_fn(one)):
+        if not self.ecfg.numeric_guard:
+            return one
+        with self._phase("gear.guard"):
+            finite = bool(self._finite_fn(one))
+        if not finite:
             if self.obs is not None:
                 self.obs.quarantine()
                 self.obs.tracer.event_bound("quarantine")
@@ -491,9 +525,10 @@ class Engine:
         return one
 
     # -- observability hooks -------------------------------------------
-    @property
-    def _prof(self) -> bool:
-        return self.obs is not None and self.obs.cfg.profiler
+    def _phase(self, name: str):
+        """Step phase on the engine's telemetry (``ObsConfig.profiler``);
+        a no-op without obs."""
+        return NULL_PHASE if self.obs is None else self.obs.phase(name)
 
     def _span(self, name: str):
         """Trace span on the scheduler-bound rid; no-op without obs."""
@@ -510,7 +545,7 @@ class Engine:
         o = self.obs
         if o is None:
             return
-        plen = int(np.asarray(batch1["tokens"]).shape[-1])
+        plen = int(batch1["tokens"].shape[-1])
         nb = self.ecfg.policy.buffer_size
         bucket = (plen + nb - 1) // nb * nb if self._can_bucket else plen
         o.observe_bucket(bucket)
@@ -621,22 +656,29 @@ class Engine:
         """
         n = batch1["tokens"].shape[1]
         nb = self.ecfg.policy.buffer_size
-        with profiler_span("gear.prefill", self._prof), self.mesh_context():
+        with self._phase("gear.prefill"), self.mesh_context():
             if not self._can_bucket or n % nb == 0:
                 return self._prefill(self.params, batch1)
-            n_bucket = (n + nb - 1) // nb * nb
-            toks = jnp.asarray(batch1["tokens"], jnp.int32)
-            padded = {"tokens": jnp.pad(toks, ((0, 0), (0, n_bucket - n)))}
-            return self._prefill_bucketed(self.params, padded, jnp.int32(n))
+            padded = {"tokens": _pad_tokens(batch1["tokens"], n, nb)}
+            return self._prefill_bucketed(self.params, padded, np.int32(n))
 
     def decode(self, token_batch: dict, caches, pos):
         """One decode step.  ``pos``: scalar or per-slot [B] int32 vector."""
-        with profiler_span("gear.decode", self._prof), self.mesh_context():
+        with self._phase("gear.decode"), self.mesh_context():
             if self.layout is CacheLayout.PAGED:
                 return self._decode(self.params, token_batch, caches,
                                     jnp.asarray(pos, jnp.int32), self._bt)
             return self._decode(self.params, token_batch, caches,
                                 jnp.asarray(pos, jnp.int32))
+
+    def sample_next(self, logits, key, step=None, sampler=sample):
+        """Next token of each row from ``logits``' last position, and the
+        key it was drawn with: ``key`` folded with ``step`` (a decode step)
+        or ``key`` itself (``step=None``: a prefill's first token).
+        ``sampler`` is the token rule (``repro.serving.sampling.sample``'s
+        signature; the scheduler passes its own).  One program
+        (``gear_sample``) per rule; the tokens stay on the device."""
+        return self._sample(logits, key, step, sampler=sampler)
 
     # -- slot-level continuous batching --------------------------------
     def prefill_slot(self, batch1: dict, caches, slot: int, admit: bool = True,
@@ -691,7 +733,7 @@ class Engine:
             self._obs_prefill(batch1, logits, one)
             with self._span("splice"):
                 return logits, self._splice_donate_one(
-                    caches, one, jnp.asarray(slot, jnp.int32))
+                    caches, one, np.int32(slot))
         tokens = np.asarray(batch1["tokens"][0])
         nb = self.ecfg.policy.buffer_size
         n = tokens.shape[0]
@@ -715,7 +757,7 @@ class Engine:
             self.prefix_cache.release(match)
         with self._span("splice"):
             return logits, self._splice_donate_one(
-                caches, one, jnp.asarray(slot, jnp.int32))
+                caches, one, np.int32(slot))
 
     def _prefill_suffix(self, tokens: np.ndarray, n_hit: int, one1):
         """Run the (possibly bucketed) suffix after an ``n_hit``-chunk trie
@@ -723,16 +765,13 @@ class Engine:
         nb = self.ecfg.policy.buffer_size
         suf = np.asarray(tokens[n_hit * nb:], np.int32)
         n_suf = suf.shape[0]
-        with (profiler_span("gear.prefill_suffix", self._prof),
-              self.mesh_context()):
+        with self._phase("gear.prefill_suffix"), self.mesh_context():
             if n_suf % nb == 0:
-                suffix = {"tokens": jnp.asarray(suf[None], jnp.int32)}
-                return self._suffix_fn(n_hit)(self.params, suffix, one1)
-            n_bucket = (n_suf + nb - 1) // nb * nb
-            padded = {"tokens": jnp.pad(jnp.asarray(suf[None], jnp.int32),
-                                        ((0, 0), (0, n_bucket - n_suf)))}
+                return self._suffix_fn(n_hit)(self.params,
+                                              {"tokens": suf[None]}, one1)
+            padded = {"tokens": _pad_tokens(suf[None], n_suf, nb)}
             return self._suffix_fn(n_hit, padded_tail=True)(
-                self.params, padded, one1, jnp.int32(n_suf))
+                self.params, padded, one1, np.int32(n_suf))
 
     def _prefill_slot_paged(self, batch1, caches, slot, admit, reserve_tokens):
         nb = self.ecfg.policy.buffer_size
@@ -774,13 +813,13 @@ class Engine:
             self._obs_prefill(batch1, logits, one, n_hit=n_hit,
                               pages_reserved=n_total)
             n_sc = n_closed - n_hit
-            with self._span("splice"):
+            with self._span("splice"), self._phase("gear.splice"):
                 caches = self._paged_splice_fn(n_hit)(
                     caches, one,
-                    jnp.asarray(fresh[n_sc:], jnp.int32),   # reserved: zero
-                    jnp.asarray(fresh[:n_sc], jnp.int32),   # closed: scatter
-                    jnp.asarray(slot, jnp.int32))
-            self._bt = jnp.asarray(self.pool.block_tables)
+                    np.asarray(fresh[n_sc:], np.int32),    # reserved: zero
+                    np.asarray(fresh[:n_sc], np.int32),    # closed: scatter
+                    np.int32(slot))
+                self._bt = jnp.asarray(self.pool.block_tables)
             if self.prefix_cache is not None and admit and n_closed > n_hit:
                 row = self.pool.block_tables[slot]
                 self.prefix_cache.insert(
@@ -799,7 +838,7 @@ class Engine:
         prefix chunk offset; jit re-specializes on the page-count shapes."""
         fn = self._paged_splice_fns.get(c_lo)
         if fn is None:
-            def impl(caches, one, zero_pages, sc_pages, slot):
+            def gear_paged_splice(caches, one, zero_pages, sc_pages, slot):
                 n_sc = sc_pages.shape[0]
                 out = []
                 for i, flag in enumerate(self._paged_flags):
@@ -828,7 +867,7 @@ class Engine:
                     out.append(dataclasses.replace(lyr, **sub))
                 return tuple(out)
 
-            fn = jax.jit(impl, donate_argnums=(0,))
+            fn = jax.jit(gear_paged_splice, donate_argnums=(0,))
             self._paged_splice_fns[c_lo] = fn
         return fn
 
@@ -850,7 +889,9 @@ class Engine:
         # prefix_cache requires every layer paged-capable, so per_pos covers
         # all positions; jit re-specializes per distinct page count
         if not hasattr(self, "_gather_fn"):
-            self._gather_fn = jax.jit(self._gather_scaffold_impl)
+            def gear_prefix_gather(caches, fresh, pages):
+                return self._gather_scaffold_impl(caches, fresh, pages)
+            self._gather_fn = jax.jit(gear_prefix_gather)
         return self._gather_fn(caches, fresh, pages)
 
     def _fresh_batch1(self):
@@ -878,16 +919,17 @@ class Engine:
         if fn is None:
             start = n_pre_chunks * self.ecfg.policy.buffer_size
             if padded_tail:
-                fn = jax.jit(
-                    lambda p, b, c1, tl: self.model.prefill_suffix(
+                def gear_prefill_suffix_padded(p, b, c1, tl):
+                    return self.model.prefill_suffix(
                         p, b, c1, start, self.ecfg.policy, self._cap(),
-                        fused=self.ecfg.fused, padded_tail=True,
-                        true_len=tl))
+                        fused=self.ecfg.fused, padded_tail=True, true_len=tl)
+                fn = jax.jit(gear_prefill_suffix_padded)
             else:
-                fn = jax.jit(
-                    lambda p, b, c1: self.model.prefill_suffix(
+                def gear_prefill_suffix(p, b, c1):
+                    return self.model.prefill_suffix(
                         p, b, c1, start, self.ecfg.policy, self._cap(),
-                        fused=self.ecfg.fused))
+                        fused=self.ecfg.fused)
+                fn = jax.jit(gear_prefill_suffix)
             self._suffix_fns[(n_pre_chunks, padded_tail)] = fn
         return fn
 
@@ -895,8 +937,10 @@ class Engine:
         """Jitted chunk extraction from a batch-1 cache tree."""
         fn = self._extract_fns.get((c_lo, c_hi))
         if fn is None:
-            fn = jax.jit(lambda caches: pc_store.extract_tree_chunks(
-                self._cache_cfgs, caches, c_lo, c_hi))
+            def gear_prefix_extract(caches):
+                return pc_store.extract_tree_chunks(self._cache_cfgs, caches,
+                                                    c_lo, c_hi)
+            fn = jax.jit(gear_prefix_extract)
             self._extract_fns[(c_lo, c_hi)] = fn
         return fn
 
@@ -911,7 +955,7 @@ class Engine:
             self.pool.release_slot(slot)
             self._bt = jnp.asarray(self.pool.block_tables)
             if not hasattr(self, "_paged_reset_fn"):
-                def impl(caches, fresh1, slot):
+                def gear_paged_reset(caches, fresh1, slot):
                     out = []
                     for i, flag in enumerate(self._paged_flags):
                         if not flag:
@@ -926,11 +970,12 @@ class Engine:
                             slot, axis=1)
                         out.append(dataclasses.replace(caches[i], **sub))
                     return tuple(out)
-                self._paged_reset_fn = jax.jit(impl, donate_argnums=(0,))
+                self._paged_reset_fn = jax.jit(gear_paged_reset,
+                                               donate_argnums=(0,))
             return self._paged_reset_fn(caches, self._fresh_batch1(),
-                                        jnp.asarray(slot, jnp.int32))
+                                        np.int32(slot))
         return self._splice(caches, self._fresh_batch1(),
-                            jnp.asarray(slot, jnp.int32))
+                            np.int32(slot))
 
     def reclaim_pages(self, n_pages: int) -> int:
         """Evict prefix-trie entries until ``n_pages`` pool pages came free
@@ -959,7 +1004,7 @@ class Engine:
         prompt_len = self._prompt_len(batch)
         B = logits.shape[0]
 
-        tok = sample(logits[:, -1], key, ecfg.temperature, ecfg.top_k)
+        tok, key = self.sample_next(logits, key)
         out = [tok]
         done = jnp.zeros(tok.shape[:1], bool)
         t1 = time.time()
@@ -969,8 +1014,7 @@ class Engine:
             # continuous-batching path, where positions genuinely differ.
             pos = jnp.full((B,), prompt_len + t, jnp.int32)
             logits, caches = self.decode(tb, caches, pos)
-            key = jax.random.fold_in(key, t)
-            tok = sample(logits[:, -1], key, ecfg.temperature, ecfg.top_k)
+            tok, key = self.sample_next(logits, key, t)
             if ecfg.eos_id >= 0:
                 done = done | (tok == ecfg.eos_id) if cfg.modality != "audio" else done
                 tok = jnp.where(done, ecfg.eos_id, tok) if cfg.modality != "audio" else tok

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cache import NumericFault
+from repro.obs import no_phase
 from repro.serving.engine import Engine
 from repro.serving.faults import InjectedFault
 from repro.serving.pagedpool import PoolExhausted, pages_needed
@@ -309,6 +310,11 @@ class Scheduler:
         pstats0 = (eng.prefix_cache.snapshot()
                    if eng.prefix_cache is not None else None)
         obs = self.obs
+        # step phases (ObsConfig.profiler): the loop's host time splits into
+        # sched.admission, sched.decode and sched.bookkeeping (the rest)
+        phase = obs.phase if obs is not None else no_phase
+        recording = obs is not None and obs.phases is not None
+        open_phases: dict = {}             # phases that span loop sections
         pos = np.zeros(B, np.int32)        # per-slot absolute decode position
         budget = np.zeros(B, np.int32)     # per-slot remaining-token budget
         done = np.ones(B, bool)            # per-slot idle flag
@@ -318,6 +324,7 @@ class Scheduler:
         cur = np.zeros(B, np.int32)        # last sampled token per slot
         prefill_s = np.zeros(B)
         decode_s = np.zeros(B)
+        last_tok_t = np.zeros(B)           # when each slot's last token arrived
         steps = 0
         t_decode_total = 0.0
         t_all = time.time()
@@ -325,6 +332,15 @@ class Scheduler:
         degraded: set[int] = set()      # completed-but-impaired rids
         not_before = 0.0                # admission backoff gate (sched clock)
         dec_faults = 0                  # consecutive failed decode steps
+
+        def phase_open(name: str, **args) -> None:
+            if recording and name not in open_phases:
+                open_phases[name] = ph = phase(name, **args)
+                ph.__enter__()
+
+        def phase_close(name: str) -> None:
+            if recording and name in open_phases:
+                open_phases.pop(name).__exit__(None, None, None)
 
         def expired(r: Request) -> bool:
             return (r.deadline_s is not None
@@ -373,8 +389,7 @@ class Scheduler:
                 error=error))
             if obs is not None:
                 obs.result(str(status))
-                obs.tracer.add_span(r.rid, "decode", float(decode_s[s]),
-                                    steps=len(toks_buf[s]))
+                obs.tracer.event(r.rid, "last_token", tokens=len(toks_buf[s]))
                 if error:
                     obs.tracer.event(r.rid, "terminal", error=error)
                 obs.tracer.finish(r.rid, str(status))
@@ -406,6 +421,17 @@ class Scheduler:
             slot's state may have changed (spliced, or the head resolved
             terminally — the admission loop may try the next request);
             False when the head was requeued for a later retry."""
+            phase_close("sched.bookkeeping")
+            # queue pop to the first token on the host
+            phase_open("sched.admission", slot=s,
+                       prompt_tokens=len(self.queue[0].tokens))
+            try:
+                return admit(s)
+            finally:
+                phase_close("sched.admission")
+                phase_open("sched.bookkeeping")
+
+        def admit(s: int) -> bool:
             r = self.queue.popleft()
             if expired(r):
                 terminal(r, RequestStatus.TIMEOUT,
@@ -422,12 +448,12 @@ class Scheduler:
                 obs.tracer.begin(r.rid, "prefill",
                                  attempt=attempts.get(r.rid, 0) + 1, slot=s)
                 obs.tracer.bind(r.rid)
-            t0 = time.time()
+            t0 = time.perf_counter()
             try:
                 if self._faults is not None:
                     self._faults.check_step("prefill")
                 logits = view.prefill_slot(
-                    {"tokens": jnp.asarray(prompt, jnp.int32)}, s,
+                    {"tokens": prompt}, s,
                     admit=self.prefix_admission == "all",
                     reserve_tokens=self._need_tokens(r))
             except PoolExhausted as e:
@@ -453,9 +479,14 @@ class Scheduler:
                     obs.tracer.unbind()
                     obs.tracer.end(r.rid)   # close "prefill"
             first = int(np.asarray(
-                sample(logits[:, -1], key, eng.ecfg.temperature, eng.ecfg.top_k))[0])
-            prefill_s[s] = time.time() - t0
+                eng.sample_next(logits, key, sampler=sample)[0])[0])
+            prefill_s[s] = time.perf_counter() - t0
+            phase_close("sched.admission")
             if obs is not None:
+                now = self._clock()
+                last_tok_t[s] = now
+                obs.observe_ttft(max(now - self._submit_t.get(r.rid, now), 0.0))
+                obs.tracer.event(r.rid, "first_token")
                 obs.observe_prefill(float(prefill_s[s]))
             fresh[s] = False
             reqs[s] = r
@@ -475,6 +506,7 @@ class Scheduler:
             return (self._clock() >= not_before
                     and view.can_admit(self._need_tokens(self.queue[0])))
 
+        phase_open("sched.bookkeeping")
         while self.queue or not bool(done.all()):
             if self._faults is not None:
                 self._faults.tick(eng)
@@ -542,23 +574,30 @@ class Scheduler:
                         self._sleep(self.retry.backoff(dec_faults))
                     continue
                 dec_faults = 0
-            t0 = time.time()
-            tb = {"tokens": jnp.asarray(cur[:, None])}
-            logits = view.decode(tb, pos)
-            key = jax.random.fold_in(key, steps)
-            nxt = np.asarray(sample(logits[:, -1], key,
-                                    eng.ecfg.temperature, eng.ecfg.top_k))
-            step_t = time.time() - t0
+            phase_close("sched.bookkeeping")
+            with phase("sched.decode"):
+                t0 = time.perf_counter()
+                tb = {"tokens": jnp.asarray(cur[:, None])}
+                logits = view.decode(tb, pos)
+                with phase("sched.token_read"):
+                    tok_dev, key = eng.sample_next(logits, key, steps,
+                                                   sampler=sample)
+                    nxt = np.asarray(tok_dev)
+                step_t = time.perf_counter() - t0
+            phase_open("sched.bookkeeping")
             t_decode_total += step_t
             steps += 1
             pos += 1  # idle slots advance harmlessly; a splice rewrites pos[s]
             active_slots = np.nonzero(~done)[0]
             if obs is not None:
+                now = self._clock()
                 obs.decode_step(step_t, len(active_slots))
                 obs.queue_depth(len(self.queue))
             for s in active_slots:
                 decode_s[s] += step_t
                 if obs is not None:
+                    obs.observe_itl(now - last_tok_t[s])
+                    last_tok_t[s] = now
                     obs.tracer.step(reqs[s].rid)
                 tok = int(nxt[s])
                 toks_buf[s].append(tok)
@@ -569,6 +608,7 @@ class Scheduler:
                     finish(s, status=RequestStatus.TIMEOUT,
                            error=f"deadline {reqs[s].deadline_s}s elapsed "
                                  "mid-decode")
+        phase_close("sched.bookkeeping")
 
         self.last_stats = {
             "wall_s": time.time() - t_all,

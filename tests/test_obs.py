@@ -17,7 +17,9 @@ Three layers under test:
 """
 
 import dataclasses
+import glob
 import json
+import os
 
 import numpy as np
 import pytest
@@ -27,7 +29,7 @@ import jax
 from repro.configs.base import ModelConfig
 from repro.core.policy import named_policy
 from repro.models.model import build_model
-from repro.obs import Observability, ObsConfig
+from repro.obs import NULL_PHASE, Observability, ObsConfig
 from repro.obs.catalog import METRICS, build_registry
 from repro.obs.registry import (METRICS_SCHEMA, CardinalityError, Registry,
                                 parse_prometheus)
@@ -401,3 +403,132 @@ def test_prefix_counters_are_per_run_deltas():
     # the registry counter tracks the lifetime total via sync_counter
     assert (eng.obs.registry.get("prefix_expiries_total").value()
             == st5["prefix"].expiries)
+
+
+# ---------------------------------------------------------------------------
+# Step phases (ObsConfig.profiler) and named programs
+
+
+def test_phase_recorder_off_by_default(monkeypatch):
+    opened = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name, **a: opened.append(name))
+    o = Observability(ObsConfig())
+    assert o.phases is None
+    ph = o.phase("sched.decode", slot=1)
+    assert ph is NULL_PHASE
+    with ph:
+        pass
+    assert opened == []
+
+
+def _phase_engine():
+    if "phase_engine" not in _SHARED:
+        m, params = _model()
+        _SHARED["phase_engine"] = Engine(
+            m, params, EngineConfig(
+                batch=2, capacity=48, policy=_small(), eos_id=-1,
+                layout="paged", prefill_mode="streaming",
+                obs=ObsConfig(metrics=True, tracing=False, profiler=True)))
+    return _SHARED["phase_engine"]
+
+
+def _run_phases(eng, reqs):
+    eng.obs.phases.clear()
+    sched = Scheduler(eng)
+    for r in reqs:
+        sched.submit(r)
+    results = sched.run_continuous()
+    return sched, results, list(eng.obs.phases)
+
+
+def _within(inner, outers) -> bool:
+    return any(o[1] <= inner[1] and inner[2] <= o[2] for o in outers)
+
+
+def test_phases_of_a_continuous_run():
+    eng = _phase_engine()
+    reqs = _requests()
+    sched, results, ph = _run_phases(eng, reqs)
+    by = {}
+    for p in ph:
+        by.setdefault(p[0], []).append(p)
+    steps = sched.last_stats["decode_steps"]
+    # one admission per admitted request, one decode and token read a step
+    assert len(by["sched.admission"]) == len(reqs)
+    assert sorted(a[3]["prompt_tokens"] for a in by["sched.admission"]) == \
+        sorted(len(r.tokens) for r in reqs)
+    assert len(by["sched.decode"]) == len(by["sched.token_read"]) == steps
+    assert len(by["gear.decode"]) == steps
+    # nesting: the step's dispatch and token read inside its decode phase,
+    # the engine's prefill, guard and splice inside the admission
+    for inner, outer in (("sched.token_read", "sched.decode"),
+                         ("gear.decode", "sched.decode"),
+                         ("gear.prefill", "sched.admission"),
+                         ("gear.guard", "sched.admission"),
+                         ("gear.splice", "sched.admission")):
+        assert by[inner] and all(_within(p, by[outer]) for p in by[inner])
+    # the bookkeeping between steps overlaps no admission and no step
+    loop = sorted(by["sched.admission"] + by["sched.decode"]
+                  + by["sched.bookkeeping"], key=lambda p: p[1])
+    assert all(a[2] <= b[1] for a, b in zip(loop, loop[1:]))
+    # first and last tokens reach the operators' histograms
+    reg = eng.obs.registry
+    ttft = reg.get("serving_ttft_seconds").series()[0]
+    itl = reg.get("serving_itl_seconds").series()[0]
+    assert ttft["count"] >= len(reqs)
+    n_tok = sum(len(r.tokens) for r in results)
+    assert itl["count"] >= n_tok - len(reqs)
+
+
+def test_served_programs_are_named_and_annotated(tmp_path):
+    """Every device program the continuous loop runs is a ``jit_gear_*``
+    module, and each recorded phase is one profiler annotation."""
+    eng = _phase_engine()
+    _run_phases(eng, _requests())              # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _, _, ph = _run_phases(eng, _requests(seed=1))
+    finally:
+        jax.profiler.stop_trace()
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    ann, ops = [], []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("sched.", "gear.")):
+                    ann.append((e.start_ns, e.duration_ns, e.name))
+                else:
+                    st = dict(e.stats)
+                    if "hlo_module" in st:
+                        ops.append((e.start_ns, st["hlo_module"]))
+    ann.sort()
+    assert [a[2] for a in ann] == [p[0] for p in sorted(ph, key=lambda p: p[1])]
+    # from the first admission on (the run's set-up builds its caches and
+    # key before it), nothing runs but the engine's named programs
+    t0 = min(a[0] for a in ann if a[2] == "sched.admission")
+    mods = {m for t, m in ops if t >= t0}
+    assert {"jit_gear_decode_step", "jit_gear_prefill_padded",
+            "jit_gear_finite_guard", "jit_gear_paged_splice",
+            "jit_gear_sample"} <= mods
+    assert all(m.startswith("jit_gear_") for m in mods), mods
+
+
+def test_trace_records_first_and_last_token():
+    eng = _obs_engine()
+    eng.obs.tracer.reset()
+    sched = Scheduler(eng)
+    reqs = _requests()
+    for r in reqs:
+        sched.submit(r)
+    results = sched.run_continuous()
+    got = {r.rid: len(r.tokens) for r in results}
+    for tr in eng.obs.tracer.completed:
+        names = [n for n, _, _ in tr.events]
+        assert names.count("first_token") == names.count("last_token") == 1
+        first = next(t for n, t, _ in tr.events if n == "first_token")
+        last, args = next((t, a) for n, t, a in tr.events if n == "last_token")
+        assert first <= last and args["tokens"] >= got[tr.rid]
+        assert "decode" not in {s.name for s in tr.spans}
