@@ -24,14 +24,19 @@ to lane-dim 128 tiles; the chunk dim (n_b = 64/128) is the sublane dim.
 
 **Ragged batches.**  ``n_comp`` may be a scalar (all slots at one extent) or
 a per-row ``[BH]`` vector: each (bh, c) grid program reads its own row's
-compressed extent and masks chunk scores past it, so mixed-length continuous
-batches run the fused path directly.  A row at extent 0 accumulates an
-all-masked (uniform) softmax over its own cache rows: when the row's buffer
-holds tokens, the caller's ``exp(m - m_tot)`` correction zeroes that weight;
-when the row is fully empty (length 0), the correction is exp(0) = 1 and the
-output is the mean of the slot's cache rows — zeros because ``reset_slot``
-zeroes the slot's bytes, exactly matching the oracle.  Either way the math is
-per-row only (no cross-slot leakage, no NaN).
+compressed extent, so mixed-length continuous batches run the fused path
+directly.  A row does work only on its ``ceil(n_comp / chunk)`` live chunks:
+past them a grid step runs no body, and scores past the extent inside the
+last live chunk are masked, so the bytes of dead chunks never reach the math
+and may hold anything.  The index maps stay plain: the pipeline may still
+fetch a dead chunk's blocks (not where the index repeats, as the paged
+layout's dead table entries, all page 0, do); on a v5e that costs less than
+clamping the index maps on every grid step.  A row at extent 0 keeps its
+init triple ``(0, NEG_INF, 0)``: the caller's ``exp(m - m_tot)`` correction
+zeroes it when the row's buffer holds tokens, and a fully empty row (length
+0) merges to the mean of its all-masked buffer rows, zeros because
+``reset_slot`` zeroes them, exactly matching the oracle.  Either way the math
+is per-row only (no cross-slot leakage, no NaN).
 """
 
 from __future__ import annotations
@@ -89,72 +94,79 @@ def _kernel(n_comp_ref, q_ref, kp_ref, ks_ref, kz_ref, vp_ref, vs_ref, vz_ref,
             use_lr: bool, use_sp: bool):
     bh = pl.program_id(0)
     c = pl.program_id(1)
-    nb = chunk
-    q = q_ref[0].astype(jnp.float32)                       # [G, Dh]
-    G, Dh = q.shape
+    n = n_comp_ref[bh]
 
-    # ---- K chunk: dequant + outliers --------------------------------------
-    k_tile = _unpack(kp_ref[0], bits, Dh)                  # [nb, Dh]
-    k_tile = k_tile * _window_row(ks_ref, c) + _window_row(kz_ref, c)
-    if use_sp:
-        # [Dh, Ks] -> [Ks, Dh]: channel on lanes, like the tile
-        ksv = ksv_ref[0, 0].astype(jnp.float32).T
-        ksi = ksi_ref[0, 0].T
-        row = jax.lax.broadcasted_iota(jnp.int32, (nb, Dh), 0)
-        for j in range(ksv.shape[0]):
-            k_tile += jnp.where(row == ksi[j:j + 1], ksv[j:j + 1], 0.0)
-
-    s = jax.lax.dot_general(q, k_tile, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [G, nb]
-    if use_lr:
-        kb = kb_ref[0, 0].astype(jnp.float32)              # [Dh, r]
-        ka = ka_ref[0].astype(jnp.float32)                 # [nb, r]
-        qb = jax.lax.dot_general(q, kb, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)  # [G, r]
-        s += jax.lax.dot_general(qb, ka, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-    s = s * scale_factor
-
-    tok = c * nb + jax.lax.broadcasted_iota(jnp.int32, (G, nb), 1)
-    s = jnp.where(tok < n_comp_ref[bh], s, NEG_INF)
-
-    # ---- V chunk ------------------------------------------------------------
-    v_tile = _unpack(vp_ref[0], bits, Dh)
-    gv = vs_ref.shape[-1]
-    vsc = jnp.repeat(vs_ref[0].astype(jnp.float32), Dh // gv, axis=-1)
-    vzr = jnp.repeat(vz_ref[0].astype(jnp.float32), Dh // gv, axis=-1)
-    v_tile = v_tile * vsc + vzr
-    if use_sp:
-        vsv = vsv_ref[0].astype(jnp.float32)               # [nb, Kv]
-        vsi = vsi_ref[0]
-        col = jax.lax.broadcasted_iota(jnp.int32, (nb, Dh), 1)
-        for j in range(vsv.shape[-1]):
-            v_tile += jnp.where(col == vsi[:, j][:, None], vsv[:, j][:, None], 0.0)
-
-    # ---- online softmax -----------------------------------------------------
     @pl.when(c == 0)
     def _init():
         acc_ref[0] = jnp.zeros_like(acc_ref[0])
         m_ref[0] = jnp.full_like(m_ref[0], NEG_INF)
         l_ref[0] = jnp.zeros_like(l_ref[0])
 
-    m_prev = m_ref[0][:, 0]                                # [G]
-    m_cur = jnp.max(s, axis=-1)
-    m_new = jnp.maximum(m_prev, m_cur)
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])                        # [G, nb]
-    l_ref[0] = l_ref[0] * corr[:, None] + jnp.sum(p, axis=-1)[:, None]
-    pv = jax.lax.dot_general(p, v_tile, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    if use_lr:
-        va = va_ref[0].astype(jnp.float32)                 # [nb, r]
-        vb = vb_ref[0, 0].astype(jnp.float32)              # [Dh, r]
-        pa = jax.lax.dot_general(p, va, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)  # [G, r]
-        pv += jax.lax.dot_general(pa, vb, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-    acc_ref[0] = acc_ref[0] * corr[:, None] + pv
-    m_ref[0] = jnp.broadcast_to(m_new[:, None], m_ref[0].shape)
+    # live chunks are the first ceil(n / chunk); a chunk past them would
+    # add corr = 1, p = 0, so its step runs no body
+    @pl.when(c * chunk < n)
+    def _chunk():
+        nb = chunk
+        q = q_ref[0].astype(jnp.float32)                       # [G, Dh]
+        G, Dh = q.shape
+
+        # ---- K chunk: dequant + outliers ----------------------------------
+        k_tile = _unpack(kp_ref[0], bits, Dh)                  # [nb, Dh]
+        k_tile = k_tile * _window_row(ks_ref, c) + _window_row(kz_ref, c)
+        if use_sp:
+            # [Dh, Ks] -> [Ks, Dh]: channel on lanes, like the tile
+            ksv = ksv_ref[0, 0].astype(jnp.float32).T
+            ksi = ksi_ref[0, 0].T
+            row = jax.lax.broadcasted_iota(jnp.int32, (nb, Dh), 0)
+            for j in range(ksv.shape[0]):
+                k_tile += jnp.where(row == ksi[j:j + 1], ksv[j:j + 1], 0.0)
+
+        s = jax.lax.dot_general(q, k_tile, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # [G, nb]
+        if use_lr:
+            kb = kb_ref[0, 0].astype(jnp.float32)              # [Dh, r]
+            ka = ka_ref[0].astype(jnp.float32)                 # [nb, r]
+            qb = jax.lax.dot_general(q, kb, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)  # [G, r]
+            s += jax.lax.dot_general(qb, ka, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        s = s * scale_factor
+
+        tok = c * nb + jax.lax.broadcasted_iota(jnp.int32, (G, nb), 1)
+        s = jnp.where(tok < n, s, NEG_INF)
+
+        # ---- V chunk --------------------------------------------------------
+        v_tile = _unpack(vp_ref[0], bits, Dh)
+        gv = vs_ref.shape[-1]
+        vsc = jnp.repeat(vs_ref[0].astype(jnp.float32), Dh // gv, axis=-1)
+        vzr = jnp.repeat(vz_ref[0].astype(jnp.float32), Dh // gv, axis=-1)
+        v_tile = v_tile * vsc + vzr
+        if use_sp:
+            vsv = vsv_ref[0].astype(jnp.float32)               # [nb, Kv]
+            vsi = vsi_ref[0]
+            col = jax.lax.broadcasted_iota(jnp.int32, (nb, Dh), 1)
+            for j in range(vsv.shape[-1]):
+                v_tile += jnp.where(col == vsi[:, j][:, None],
+                                    vsv[:, j][:, None], 0.0)
+
+        # ---- online softmax -------------------------------------------------
+        m_prev = m_ref[0][:, 0]                                # [G]
+        m_cur = jnp.max(s, axis=-1)
+        m_new = jnp.maximum(m_prev, m_cur)
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, None])                        # [G, nb]
+        l_ref[0] = l_ref[0] * corr[:, None] + jnp.sum(p, axis=-1)[:, None]
+        pv = jax.lax.dot_general(p, v_tile, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        if use_lr:
+            va = va_ref[0].astype(jnp.float32)                 # [nb, r]
+            vb = vb_ref[0, 0].astype(jnp.float32)              # [Dh, r]
+            pa = jax.lax.dot_general(p, va, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)  # [G, r]
+            pv += jax.lax.dot_general(pa, vb, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        acc_ref[0] = acc_ref[0] * corr[:, None] + pv
+        m_ref[0] = jnp.broadcast_to(m_new[:, None], m_ref[0].shape)
 
 
 @functools.partial(
@@ -268,11 +280,11 @@ def gear_decode_paged(
     ``PrefetchScalarGridSpec`` so every BlockSpec index map can compute its
     DMA source ``row = bt[bh // H, c] * H + bh % H`` before the grid step
     runs — the gather happens in the DMA engine, not as kernel gather ops.
-    Because the pool's page 0 is the reserved zero page and fresh pages are
-    zeroed at admission, out-of-extent table entries stream the same zero
-    bytes the dense layout holds there, and the accumulated (acc, m, l)
-    triple is bit-identical to :func:`gear_decode` on the gathered-dense
-    cache.  ``n_comp`` masking is unchanged (ragged per-row extents).
+    Pages behind table entries past a row's live chunks never reach the
+    math (the body skips them), so the accumulated (acc, m, l) triple is
+    bit-identical to :func:`gear_decode` on the gathered-dense cache
+    whatever those entries point at.  ``n_comp`` handling is unchanged
+    (ragged per-row extents).
     """
     BH, G, Dh = q.shape
     B, C = block_tables.shape

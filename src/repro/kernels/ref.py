@@ -63,8 +63,10 @@ def gear_decode_ref(
     """Unnormalized online-softmax decode attention over a GEAR cache.
 
     ``n_comp`` may be a scalar (uniform extent) or a per-row ``[BH]`` vector
-    (ragged continuous batches): scores past each row's own extent are
-    masked, so every output row depends only on its own slot's cache.
+    (ragged continuous batches): positions past each row's own extent get
+    no weight, so every output row depends only on its own slot's cache.
+    A row at extent 0 returns ``(0, NEG_INF, 0)``, the kernel's init triple
+    (it works on no chunk); the caller's buffer merge gives it zero weight.
     Returns (acc [BH, G, Dh] f32 exp-weighted V sum, m [BH, G] score max,
     l [BH, G] sum of exp) so the caller can merge the fp16 buffer region.
     """
@@ -91,7 +93,9 @@ def gear_decode_ref(
     s = jnp.where(valid[:, None, :], s, NEG_INF)
 
     m = jnp.max(s, axis=-1)
-    p = jnp.exp(s - m[..., None])
+    # exp(NEG_INF - m) is already 0 for a row with a live token; the select
+    # zeroes an empty row's exp(0) too
+    p = jnp.where(valid[:, None, :], jnp.exp(s - m[..., None]), 0.0)
     l = jnp.sum(p, axis=-1)
 
     gv = v_scale.shape[-1]
@@ -251,7 +255,9 @@ def gear_hist_block_ref(
     and a vals-only scatter — so they ride the two big score/value GEMMs
     instead of paying XLA's small-einsum overhead once per scanned chunk.
     The factored forms stay in ``gear_decode`` where they belong (VMEM
-    residency on TPU).  Returns (acc [BH, G, Dh], m [BH, G], l [BH, G]).
+    residency on TPU).  A row at extent 0 returns ``(0, NEG_INF, 0)``, as
+    :func:`gear_decode_ref` does.  Returns (acc [BH, G, Dh], m [BH, G],
+    l [BH, G]).
     """
     BH, S, L = k_packed.shape
     Dh = k_scale.shape[-1]
@@ -280,7 +286,7 @@ def gear_hist_block_ref(
     valid = jnp.arange(S)[None, :] < n_comp[:, None]
     s = jnp.where(valid[:, None, :], s, NEG_INF)
     m = jnp.max(s, axis=-1)
-    p = jnp.exp(s - m[..., None])
+    p = jnp.where(valid[:, None, :], jnp.exp(s - m[..., None]), 0.0)
     l = jnp.sum(p, axis=-1)
 
     gv = v_scale.shape[-1]

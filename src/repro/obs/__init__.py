@@ -129,6 +129,21 @@ class Observability:
             self.registry.get("serving_tokens_generated_total").inc(n_active)
             self.registry.get("serving_decode_step_seconds").observe(seconds)
 
+    def decode_grid(self, lengths, grid: tuple[int, int, int]) -> None:
+        """One decode step's walk of the GEAR decode kernel, per layer call:
+        ``lengths`` [B] are the slots' cache lengths the step attends over,
+        ``grid`` is ``Engine.decode_kernel_grid``.  Each row (slot x KV
+        head) walks every capacity chunk; a step does work only on the
+        row's ``ceil(n_comp / chunk)`` live chunks (``n_comp``, the
+        compressed extent, is ``length`` rounded down to a chunk)."""
+        if self._m:
+            chunk, n_chunks, heads = grid
+            live = sum(min(int(n) // chunk, n_chunks) for n in lengths)
+            self.registry.get("serving_decode_grid_steps_total").inc(
+                len(lengths) * n_chunks * heads)
+            self.registry.get("serving_decode_live_steps_total").inc(
+                live * heads)
+
     def queue_depth(self, n: int) -> None:
         if self._m:
             self.registry.get("serving_queue_depth").set(n)

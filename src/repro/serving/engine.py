@@ -487,6 +487,16 @@ class Engine:
             return "fused-interpret"
         return "fused-kernel" if kernel_ops.on_tpu() else "fused-oracle"
 
+    @property
+    def decode_kernel_grid(self) -> tuple[int, int, int] | None:
+        """(chunk, capacity chunks, KV heads): the GEAR decode kernel's
+        grid per slot, one row per KV head walking the capacity's chunks;
+        None when no decode layer runs it (``attend_path`` "xla")."""
+        if self.attend_path == "xla":
+            return None
+        nb = self.ecfg.policy.buffer_size
+        return nb, self._cap() // nb, self.cfg.num_kv_heads
+
     # ------------------------------------------------------------------
     def attach_faults(self, injector) -> None:
         """Wire a :class:`~repro.serving.faults.FaultInjector` into the

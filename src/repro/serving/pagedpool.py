@@ -21,10 +21,11 @@ to pure reference counting and *no page is ever copied*.  The radix trie
 
 The zero-page invariant: page 0 is reserved, permanently zero, and never
 allocated; block-table rows reset to 0 and fresh pages are zeroed at
-admission (:func:`repro.core.cache.zero_pool_pages`), so any table entry a
-kernel reads past a slot's live extent streams the same zero bytes the
-dense layout holds there — the invariant behind the paged ≡ dense
-bit-identity guarantee.
+admission (:func:`repro.core.cache.zero_pool_pages`), so any table entry
+read past a slot's live extent (by the gathering paths; the fused decode
+kernels do no work there) streams the same zero bytes the dense layout
+holds there — the invariant behind the paged ≡ dense bit-identity
+guarantee.
 """
 
 from __future__ import annotations

@@ -310,6 +310,7 @@ class Scheduler:
         pstats0 = (eng.prefix_cache.snapshot()
                    if eng.prefix_cache is not None else None)
         obs = self.obs
+        kernel_grid = eng.decode_kernel_grid if obs is not None else None
         # step phases (ObsConfig.profiler): the loop's host time splits into
         # sched.admission, sched.decode and sched.bookkeeping (the rest)
         phase = obs.phase if obs is not None else no_phase
@@ -592,6 +593,9 @@ class Scheduler:
             if obs is not None:
                 now = self._clock()
                 obs.decode_step(step_t, len(active_slots))
+                if kernel_grid is not None:
+                    # pos now holds each slot's length after the append
+                    obs.decode_grid(pos, kernel_grid)
                 obs.queue_depth(len(self.queue))
             for s in active_slots:
                 decode_s[s] += step_t

@@ -4,11 +4,12 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import CacheConfig, named_policy, init_layer_cache, prefill_layer_cache
 from repro.kernels.quant_pack import quant_pack
-from repro.kernels.gear_decode import gear_decode
+from repro.kernels.gear_decode import gear_decode, gear_decode_paged
 from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels import ref
 
@@ -75,6 +76,11 @@ def _cache_arrays(polname, B=2, H=2, Dh=128, S=128, n=100, nb=None):
     return cfg, common, extras
 
 
+def _assert_empty_triple(acc, m, l):
+    """A row at extent 0 walks no chunk: (acc, m, l) is the init triple."""
+    assert (acc == 0).all() and (m == ref.NEG_INF).all() and (l == 0).all()
+
+
 @pytest.mark.parametrize("polname", ["gear_kivi2", "gear_l_kivi2", "kivi2",
                                      "gear_kcvt4", "kcvt4", "outlier_kivi2"])
 @pytest.mark.parametrize("G,Dh,S", [(2, 128, 128), (1, 64, 64), (4, 128, 192)])
@@ -86,6 +92,10 @@ def test_gear_decode_sweep(polname, G, Dh, S, rng):
     acc_r, m_r, l_r = ref.gear_decode_ref(q, *common, **kwargs, **extras)
     acc_k, m_k, l_k = gear_decode(q, *common, interpret=True, **kwargs, **extras)
     assert jnp.allclose(m_k[..., 0], m_r, atol=1e-4)
+    if common[-1] == 0:          # S - 10 tokens close no chunk of 64: empty rows
+        _assert_empty_triple(acc_k, m_k[..., 0], l_k[..., 0])
+        _assert_empty_triple(acc_r, m_r, l_r)
+        return
     out_r = acc_r / l_r[..., None]
     out_k = acc_k / l_k[..., 0:1]
     assert jnp.allclose(out_k, out_r, atol=1e-4), float(jnp.abs(out_k - out_r).max())
@@ -109,7 +119,11 @@ def test_gear_decode_ragged_sweep(polname, rng):
     acc_k, m_k, l_k = gear_decode(q, *arrays, n_comp, interpret=True,
                                   **kwargs, **extras)
     assert jnp.allclose(m_k[..., 0], m_r, atol=1e-4)
-    assert jnp.allclose(acc_k / l_k[..., 0:1], acc_r / l_r[..., None], atol=1e-4)
+    # the empty row keeps the init triple (its acc / l would be 0 / 0)
+    _assert_empty_triple(acc_k[:1], m_k[:1, :, 0], l_k[:1, :, 0])
+    _assert_empty_triple(acc_r[:1], m_r[:1], l_r[:1])
+    assert jnp.allclose(acc_k[1:] / l_k[1:, :, 0:1],
+                        acc_r[1:] / l_r[1:, :, None], atol=1e-4)
 
     # row independence: each ragged row == a solo call at its scalar extent
     for x in range(1, 4):                                      # skip the empty row
@@ -133,6 +147,178 @@ def test_gear_decode_scalar_extent_still_accepted(rng):
         acc_s, m_s, l_s = fn(q, *arrays, scalar, **kwargs, **extras)
         acc_v, m_v, l_v = fn(q, *arrays, vec, **kwargs, **extras)
         assert (acc_s == acc_v).all() and (m_s == m_v).all() and (l_s == l_v).all()
+
+
+HEAD = ("k_packed", "k_scale", "k_zero", "v_packed", "v_scale", "v_zero")
+# an empty row, a partial chunk (live chunks = ceil(40 / 32) = 2), a chunk
+# boundary and the full capacity of 8 chunks of 32
+DEAD_EXTENTS = (0, 40, 96, 256)
+
+
+def _junk(x):
+    """NaN for float operands, the largest code for integer ones."""
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        return jnp.asarray(jnp.nan, x.dtype)
+    return jnp.asarray(jnp.iinfo(x.dtype).max, x.dtype)
+
+
+def _poison_past(x, live, n_chunks):
+    """Rows [N, X, ...] (X = n_chunks chunks of rows): every chunk at or past
+    row i's first ``live[i]`` chunks becomes junk."""
+    chunk = jnp.arange(x.shape[1]) // (x.shape[1] // n_chunks)
+    dead = chunk[None, :] >= jnp.asarray(live)[:, None]
+    return jnp.where(dead.reshape(dead.shape + (1,) * (x.ndim - 2)), _junk(x), x)
+
+
+def _decode_case(polname, B, nb=32):
+    """Dense kernel operands of ``B`` slots x 2 heads over a full 256-token
+    cache, as one dict, with the call kwargs."""
+    cfg, common, extras = _cache_arrays(polname, B=B, H=2, Dh=64, S=256,
+                                        n=256, nb=nb)
+    ops = dict(zip(HEAD, common[:-1])) | extras
+    kwargs = dict(bits=cfg.policy.bits, chunk=nb, scale_factor=64**-0.5)
+    return cfg, ops, kwargs
+
+
+def _call(fn, q, ops, n_comp, *tables, **kwargs):
+    return fn(q, *(ops[k] for k in HEAD), n_comp, *tables,
+              **{k: v for k, v in ops.items() if k not in HEAD}, **kwargs)
+
+
+def _assert_matches_oracle(got, want, live_rows):
+    """Kernel triple ``got`` (m, l carried on lanes) against an oracle
+    triple on clean data: normalized outputs on live rows, init triple on
+    empty rows."""
+    (acc_k, m_k, l_k), (acc_r, m_r, l_r) = got, want
+    m_k, l_k = m_k[..., 0], l_k[..., 0]
+    live = jnp.asarray(live_rows)
+    assert jnp.isfinite(acc_k).all() and jnp.isfinite(l_k).all()
+    assert jnp.allclose(m_k[live], m_r[live], atol=1e-4)
+    assert jnp.allclose(acc_k[live] / l_k[live][..., None],
+                        acc_r[live] / l_r[live][..., None], atol=1e-4)
+    empty = jnp.asarray([x for x in range(m_k.shape[0]) if x not in live_rows])
+    _assert_empty_triple(acc_k[empty], m_k[empty], l_k[empty])
+    _assert_empty_triple(acc_r[empty], m_r[empty], l_r[empty])
+
+
+@pytest.mark.parametrize("polname", ["gear_kcvt4", "gear_kivi2", "kivi2"])
+def test_gear_decode_never_reads_dead_chunks(polname, rng):
+    """Every chunk past a row's live extent holds NaN / junk: the kernel
+    stays finite, equals its own run on clean data bit for bit, and matches
+    the oracle run on clean data.  Extent 40 pins the walk's end at
+    ceil(n_comp / chunk): its second chunk is live, and masked past token
+    40."""
+    nb, C = 32, 8
+    cfg, ops, kwargs = _decode_case(polname, B=2, nb=nb)
+    n_comp = jnp.asarray(DEAD_EXTENTS, jnp.int32)           # one per bh row
+    live = -(-n_comp // nb)
+    bad = {k: _poison_past(v, live, C) for k, v in ops.items()}
+    q = jax.random.normal(rng, (4, 3, 64))
+
+    kern = lambda o: _call(gear_decode, q, o, n_comp, interpret=True, **kwargs)
+    got = kern(bad)
+    for a, b in zip(got, kern(ops)):
+        assert (a == b).all()
+    want = _call(ref.gear_decode_ref, q, ops, n_comp, **kwargs)
+    _assert_matches_oracle(got, want, live_rows=[1, 2, 3])
+
+
+def _paged_pool(ops: dict, B: int, H: int, live, n_chunks: int):
+    """Dense kernel rows [B*H, X, ...] -> head-flattened pool pages
+    [P*H, X / n_chunks, ...] and block tables [B, n_chunks].  Each live
+    chunk gets a page of its own; every dead table entry points at page 0,
+    which holds NaN / junk."""
+    bt = np.zeros((B, n_chunks), np.int32)
+    owners = [(b, c) for b in range(B) for c in range(int(live[b]))]
+    for page, (b, c) in enumerate(owners, start=1):
+        bt[b, c] = page
+    pools = {}
+    for name, x in ops.items():
+        rows = x.shape[1] // n_chunks
+        xs = x.reshape((B, H, n_chunks, rows) + x.shape[2:])
+        junk = jnp.full(xs.shape[1:2] + xs.shape[3:], _junk(x))
+        pages = jnp.stack([junk] + [xs[b, :, c] for b, c in owners])
+        pools[name] = pages.reshape((-1, rows) + x.shape[2:])
+    return pools, jnp.asarray(bt)
+
+
+@pytest.mark.parametrize("polname", ["gear_kcvt4", "gear_kivi2", "kivi2"])
+def test_gear_decode_paged_never_reads_dead_pages(polname, rng):
+    """Dead table entries point at a NaN / junk page (not the zero page):
+    the paged kernel stays finite, equals the dense kernel on clean data bit
+    for bit, and matches the oracle run on clean data."""
+    nb, C, B, H = 32, 8, 4, 2
+    cfg, ops, kwargs = _decode_case(polname, B=B, nb=nb)
+    n_slot = jnp.asarray(DEAD_EXTENTS, jnp.int32)           # one per slot
+    n_comp = jnp.repeat(n_slot, H)
+    pools, bt = _paged_pool(ops, B, H, -(-n_slot // nb), C)
+    q = jax.random.normal(rng, (B * H, 3, 64))
+
+    got = _call(gear_decode_paged, q, pools, n_comp, bt, interpret=True,
+                **kwargs)
+    dense = _call(gear_decode, q, ops, n_comp, interpret=True, **kwargs)
+    for a, b in zip(got, dense):
+        assert (a == b).all()
+    want = _call(ref.gear_decode_ref, q, ops, n_comp, **kwargs)
+    _assert_matches_oracle(got, want, live_rows=list(range(H, B * H)))
+
+
+@pytest.mark.parametrize("polname", ["gear_kcvt4", "gear_kivi2", "kivi2"])
+def test_gear_decode_empty_extent_triple(polname, rng):
+    """Extent 0 everywhere: both kernels and every oracle return the init
+    triple (0, NEG_INF, 0) on every row."""
+    nb, C, B, H = 32, 8, 2, 2
+    cfg, ops, kwargs = _decode_case(polname, B=B, nb=nb)
+    n_comp = jnp.zeros((B * H,), jnp.int32)
+    pools, bt = _paged_pool(ops, B, H, [0] * B, C)
+    q = jax.random.normal(rng, (B * H, 3, 64))
+    for acc, m, l in (
+            _call(gear_decode, q, ops, n_comp, interpret=True, **kwargs),
+            _call(gear_decode_paged, q, pools, n_comp, bt, interpret=True,
+                  **kwargs)):
+        _assert_empty_triple(acc, m, l)
+    zero_page = {k: jnp.zeros_like(v) for k, v in pools.items()}
+    for triple in (_call(ref.gear_decode_ref, q, ops, n_comp, **kwargs),
+                   _call(ref.gear_hist_block_ref, q, ops, n_comp, **kwargs),
+                   _call(ref.gear_decode_paged_ref, q, zero_page, n_comp, bt,
+                         **kwargs)):
+        _assert_empty_triple(*triple)
+
+
+@pytest.mark.parametrize("polname", ["gear_kcvt4", "gear_kivi2", "kivi2"])
+@pytest.mark.parametrize("n_comp", [0, 32, 64])
+def test_gear_attend_block_view_wider_than_extent(polname, n_comp, rng):
+    """Streaming prefill attends each chunk's queries against a prefix view
+    wider than its extent (the attend scan's segments): with every view
+    chunk past ``n_comp`` NaN / junk, the kernel path stays finite and
+    matches the oracle path on clean data."""
+    from repro.kernels import ops as kernel_ops
+    nb, C, B, H, Dh = 32, 4, 2, 2, 64
+    pol = named_policy(polname)
+    pol = dataclasses.replace(pol, buffer_size=nb, group=min(pol.group, nb))
+    cfg = CacheConfig(batch=B, kv_heads=H, head_dim=Dh, capacity=C * nb,
+                      policy=pol)
+    keys = jax.random.split(rng, 5)
+    k = jax.random.normal(keys[0], (B, H, C * nb, Dh))
+    v = jax.random.normal(keys[1], (B, H, C * nb, Dh))
+    cache = prefill_layer_cache(cfg, init_layer_cache(cfg), k, v)
+    live = jnp.full((B * H,), -(-n_comp // nb))
+    bad = dataclasses.replace(cache, **{
+        f: _poison_past(x.reshape((B * H,) + x.shape[2:]), live, C
+                        ).reshape(x.shape)
+        for f in HEAD + ("k_a", "k_b", "v_a", "v_b", "k_sp_val", "k_sp_idx",
+                         "v_sp_val", "v_sp_idx")
+        if (x := getattr(cache, f)) is not None})
+    q = jax.random.normal(keys[2], (B, 2 * H, nb, Dh))
+    k_blk = jax.random.normal(keys[3], (B, H, nb, Dh))
+    v_blk = jax.random.normal(keys[4], (B, H, nb, Dh))
+    o_ref = kernel_ops.gear_attend_block(cfg, cache, q, k_blk, v_blk,
+                                         n_comp, nb, Dh**-0.5)
+    o_krn = kernel_ops.gear_attend_block(cfg, bad, q, k_blk, v_blk,
+                                         n_comp, nb, Dh**-0.5,
+                                         force_kernel=True, interpret=True)
+    assert jnp.isfinite(o_krn).all()
+    assert jnp.allclose(o_krn, o_ref, atol=1e-4), float(jnp.abs(o_krn - o_ref).max())
 
 
 @pytest.mark.parametrize("S,Dh,bq,bk", [(128, 64, 32, 32), (256, 128, 64, 64),

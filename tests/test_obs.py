@@ -516,6 +516,60 @@ def test_served_programs_are_named_and_annotated(tmp_path):
     assert all(m.startswith("jit_gear_") for m in mods), mods
 
 
+def test_decode_grid_counts_live_chunks():
+    """Ragged slot lengths: grid steps are rows x capacity chunks, live
+    steps are KV heads x sum of ceil(n_comp / chunk), with n_comp the
+    length rounded down to a chunk; metrics off counts nothing."""
+    lengths = np.asarray([0, 7, 8, 17, 40, 63])     # capacity 6 x 8 = 48
+    chunk, n_chunks, heads = 8, 6, 2
+    o = Observability(ObsConfig())
+    o.decode_grid(lengths, (chunk, n_chunks, heads))
+    o.decode_grid(lengths[:2], (chunk, n_chunks, heads))
+    n_comp = np.minimum(lengths // chunk * chunk, n_chunks * chunk)
+    live = heads * int(np.sum(-(-n_comp // chunk)))          # 2 * 14
+    reg = o.registry
+    assert reg.get("serving_decode_grid_steps_total").value() == 8 * 6 * 2
+    assert reg.get("serving_decode_live_steps_total").value() == live == 28
+    off = Observability(ObsConfig(metrics=False))
+    off.decode_grid(lengths, (chunk, n_chunks, heads))
+    assert off.registry.get("serving_decode_grid_steps_total").value() == 0
+
+
+def test_decode_grid_counters_follow_the_cache(monkeypatch):
+    """Over a continuous run, the counters equal what each decode step's
+    cache lengths (read back from the device) give."""
+    from repro.core.cache import PagedGEARLayerCache
+    eng = _obs_engine()
+    chunk, n_chunks, heads = eng.decode_kernel_grid
+    assert (chunk, heads) == (8, TINY.num_kv_heads) and n_chunks == 6
+    reg = eng.obs.registry
+    grid0 = reg.get("serving_decode_grid_steps_total").value()
+    live0 = reg.get("serving_decode_live_steps_total").value()
+    seen = []
+    real = eng.decode
+
+    def spy(token_batch, caches, pos):
+        logits, caches = real(token_batch, caches, pos)
+        layer = next(c for c in jax.tree.leaves(
+            caches, is_leaf=lambda c: isinstance(c, PagedGEARLayerCache))
+            if isinstance(c, PagedGEARLayerCache))
+        seen.append(np.asarray(layer.length).reshape(-1, 2)[0])
+        return logits, caches
+
+    monkeypatch.setattr(eng, "decode", spy)
+    sched = Scheduler(eng)
+    for r in _requests():
+        sched.submit(r)
+    sched.run_continuous()
+    assert len(seen) == sched.last_stats["decode_steps"] > 0
+    n_comp = np.minimum(np.stack(seen) // chunk * chunk, n_chunks * chunk)
+    want_live = heads * int(np.sum(-(-n_comp // chunk)))
+    grid = reg.get("serving_decode_grid_steps_total").value() - grid0
+    live = reg.get("serving_decode_live_steps_total").value() - live0
+    assert grid == len(seen) * 2 * heads * n_chunks
+    assert live == want_live > 0
+
+
 def test_trace_records_first_and_last_token():
     eng = _obs_engine()
     eng.obs.tracer.reset()

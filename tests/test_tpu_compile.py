@@ -5,7 +5,9 @@ block shapes off the tiling, casts it has no lowering for, reshapes that
 split lanes.  These tests compile each kernel of the serving path for a
 *described* v5e chip (no chip attached) at real widths — minicpm-2b's
 (head_dim 64, 36 kv heads) and head_dim 128 — with the GEAR-KCVT-4bit cache
-geometry of an 8-slot, 1088-token engine.
+geometry of an 8-slot, 1088-token engine, and the decode kernels also at
+starcoder2-3b's GQA shape (12 query rows per kv head, 2 kv heads of 128,
+16 slots of 2048 tokens).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and pytest-xdist
@@ -31,6 +33,13 @@ NB = POL.buffer_size
 SLOTS, CAPACITY = 8, 1088
 WIDTHS = [(64, 36), (128, 8)]          # (head_dim, kv heads)
 ORIENTATIONS = ["k", "v"]
+# decode kernels: (head_dim, kv heads, query rows per kv head, slots,
+# capacity); the last is starcoder2-3b's GQA decode at the benchmark's size
+DECODE_SHAPES = [
+    *(pytest.param(dh, heads, 1, SLOTS, CAPACITY, id=f"{dh}-{heads}")
+      for dh, heads in WIDTHS),
+    pytest.param(128, 2, 12, 16, 2048, id="gqa12-128-2"),
+]
 
 
 @pytest.fixture(scope="module")
@@ -86,32 +95,32 @@ def _split(ops: dict):
     return head, ops
 
 
-@pytest.mark.parametrize("dh,heads", WIDTHS)
-def test_gear_decode_compiles(one_chip, dh, heads):
-    cfg = CacheConfig(batch=SLOTS, kv_heads=heads, head_dim=dh,
-                      capacity=CAPACITY, policy=POL)
-    bh = SLOTS * heads
+@pytest.mark.parametrize("dh,heads,g,slots,capacity", DECODE_SHAPES)
+def test_gear_decode_compiles(one_chip, dh, heads, g, slots, capacity):
+    cfg = CacheConfig(batch=slots, kv_heads=heads, head_dim=dh,
+                      capacity=capacity, policy=POL)
+    bh = slots * heads
     head, extras = _split(_decode_operands(cfg, one_chip))
-    q = jax.ShapeDtypeStruct((bh, 1, dh), jnp.float32, sharding=one_chip)
+    q = jax.ShapeDtypeStruct((bh, g, dh), jnp.float32, sharding=one_chip)
     n_comp = jax.ShapeDtypeStruct((bh,), jnp.int32, sharding=one_chip)
     _compile(lambda q, head, n, extras: gear_decode(
         q, *head, n, bits=POL.bits, chunk=NB, scale_factor=dh**-0.5, **extras),
         q, head, n_comp, extras)
 
 
-@pytest.mark.parametrize("dh,heads", WIDTHS)
-def test_gear_decode_paged_compiles(one_chip, dh, heads):
+@pytest.mark.parametrize("dh,heads,g,slots,capacity", DECODE_SHAPES)
+def test_gear_decode_paged_compiles(one_chip, dh, heads, g, slots, capacity):
     cfg = CacheConfig(batch=1, kv_heads=heads, head_dim=dh,
-                      capacity=CAPACITY, policy=POL)
-    pages = SLOTS * cfg.n_chunks + 1
+                      capacity=capacity, policy=POL)
+    pages = slots * cfg.n_chunks + 1
     pool = {n: None if spec is None
             else jax.ShapeDtypeStruct((pages,) + spec[0], spec[1])
             for n, spec in page_field_shapes(cfg).items()}
     head, extras = _split(_flat_operands(pool, pages * heads, one_chip))
-    bh = SLOTS * heads
-    q = jax.ShapeDtypeStruct((bh, 1, dh), jnp.float32, sharding=one_chip)
+    bh = slots * heads
+    q = jax.ShapeDtypeStruct((bh, g, dh), jnp.float32, sharding=one_chip)
     n_comp = jax.ShapeDtypeStruct((bh,), jnp.int32, sharding=one_chip)
-    tables = jax.ShapeDtypeStruct((SLOTS, cfg.n_chunks), jnp.int32,
+    tables = jax.ShapeDtypeStruct((slots, cfg.n_chunks), jnp.int32,
                                   sharding=one_chip)
     _compile(lambda q, head, n, bt, extras: gear_decode_paged(
         q, *head, n, bt, bits=POL.bits, chunk=NB, scale_factor=dh**-0.5,
