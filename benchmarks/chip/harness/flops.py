@@ -98,6 +98,11 @@ class Dense:
                    d_ff=c["intermediate_size"], vocab=c["vocab_size"],
                    gated=c["hidden_act"] == "silu")
 
+    @property
+    def gear_layers(self) -> int:
+        """Layers whose K/V the GEAR pool holds: all of a dense decoder's."""
+        return self.layers
+
     def matmul_params(self) -> int:
         """Weights each token multiplies in the layers (not the head)."""
         d, q, kv = self.d_model, self.heads * self.head_dim, self.kv_heads * self.head_dim

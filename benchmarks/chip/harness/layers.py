@@ -7,8 +7,10 @@ returns None when its cell gives it nothing to read.  ``ctx`` carries
 window), ``traced`` (host interval of the trace), ``stall`` (host interval
 of ``stop_trace``), ``trace`` (``harness.trace.reduce`` of the trace),
 ``peaks`` (``peaks.json`` of the device, None off the chip), ``model``
-(``flops.Dense``), ``gear`` (``flops.Gear``), ``slots``, ``kv_heads``,
-``group`` (query heads per KV head).
+(the configuration's flop model, ``flop_model(cfg)`` of its architecture
+module: ``token_flops``, ``prefill_flops``, ``gear_layers``), ``gear``
+(``flops.Gear``), ``slots``, ``kv_heads``, ``group`` (query heads per KV
+head).
 """
 
 from __future__ import annotations

@@ -1,14 +1,13 @@
-"""Plain reference of the served decoder, and the comparison that decides
+"""What every plain reference shares, and the comparison that decides
 ``correct``.
 
-The reference follows the published architecture in float32 jax.numpy,
-every matrix product at ``Precision.HIGHEST``: token embedding, then per
-layer a pre-norm attention block (rotary embedding on the two halves of each
-head, causal softmax attention with grouped KV heads, scale ``head_dim **
--0.5``) and a pre-norm MLP (``silu`` gated, or ``gelu`` with the tanh
-approximation), a final norm and the tied (or separate) output head.  It
-imports nothing of the served program and has no cache, batching or kernel:
-the whole sequence is recomputed, one layer at a time, queries in blocks.
+A configuration's architecture module (``archs/<arch_module>.py``) builds
+its float32 forward in jax.numpy from these pieces, every matrix product at
+``Precision.HIGHEST`` through ``_mm``, and hands ``gaps`` two functions: the
+final hidden states of a padded sequence, and the output head over a block
+of them.  A reference imports nothing of the served program and has no
+cache, batching or kernel: the whole sequence is recomputed, one layer at a
+time, queries in blocks of ``Q_BLOCK``.
 
 Departures from the published configurations are in the configuration
 files (``assumed``); the weights are the run's own (``harness.weights``),
@@ -21,8 +20,6 @@ change might take.  Its gaps must fail the limit.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -69,97 +66,34 @@ def _rope(x, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(cfg: dict, control: bool, x, lp):
-    T, d = x.shape
-    H, Hkv, Dh = cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
-    G = H // Hkv
-    w = lambda a: a.astype(jnp.float32)
-    a = lp["attn"]
-    h = _norm(x, lp["ln1"], cfg)
-    q = _mm("td,de->te", h, w(a["wq"]), control, 1, 0).reshape(T, H, Dh)
-    k = _mm("td,de->te", h, w(a["wk"]), control, 1, 0).reshape(T, Hkv, Dh)
-    v = _mm("td,de->te", h, w(a["wv"]), control, 1, 0).reshape(T, Hkv, Dh)
-    q = _rope(q, cfg["rope_theta"]).reshape(T, Hkv, G, Dh)
-    k = _rope(k, cfg["rope_theta"])
-    outs = []
-    for lo in range(0, T, Q_BLOCK):
-        qb = q[lo:lo + Q_BLOCK]
-        s = _mm("qhgd,khd->hgqk", qb, k, control, 3, 2) * Dh ** -0.5
-        causal = (lo + jnp.arange(qb.shape[0]))[:, None] >= jnp.arange(T)[None]
-        s = jnp.where(causal, s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        outs.append(_mm("hgqk,khd->qhgd", p, v, control, 3, 0))
-    o = jnp.concatenate(outs, 0).reshape(T, H * Dh)
-    x = x + _mm("te,ed->td", o, w(a["wo"]), control, 1, 0)
-    h = _norm(x, lp["ln2"], cfg)
-    m = lp["mlp"]
-    up = _mm("td,df->tf", h, w(m["w_up"]), control, 1, 0)
-    if cfg["gated"]:
-        up = jax.nn.silu(_mm("td,df->tf", h, w(m["w_gate"]), control, 1, 0)) * up
-    else:
-        up = jax.nn.gelu(up, approximate=True)
-    return x + _mm("tf,fd->td", up, w(m["w_down"]), control, 1, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_items", "control"))
-def _hidden(params, tokens, cfg_items, control):
-    cfg = dict(cfg_items)
-    x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
-    (blocks,) = params["blocks"]
-    x, _ = jax.lax.scan(lambda c, lp: (_layer(cfg, control, c, lp), None),
-                        x, blocks)
-    return _norm(x, params["final_norm"], cfg)
-
-
-@functools.partial(jax.jit, static_argnames=("control",))
-def _logits(params, h, control):
-    if "lm_head" in params:
-        return _mm("pd,dv->pv", h, params["lm_head"].astype(jnp.float32),
-                   control, 1, 0)
-    return _mm("pd,vd->pv", h, params["embed"].astype(jnp.float32),
-               control, 1, 1)
-
-
-def ref_config(cfg: dict) -> dict:
-    """The reference's view of a configuration file."""
-    d, H = cfg["hidden_size"], cfg["num_attention_heads"]
-    act = cfg["hidden_act"]
-    if act not in ("silu", "gelu_pytorch_tanh"):
-        raise ValueError(f"reference has no activation {act!r}")
-    return {"heads": H, "kv_heads": cfg["num_key_value_heads"],
-            "head_dim": cfg.get("head_dim", d // H),
-            "rope_theta": float(cfg["rope_theta"]),
-            "norm_eps": float(cfg.get("rms_norm_eps", cfg.get("norm_epsilon"))),
-            "gated": act == "silu"}
-
-
-def gaps(cfg: dict, params, prompt: np.ndarray, served: np.ndarray,
+def gaps(hidden, logits, prompt: np.ndarray, served: np.ndarray,
          pad_to: int, control: bool = False) -> np.ndarray:
     """For each served token, how far the reference's logit of the token
     that was served lies below the reference's best, in logits.
 
-    ``prompt`` [P] and ``served`` [n] (the tokens the program generated for
-    it) are run through the reference once, as one sequence, padded at the
-    end to ``pad_to`` (positions past the real ones never reach a real one:
-    attention is causal).  With ``control``, the token judged at each
-    position is the one the float8 forward puts first on the same input,
-    and its gap is read under the float32 reference."""
-    items = tuple(sorted(ref_config(cfg).items()))
+    ``hidden(tokens, control)`` gives the final hidden states [pad_to, d] of
+    a sequence; ``logits(h, control)`` the output head over ``P_BLOCK`` of
+    them.  ``prompt`` [P] and ``served`` [n] (the tokens the program
+    generated for it) are run through the reference once, as one sequence,
+    padded at the end to ``pad_to`` (positions past the real ones never
+    reach a real one: attention is causal).  With ``control``, the token
+    judged at each position is the one the float8 forward puts first on the
+    same input, and its gap is read under the float32 reference."""
     seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
     if seq.size > pad_to:
         raise ValueError(f"sequence of {seq.size} tokens over pad_to {pad_to}")
     toks = jnp.asarray(np.pad(seq, (0, pad_to - seq.size)))
     pos = np.arange(prompt.size - 1, seq.size)          # predicts served[i]
-    h = _hidden(params, toks, items, False)
-    hc = _hidden(params, toks, items, True) if control else None
+    h = hidden(toks, False)
+    hc = hidden(toks, True) if control else None
     out = []
     for lo in range(0, pos.size, P_BLOCK):
         p = np.zeros(P_BLOCK, np.int64)
         n = min(P_BLOCK, pos.size - lo)
         p[:n] = pos[lo:lo + n]
-        ref = _logits(params, h[p], False)
+        ref = logits(h[p], False)
         if control:
-            tok = jnp.argmax(_logits(params, hc[p], True), -1)
+            tok = jnp.argmax(logits(hc[p], True), -1)
         else:
             t = np.zeros(P_BLOCK, np.int64)
             t[:n] = served[lo:lo + n]

@@ -1,8 +1,20 @@
 """Find a cell's data by name: ``BENCHMARK.json`` at the checkout root names
 the cell, its configuration and its traffic mix; each of those is a file of
-its own under ``benchmarks/chip`` (``configs/<config>.json``,
-``traffic/<mix>.json``, ``cells/<cell>.json``), so a cell is added by adding
-files and entries, never by editing code."""
+its own under ``benchmarks/chip``, so a cell or a configuration is added by
+adding files and entries, never by editing code:
+
+* ``configs/<config>.json``: the configuration as run, with its sizes, a
+  ``rehearse`` block for the CPU, ``serving`` (policy, slots, pool) and
+  ``"arch_module"``, the name of its architecture module;
+* ``archs/<arch_module>.py``: the architecture (``harness/arch.py`` says
+  what it gives: the program's ``ModelConfig``, the plain reference and the
+  FLOP count), where no module there has it yet;
+* ``traffic/<mix>.json``: the traffic mix, where it is new;
+* ``cells/<cell>.json``: the cell's limits for ``correct``;
+* entries in ``BENCHMARK.json``: the configuration under ``configs``, the
+  cell under ``workloads``, and the cell's name in the ``workloads`` of each
+  metric it reports.
+"""
 
 from __future__ import annotations
 
@@ -34,7 +46,8 @@ def _reports(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-def load_cell(name: str, root: Path = ROOT) -> Cell:
+def load_cell(name: str, root: Path | None = None) -> Cell:
+    root = ROOT if root is None else root
     bench = load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:

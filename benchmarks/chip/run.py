@@ -7,12 +7,17 @@ One process loads the served model (random bf16 weights from ``--seed``),
 warms up every shape the cell's traffic uses, measures for ``--seconds``
 through ``Scheduler.run_continuous`` over one paged, streaming-prefill GEAR
 engine, then checks a sample of the requests it served against a plain
-float32 reference (``harness/reference.py``).  The last line of standard
-output is one JSON object: ``correct``, ``attempted``, ``failed``,
-``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
-per-layer metrics, read from a profiler trace of part of the window),
-``device``, ``breakdown`` (traced runs) and, last, ``checks``: each number
-compared for ``correct`` with its limit.  Logs go to standard error.
+float32 reference.  The last line of standard output is one JSON object:
+``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end
+metrics, or with ``--trace 1`` its per-layer metrics, read from a profiler
+trace of part of the window), ``device``, ``breakdown`` (traced runs) and,
+last, ``checks``: each number compared for ``correct`` with its limit.
+Logs go to standard error.
+
+This file holds no model-specific code.  The configuration file names its
+architecture in ``"arch_module"``, and ``archs/<arch_module>.py``
+(``harness/arch.py``) maps it onto the program's ``ModelConfig``, holds its
+reference (built on ``harness/reference.py``) and counts its FLOPs.
 
 The run needs a TPU: without one (or with fewer chips than the cell asks
 for) it exits 1 and prints no result.  ``--rehearse`` runs the same control
@@ -93,25 +98,6 @@ class CompileCounter:
         return sum(lo <= t <= hi for t, _ in self.seen)
 
 
-def model_config(cfg: dict):
-    """The served program's configuration from a configuration file."""
-    import dataclasses
-
-    from repro.configs import get_config
-    p = cfg["program"]
-    base = get_config(p["arch"])
-    H = cfg["num_attention_heads"]
-    return dataclasses.replace(
-        base, num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=H, num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim", cfg["hidden_size"] // H),
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        mlp_kind={"silu": "swiglu", "gelu_pytorch_tanh": "gelu_mlp"}[cfg["hidden_act"]],
-        norm=p["norm"], max_seq_len=cfg["max_position_embeddings"])
-
-
 def sized(cell, rehearse: bool) -> tuple[dict, dict, dict]:
     """(configuration, mix, serving) as run: the files' own, or with their
     ``rehearse`` blocks laid over them."""
@@ -149,13 +135,14 @@ def setup(cell, seed: int, rehearse: bool):
     """Configuration, weights from ``seed`` and a warmed engine."""
     import jax
 
-    from harness import traffic, warmup, weights
+    from harness import arch, traffic, warmup, weights
     from repro.core.policy import named_policy
     from repro.models.model import build_model
     from repro.serving import Engine, EngineConfig
 
     cfg, mix, serving = sized(cell, rehearse)
-    mcfg = model_config(cfg)
+    A = arch.of(cfg)
+    mcfg = A.model_config(cfg)
     pol = named_policy(serving["policy"])
     nb = pol.buffer_size
     cap = -(-(traffic.max_context(mix) + 1) // nb) * nb
@@ -177,7 +164,7 @@ def setup(cell, seed: int, rehearse: bool):
     t0 = time.perf_counter()
     warmup.warm(engine, mix, nb, mcfg.vocab_size, log)
     return types.SimpleNamespace(
-        cell=cell, cfg=cfg, mix=mix, mcfg=mcfg, pol=pol, nb=nb, cap=cap,
+        cell=cell, cfg=cfg, arch=A, mix=mix, mcfg=mcfg, pol=pol, nb=nb, cap=cap,
         slots=slots, model=model, params=params, engine=engine,
         t_weights=t_weights, t_warm=time.perf_counter() - t0)
 
@@ -248,7 +235,7 @@ def logit_gaps(S, params, finished: list, seed: int, control: bool = False):
     sample = sample_for_check(traffic.rng_for(seed + 1), finished,
                               S.cell.limits["check_tokens"])
     pad = -(-S.cap // reference.Q_BLOCK) * reference.Q_BLOCK
-    g = [reference.gaps(S.cfg, params, p, s, pad, control)
+    g = [S.arch.gaps(S.cfg, params, p, s, pad, control)
          for _, p, s in sample]
     if not g:
         return None, 0, 0, None
@@ -276,7 +263,7 @@ def per_layer(S, hub, info: dict, trace_dir: str, rehearse: bool,
         hub=hub, lo=hub.t_open, hi=hub.t_close, trace=tr,
         traced=(t_tr[0], t_tr[1]), stall=(t_tr[1], t_tr[2]),
         peaks=None if rehearse else spec.peaks(info["kind"]),
-        model=flops.Dense.from_config(S.cfg),
+        model=S.arch.flop_model(S.cfg),
         gear=flops.Gear(head_dim=S.mcfg.head_dim, chunk=S.nb, bits=S.pol.bits,
                         rank=S.pol.rank, sparsity=S.pol.sparsity),
         slots=S.slots, kv_heads=S.mcfg.num_kv_heads,

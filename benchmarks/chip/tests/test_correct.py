@@ -10,7 +10,7 @@ import pytest
 import run
 from harness import spec
 
-CELLS = ["starcoder2-3b.decode_2k"]
+CELLS = [w["name"] for w in spec.load_json(spec.ROOT / "BENCHMARK.json")["workloads"]]
 SEED = 2**31 + 99
 
 
@@ -76,11 +76,11 @@ def test_fault_is_not_correct(cell, fault, monkeypatch, capsys):
 
 
 def test_reference_matches_served_math_exactly_in_f32():
-    """The reference's own arithmetic: on a 1-layer toy with identity
-    norms, its logits equal a direct numpy forward."""
+    """The ``dense_decoder`` reference's own arithmetic: on a 1-layer toy
+    with identity norms, its logits equal a direct numpy forward."""
     import numpy as np
 
-    from harness import reference
+    from harness import arch
     rng = np.random.default_rng(0)
     d, H, Dh, F, V, T = 8, 2, 4, 16, 11, 5
     w = lambda *s: jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)
@@ -96,7 +96,7 @@ def test_reference_matches_served_math_exactly_in_f32():
            "hidden_act": "silu"}
     toks = np.array([1, 4, 2, 7, 3], np.int32)
     served = np.array([5, 6, 0], np.int32)     # predicted at positions 2..4
-    g = reference.gaps(cfg, p, toks[:3], served, pad_to=512)
+    g = arch.load("dense_decoder").gaps(cfg, p, toks[:3], served, pad_to=512)
 
     # numpy forward
     P = {k: np.asarray(v) for k, v in p["blocks"][0]["attn"].items()}
