@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from harness import flops, reference
-from harness.reference import Q_BLOCK, _mm, _norm, _rope
+from harness.reference import _attention, _mm, _norm, _rope
 
 
 def model_config(cfg: dict):
@@ -48,24 +48,15 @@ def flop_model(cfg: dict) -> flops.Dense:
 def _layer(cfg: dict, control: bool, x, lp):
     T, d = x.shape
     H, Hkv, Dh = cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
-    G = H // Hkv
     w = lambda a: a.astype(jnp.float32)
     a = lp["attn"]
     h = _norm(x, lp["ln1"], cfg)
     q = _mm("td,de->te", h, w(a["wq"]), control, 1, 0).reshape(T, H, Dh)
     k = _mm("td,de->te", h, w(a["wk"]), control, 1, 0).reshape(T, Hkv, Dh)
     v = _mm("td,de->te", h, w(a["wv"]), control, 1, 0).reshape(T, Hkv, Dh)
-    q = _rope(q, cfg["rope_theta"]).reshape(T, Hkv, G, Dh)
+    q = _rope(q, cfg["rope_theta"])
     k = _rope(k, cfg["rope_theta"])
-    outs = []
-    for lo in range(0, T, Q_BLOCK):
-        qb = q[lo:lo + Q_BLOCK]
-        s = _mm("qhgd,khd->hgqk", qb, k, control, 3, 2) * Dh ** -0.5
-        causal = (lo + jnp.arange(qb.shape[0]))[:, None] >= jnp.arange(T)[None]
-        s = jnp.where(causal, s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        outs.append(_mm("hgqk,khd->qhgd", p, v, control, 3, 0))
-    o = jnp.concatenate(outs, 0).reshape(T, H * Dh)
+    o = _attention(q, k, v, control).reshape(T, H * Dh)
     x = x + _mm("te,ed->td", o, w(a["wo"]), control, 1, 0)
     h = _norm(x, lp["ln2"], cfg)
     m = lp["mlp"]

@@ -79,7 +79,11 @@ def compress_cost(g: Gear, chunks: int, kind: str):
 
 @dataclasses.dataclass(frozen=True)
 class Dense:
-    """A dense decoder's shapes, for model FLOPs."""
+    """A decoder's shapes, for model FLOPs.  Every layer attends fully
+    unless ``kinds`` names each layer ``"full"`` or ``"window"`` (a window
+    layer attends the last ``window`` keys); the FFN is a dense MLP of
+    ``d_ff`` unless ``experts`` > 0: ``top_k`` of them a token, each
+    ``expert_ff`` wide, and a router over all."""
     layers: int
     d_model: int
     heads: int
@@ -88,6 +92,19 @@ class Dense:
     d_ff: int
     vocab: int
     gated: bool
+    kinds: tuple[str, ...] = ()     # per layer; empty: all full
+    window: int = 0
+    experts: int = 0
+    top_k: int = 0
+    expert_ff: int = 0
+
+    def __post_init__(self):
+        if self.kinds and len(self.kinds) != self.layers:
+            raise ValueError(f"{len(self.kinds)} layer kinds for {self.layers} layers")
+        if set(self.kinds) - {"full", "window"}:
+            raise ValueError(f"layer kinds {sorted(set(self.kinds))}: only full and window")
+        if "window" in self.kinds and self.window < 1:
+            raise ValueError("window layers need a window of at least 1")
 
     @classmethod
     def from_config(cls, c: dict) -> "Dense":
@@ -98,25 +115,44 @@ class Dense:
                    d_ff=c["intermediate_size"], vocab=c["vocab_size"],
                    gated=c["hidden_act"] == "silu")
 
+    def layer_kinds(self) -> tuple[str, ...]:
+        return self.kinds or ("full",) * self.layers
+
     @property
     def gear_layers(self) -> int:
-        """Layers whose K/V the GEAR pool holds: all of a dense decoder's."""
-        return self.layers
+        """Layers whose K/V the GEAR pool holds: the full ones."""
+        return self.layer_kinds().count("full")
 
     def matmul_params(self) -> int:
-        """Weights each token multiplies in the layers (not the head)."""
+        """Weights each token multiplies in the layers (not the head): the
+        attention, and the MLP or the router and ``top_k`` experts."""
         d, q, kv = self.d_model, self.heads * self.head_dim, self.kv_heads * self.head_dim
-        mlp = (3 if self.gated else 2) * d * self.d_ff
-        return self.layers * (2 * d * q + 2 * d * kv + mlp)
+        mats = 3 if self.gated else 2
+        if self.experts:
+            ffn = self.top_k * mats * d * self.expert_ff + d * self.experts
+        else:
+            ffn = mats * d * self.d_ff
+        return self.layers * (2 * d * q + 2 * d * kv + ffn)
+
+    def _keys(self, context: int) -> int:
+        """Keys one token at ``context`` attends, summed over the layers."""
+        return sum(context if k == "full" else min(context, self.window)
+                   for k in self.layer_kinds())
 
     def token_flops(self, context: int, logits: bool) -> int:
         """FLOPs of one token that attends ``context`` keys (itself
-        included), with the output head when ``logits``."""
-        attn = 4 * self.layers * context * self.heads * self.head_dim
+        included; a window layer ``min(context, window)``), with the output
+        head when ``logits``."""
+        attn = 4 * self._keys(context) * self.heads * self.head_dim
         head = 2 * self.d_model * self.vocab if logits else 0
         return 2 * self.matmul_params() + attn + head
 
     def prefill_flops(self, n: int) -> int:
-        """A causal prefill of ``n`` tokens with logits at the last one."""
-        attn = 4 * self.layers * self.heads * self.head_dim * n * (n + 1) // 2
+        """A causal prefill of ``n`` tokens with logits at the last one:
+        token i (1..n) attends i keys, ``min(i, window)`` on a window layer."""
+        w = min(n, self.window)
+        windowed = w * (w + 1) // 2 + (n - w) * self.window
+        keys = sum(n * (n + 1) // 2 if k == "full" else windowed
+                   for k in self.layer_kinds())
+        attn = 4 * self.heads * self.head_dim * keys
         return 2 * self.matmul_params() * n + attn + 2 * self.d_model * self.vocab

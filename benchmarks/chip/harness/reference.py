@@ -2,7 +2,9 @@
 ``correct``.
 
 A configuration's architecture module (``archs/<arch_module>.py``) builds
-its float32 forward in jax.numpy from these pieces, every matrix product at
+its float32 forward in jax.numpy from these pieces (norms ``_norm``, rotary
+embeddings ``_rope`` and ``_rope_yarn``, causal or sliding-window attention
+``_attention``, a drop-free expert FFN ``_moe``), every matrix product at
 ``Precision.HIGHEST`` through ``_mm``, and hands ``gaps`` two functions: the
 final hidden states of a padded sequence, and the output head over a block
 of them.  A reference imports nothing of the served program and has no
@@ -21,6 +23,8 @@ change might take.  Its gaps must fail the limit.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,6 +32,7 @@ import numpy as np
 HI = jax.lax.Precision.HIGHEST
 Q_BLOCK = 512          # query rows per attention block
 P_BLOCK = 256          # logit positions per output-head call
+MOE_BLOCK = 256        # token rows per expert-FFN block
 F8_MAX = 448.0         # largest finite float8_e4m3fn
 
 
@@ -64,6 +69,91 @@ def _rope(x, theta: float):
     cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def _yarn_range(dh: int, theta: float, original_max: int, beta_fast: float,
+                beta_slow: float) -> tuple[int, float]:
+    """YaRN's band of rotary dimensions (pairs) that move from plain RoPE
+    to interpolated: the dimensions that turn ``beta_fast`` and
+    ``beta_slow`` times over ``original_max`` positions (transformers'
+    ``_compute_yarn_parameters``, truncated)."""
+    corr = lambda r: dh * math.log(original_max / (2 * math.pi * r)) / (2 * math.log(theta))
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dh - 1)
+    if low == high:
+        high += 0.001       # a ramp of width 0 would divide by 0
+    return low, high
+
+
+def _yarn_inv_freq(dh: int, theta: float, factor: float, original_max: int,
+                   beta_fast: float, beta_slow: float):
+    """[Dh/2] rotary frequencies: plain RoPE's below the band, ``1/factor``
+    of them above it, a linear ramp between."""
+    pos_freqs = theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    low, high = _yarn_range(dh, theta, original_max, beta_fast, beta_slow)
+    ramp = jnp.clip((jnp.arange(dh // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return (1.0 / (factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * (1.0 - ramp)
+
+
+def _rope_yarn(x, theta: float, factor: float, original_max: int,
+               beta_fast: float, beta_slow: float, attention_factor: float):
+    """``_rope`` with YaRN's frequencies, cos and sin scaled by
+    ``attention_factor``.  x: [T, H, Dh]; positions 0..T-1."""
+    T, _, dh = x.shape
+    freqs = _yarn_inv_freq(dh, theta, factor, original_max, beta_fast, beta_slow)
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs     # [T, Dh/2]
+    cos = (jnp.cos(ang) * attention_factor)[:, None]
+    sin = (jnp.sin(ang) * attention_factor)[:, None]
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def _attention(q, k, v, control: bool, window: int = 0):
+    """Causal softmax attention, scale ``Dh ** -0.5``, queries in blocks of
+    ``Q_BLOCK``.  q: [T, H, Dh]; k, v: [T, Hkv, Dh], query head ``h`` on KV
+    head ``h // (H / Hkv)``.  With ``window > 0`` the query at position p
+    sees keys p - window + 1 .. p.  Returns [T, H, Dh]."""
+    T, H, Dh = q.shape
+    Hkv = k.shape[1]
+    q = q.reshape(T, Hkv, H // Hkv, Dh)
+    outs = []
+    for lo in range(0, T, Q_BLOCK):
+        qb = q[lo:lo + Q_BLOCK]
+        s = _mm("qhgd,khd->hgqk", qb, k, control, 3, 2) * Dh ** -0.5
+        qpos, kpos = (lo + jnp.arange(qb.shape[0]))[:, None], jnp.arange(T)[None]
+        seen = qpos >= kpos
+        if window:
+            seen = seen & (qpos - kpos < window)
+        s = jnp.where(seen, s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        outs.append(_mm("hgqk,khd->qhgd", p, v, control, 3, 0))
+    return jnp.concatenate(outs, 0).reshape(T, H, Dh)
+
+
+def _moe(h, mp, n_experts: int, top_k: int, norm_topk: bool, control: bool):
+    """A mixture of SwiGLU experts with no capacity: every token gets its
+    ``top_k`` experts of a softmax router over all ``n_experts``, weighted
+    by their router probabilities (renormalised to sum 1 with
+    ``norm_topk``).  h: [T, d]; ``mp`` in the served tree's names:
+    ``router [d, E]``, ``w_gate`` / ``w_up [E, d, f]``, ``w_down [E, f, d]``.
+
+    Every expert runs on every token, ``MOE_BLOCK`` tokens at a time, and
+    the router's weights, zero off a token's ``top_k``, mask the sum; no
+    token's result depends on another's."""
+    w = lambda a: a.astype(jnp.float32)
+    outs = []
+    for lo in range(0, h.shape[0], MOE_BLOCK):
+        hb = h[lo:lo + MOE_BLOCK]
+        probs = jax.nn.softmax(_mm("td,de->te", hb, w(mp["router"]), control, 1, 0), -1)
+        top, idx = jax.lax.top_k(probs, top_k)
+        if norm_topk:
+            top = top / jnp.sum(top, -1, keepdims=True)
+        mask = jnp.sum(jax.nn.one_hot(idx, n_experts) * top[..., None], 1)   # [t, E]
+        gate = _mm("td,edf->etf", hb, w(mp["w_gate"]), control, 1, 1)
+        up = _mm("td,edf->etf", hb, w(mp["w_up"]), control, 1, 1)
+        y = _mm("etf,efd->etd", jax.nn.silu(gate) * up, w(mp["w_down"]), control, 2, 1)
+        outs.append(jnp.sum(mask.T[:, :, None] * y, 0))
+    return jnp.concatenate(outs, 0)
 
 
 def gaps(hidden, logits, prompt: np.ndarray, served: np.ndarray,

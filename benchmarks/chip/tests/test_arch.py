@@ -38,21 +38,83 @@ def test_missing_or_unknown_arch_module_exits(named):
     assert "dense_decoder" in str(e.value)
 
 
-def test_dense_decoder_model_config_field_by_field():
-    got = dataclasses.asdict(arch.load("dense_decoder").model_config(starcoder2()))
-    assert got == {
-        "name": "starcoder2-3b", "family": "dense", "num_layers": 30,
-        "d_model": 3072, "num_heads": 24, "num_kv_heads": 2, "head_dim": 128,
-        "d_ff": 12288, "vocab_size": 49152, "mlp_kind": "gelu_mlp",
-        "norm": "layernorm", "rope_theta": 999999.4420358813, "qk_norm": False,
-        "attn_logit_softcap": 0.0, "attn_pattern": "global",
-        "local_window": 1024, "pattern_locals": 5, "moe": False,
-        "num_experts": 0, "moe_top_k": 0, "moe_d_ff": 0,
-        "shared_expert": False, "capacity_factor": 1.25,
-        "router_aux_weight": 0.01, "ssm": False, "ssm_state": 16,
-        "ssm_conv": 4, "hybrid_parallel": False, "rwkv": False,
-        "modality": "text", "num_prefix_tokens": 0, "num_codebooks": 0,
-        "tie_embeddings": True, "lr_schedule": "cosine", "max_seq_len": 16384}
+# starcoder2-3b as served, every field ``ModelConfig`` has: the keys the
+# configuration file maps, and the rest as the registered base config has them
+STARCODER2_SERVED = {
+    "name": "starcoder2-3b", "family": "dense", "num_layers": 30,
+    "d_model": 3072, "num_heads": 24, "num_kv_heads": 2, "head_dim": 128,
+    "d_ff": 12288, "vocab_size": 49152, "mlp_kind": "gelu_mlp",
+    "norm": "layernorm", "rope_theta": 999999.4420358813, "qk_norm": False,
+    "attn_logit_softcap": 0.0, "attn_pattern": "global",
+    "local_window": 1024, "pattern_locals": 5, "moe": False,
+    "num_experts": 0, "moe_top_k": 0, "moe_d_ff": 0,
+    "shared_expert": False, "capacity_factor": 1.25,
+    "router_aux_weight": 0.01, "ssm": False, "ssm_state": 16,
+    "ssm_conv": 4, "hybrid_parallel": False, "rwkv": False,
+    "modality": "text", "num_prefix_tokens": 0, "num_codebooks": 0,
+    "tie_embeddings": True, "lr_schedule": "cosine", "max_seq_len": 16384}
+MAPPED = ("name", "num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
+          "d_ff", "vocab_size", "mlp_kind", "norm", "rope_theta", "tie_embeddings",
+          "max_seq_len")
+
+
+def config_mismatches(got, base, served: dict) -> dict:
+    """Fields of ``got`` that differ from ``served``'s literal values, or,
+    for a field ``served`` does not name (one added to ``ModelConfig``
+    since), from the registered ``base``'s; and fields either side lacks."""
+    missing = object()
+    g = dataclasses.asdict(got)
+    want = {**dataclasses.asdict(base), **served}
+    return {k: (g.get(k, missing), want.get(k, missing))
+            for k in g.keys() | want.keys() if g.get(k, missing) != want.get(k, missing)}
+
+
+def wider(cfg):
+    """``cfg`` as an instance of a config type with one more defaulted field."""
+    from repro.configs.base import ModelConfig
+    Wider = dataclasses.make_dataclass(
+        "Wider", [("yarn_factor", float, dataclasses.field(default=1.0))],
+        bases=(ModelConfig,), frozen=True)
+    return Wider(**dataclasses.asdict(cfg))
+
+
+def changed(value):
+    """Another value of the same type."""
+    if isinstance(value, bool):
+        return not value
+    if isinstance(value, str):
+        return value + "-x"
+    return value * 2
+
+
+@pytest.mark.parametrize("case", ["as served", "a wider ModelConfig",
+                                  "registered attn_pattern changed",
+                                  *(f"mapped {k} changed" for k in MAPPED)])
+def test_dense_decoder_model_config_field_by_field(case, monkeypatch):
+    """Every field of starcoder2-3b's served config against its literal
+    value; a field added to ``ModelConfig`` since, against the registered
+    base config.  A field added with a default passes; any change in how
+    the model is served fails, from the configuration file or from the
+    registry alike."""
+    import repro.configs
+    cfg = starcoder2()
+    if case == "registered attn_pattern changed":
+        registered = repro.configs.get_config
+        monkeypatch.setattr(repro.configs, "get_config", lambda name: dataclasses.replace(
+            registered(name), attn_pattern=changed(registered(name).attn_pattern)))
+    got = arch.load("dense_decoder").model_config(cfg)
+    base = repro.configs.get_config(cfg["program"]["arch"])
+    if case == "a wider ModelConfig":
+        got, base = wider(got), wider(base)
+        assert dataclasses.asdict(got)["yarn_factor"] == 1.0
+    elif case.startswith("mapped "):
+        field = case.split()[1]
+        got = dataclasses.replace(got, **{field: changed(getattr(got, field))})
+    bad = config_mismatches(got, base, STARCODER2_SERVED)
+    if case.endswith(" changed"):
+        assert list(bad) == [case.split()[1]]
+    else:
+        assert bad == {}
 
 
 def test_dense_decoder_flop_model_is_dense():
