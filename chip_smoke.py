@@ -12,8 +12,9 @@ at use, so the numbers are the ones an f32 tree would give).
 
 * Phase A, the default path: dense cache, monolithic prefill, ``fused="auto"``
   (``flash_prefill`` + ``gear_decode``), driven by ``Scheduler.run_continuous``.
-* Phase B, the rest of the serving stack: paged cache, streaming prefill
-  (``gear_compress``, ``gear_decode_paged`` for history, ``flash_prefill_block``).
+* Phase B, the rest of the serving stack: paged cache (``gear_decode_paged``
+  in decode), streaming prefill (``gear_compress``, ``gear_decode`` for
+  history, ``flash_prefill_block``).
 * Phase K (one chip): ``gear_decode``, ``flash_prefill``,
   ``flash_prefill_block`` and ``gear_compress`` (every output) against
   their jnp oracles on identical random inputs at the model's attention

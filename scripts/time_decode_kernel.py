@@ -17,16 +17,23 @@ traffic.
 
 Each ``--kernel`` is a ``gear_decode.py`` file loaded on its own (default:
 this checkout's), so versions of the kernel are timed in one process on one
-chip.  Device time comes from a profiler trace (the benchmark's own reader,
+chip.  Every file gets the same cache contents, each in its own page
+layout: a ``gear_decode_paged`` that takes a ``layout`` reads lane-dense
+``codes`` / ``side`` pool leaves (``repro.core.cache.chunks_to_pages``), an
+older one the head-flattened per-field pages ``[P*H, ...]``.  Device time
+comes from a profiler trace (the benchmark's own reader,
 ``benchmarks/chip/harness/trace.py``), summed over ``--calls`` calls; the
 host clock around ``block_until_ready`` is printed beside it.  One JSON
-line per (kernel file, kernel, mix).  Without a TPU it exits 1.
+line per (kernel file, kernel, mix), with the device time of a live grid
+step and the largest gap of the outputs to the first file's.  Without a
+TPU it exits 1.
 """
 
 from __future__ import annotations
 
 import argparse
 import importlib.util
+import inspect
 import json
 import os
 import sys
@@ -42,8 +49,8 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 sys.path.insert(0, os.path.join(ROOT, "benchmarks", "chip"))
 
 from harness import trace as trace_lib  # noqa: E402
-from repro.core.cache import (CacheConfig, init_layer_cache,  # noqa: E402
-                              page_field_shapes)
+from repro.core.cache import (CacheConfig, chunk_field_shapes,  # noqa: E402
+                              chunks_to_pages, init_layer_cache, page_layout)
 from repro.core.policy import named_policy  # noqa: E402
 
 HEADS, G, DH, CAPACITY = 2, 12, 128, 2048
@@ -51,6 +58,9 @@ SLOTS = 16                   # decode batch
 PREFILL_ROWS = G * 64        # one prefill chunk's queries per KV head
 # live chunks per slot: 352 of 16 x 32
 MIXED = (16, 17, 18, 19, 20, 21, 22, 23, 21, 22, 23, 24, 25, 26, 27, 28)
+HEAD = ("k_packed", "k_scale", "k_zero", "v_packed", "v_scale", "v_zero")
+EXTRA = ("k_a", "k_b", "v_a", "v_b", "k_sp_val", "k_sp_idx", "v_sp_val",
+         "v_sp_idx")
 
 
 def load_kernels(path: str, tag: str):
@@ -83,12 +93,27 @@ def cache_config():
                        capacity=CAPACITY, policy=named_policy("gear_kcvt4"))
 
 
-def paged_operands(key):
-    """Pool pages for every slot-chunk (page 0 left for dead entries)."""
+def page_fields(key):
+    """Every slot-chunk's fields [P, H, ...] (page 0 left for dead
+    entries), in the dense layout's chunk form."""
     cfg = cache_config()
-    pages = SLOTS * cfg.n_chunks + 1
-    shapes = {n: (s[1:], d) for n, (s, d) in page_field_shapes(cfg).items()}
-    return _random(key, shapes, pages * HEADS, cfg.chunk)
+    return _random(key, chunk_field_shapes(cfg), SLOTS * cfg.n_chunks + 1,
+                   cfg.chunk)
+
+
+def paged_call(fn, fields: dict, q, live, kw: dict):
+    """A call of ``fn`` over ``fields`` in ``fn``'s own page layout."""
+    cfg = cache_config()
+    bt = tables(live, cfg.n_chunks)
+    n_slot = jnp.asarray(live, jnp.int32) * cfg.chunk
+    if "layout" in inspect.signature(fn).parameters:
+        layout = page_layout(cfg)
+        pool = chunks_to_pages(layout, fields)
+        return lambda: fn(q, pool["codes"], pool["side"], n_slot, bt,
+                          layout=layout, scale_factor=kw["scale_factor"])
+    flat = {n: x.reshape((-1,) + x.shape[2:]) for n, x in fields.items()}
+    return lambda: fn(q, *(flat[n] for n in HEAD), jnp.repeat(n_slot, HEADS),
+                      bt, **kw, **{n: flat[n] for n in EXTRA})
 
 
 def dense_operands(key):
@@ -100,16 +125,21 @@ def dense_operands(key):
     return _random(key, shapes, HEADS, cfg.chunk)
 
 
-HEAD = ("k_packed", "k_scale", "k_zero", "v_packed", "v_scale", "v_zero")
-EXTRA = ("k_a", "k_b", "v_a", "v_b", "k_sp_val", "k_sp_idx", "v_sp_val",
-         "v_sp_idx")
-
-
 def tables(live, n_chunks: int):
-    bt = np.zeros((SLOTS, n_chunks), np.int32)
+    bt = np.zeros((len(live), n_chunks), np.int32)
     for b, n in enumerate(live):
         bt[b, :n] = 1 + b * n_chunks + np.arange(n)
     return jnp.asarray(bt)
+
+
+def grid_steps(fn, name: str, rows: int, live) -> tuple[int, int]:
+    """(grid steps, live grid steps) of one call: the dense kernel and an
+    older paged one step per (row, head, chunk), a paged kernel with a
+    ``layout`` per (slot, chunk) for all its heads."""
+    heads = (1 if name == "gear_decode_paged"
+             and "layout" in inspect.signature(fn).parameters else HEADS)
+    C = cache_config().n_chunks
+    return rows * heads * C, heads * sum(live)
 
 
 def device_ms(call, calls: int, name: str) -> tuple[float, float, int]:
@@ -131,6 +161,11 @@ def device_ms(call, calls: int, name: str) -> tuple[float, float, int]:
     return 1e3 * red["kernel_s"].get("k", 0.0) / max(n, 1), host_ms, n
 
 
+def normalized(out):
+    acc, _, l = out
+    return np.asarray(acc / jnp.maximum(l[..., :1], 1e-30))
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--kernel", action="append", default=[],
@@ -146,7 +181,7 @@ def main() -> int:
     cfg = cache_config()
     C, nb = cfg.n_chunks, cfg.chunk
     key = jax.random.PRNGKey(args.seed)
-    pool = paged_operands(jax.random.fold_in(key, 1))
+    fields = page_fields(jax.random.fold_in(key, 1))
     dense = dense_operands(jax.random.fold_in(key, 2))
     q_dec = jax.random.normal(jax.random.fold_in(key, 3), (SLOTS * HEADS, G, DH))
     q_pre = jax.random.normal(jax.random.fold_in(key, 4),
@@ -156,26 +191,30 @@ def main() -> int:
              for mix, live in (("all_live", (C,) * SLOTS), ("mixed", MIXED))]
     cases += [("gear_decode", mix, live, 1)
               for mix, live in (("all_live", (C,)), ("mixed", (24,)))]
+    first: dict = {}
     for i, path in enumerate(paths):
         paged_fn, dense_fn = load_kernels(path, str(i))
         for name, mix, live, rows in cases:
-            n_comp = jnp.repeat(jnp.asarray(live, jnp.int32) * nb, HEADS)
             if name == "gear_decode_paged":
-                bt = tables(live, C)
-                call = lambda: paged_fn(  # noqa: E731
-                    q_dec, *(pool[n] for n in HEAD), n_comp, bt, **kw,
-                    **{n: pool[n] for n in EXTRA})
+                call = paged_call(paged_fn, fields, q_dec, live, kw)
+                fn = paged_fn
             else:
+                n_comp = jnp.repeat(jnp.asarray(live, jnp.int32) * nb, HEADS)
                 call = lambda: dense_fn(  # noqa: E731
                     q_pre, *(dense[n] for n in HEAD), n_comp, **kw,
                     **{n: dense[n] for n in EXTRA})
+                fn = dense_fn
+            out = normalized(call())
+            gap = float(np.abs(out - first.setdefault((name, mix), out)).max())
             dev, host, n = device_ms(call, args.calls, name)
+            steps, live_steps = grid_steps(fn, name, rows, live)
             print(json.dumps({
                 "kernel_file": path, "kernel": name, "mix": mix,
                 "live_share": sum(live) / (rows * C), "calls": n,
                 "device_ms_per_call": dev, "host_ms_per_call": host,
-                "grid_steps": rows * HEADS * C,
-                "live_steps": HEADS * sum(live),
+                "grid_steps": steps, "live_steps": live_steps,
+                "us_per_live_step": 1e3 * dev / live_steps,
+                "max_gap_to_first_file": gap,
                 "device": jax.devices()[0].device_kind,
             }), flush=True)
     return 0

@@ -81,8 +81,17 @@ __all__ = [
     "fresh_batch1_cache",
     "PagedGEARLayerCache",
     "paged_supported",
+    "PageLayout",
+    "page_layout",
+    "chunk_field_shapes",
     "page_field_shapes",
     "page_nbytes",
+    "chunks_to_pages",
+    "pages_to_chunks",
+    "layer_pool",
+    "unfold_rows",
+    "unpack_words",
+    "gather_pages",
     "init_paged_layer_cache",
     "paged_to_dense",
     "gather_pool_chunks",
@@ -1156,51 +1165,311 @@ def tree_finite(tree) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # Paged compressed KV pool (vLLM-style block tables over GEAR chunks)
 #
-# One **page** holds one n_b-token chunk's compressed fields for one layer:
-# every chunk-indexed array of the dense layout (see ``_chunk_row_axes``)
-# gets a pooled twin whose batch axis is replaced by a page axis and whose
-# chunk-row axis is sliced to one chunk's rows.  A per-slot **block table**
-# ``[B, C]`` of page ids maps logical chunk ``c`` of slot ``b`` to its pool
-# page; page 0 is the permanently-zero reserved page, so table entries past
-# a slot's allocated extent read as the dense layout's zeros — which is what
-# makes ``paged_to_dense`` *bitwise* equal to the dense-slot cache (the
-# allocator zeroes fresh pages at admission to keep the invariant; see
-# DESIGN.md §5).  The streaming buffer and ``length`` stay per-slot: only
-# closed (immutable) chunks live in the pool, which is why prefix-cache
-# sharing is pure refcounting with no copy-on-write copies ever needed.
+# One **page** holds one n_b-token chunk's compressed fields for one layer,
+# every KV head of it.  A per-slot **block table** ``[B, C]`` of page ids
+# maps logical chunk ``c`` of slot ``b`` to its pool page; page 0 is the
+# permanently-zero reserved page, so table entries past a slot's allocated
+# extent read as the dense layout's zeros — which is what makes
+# ``paged_to_dense`` *bitwise* equal to the dense-slot cache (the allocator
+# zeroes fresh pages at admission to keep the invariant; see DESIGN.md §5).
+# The streaming buffer and ``length`` stay per-slot: only closed (immutable)
+# chunks live in the pool, which is why prefix-cache sharing is pure
+# refcounting with no copy-on-write copies ever needed.
+#
+# **Page layout.**  A page is stored lane-dense, the way the TPU tiles
+# memory: two leaves, ``codes`` (int32) and ``side`` (the cache dtype), each
+# ``[rows, 128]`` a page with whole tiles of rows (8 rows of 32-bit data,
+# 16 of 16-bit), so a leaf's default device layout is the unpadded
+# row-major one the decode kernel DMAs page by page.  One rule places every
+# pooled field, from its dense chunk shape (:func:`page_layout`):
+#
+# * codes are repacked token-major, ``[a, Dh]`` int32 words with ``a =
+#   ceil(n_b / per)``: word ``(r, d)`` holds token ``r + a·j`` of channel
+#   ``d`` in bits ``[j·bits, (j+1)·bits)``, so unpacking is whole-vreg
+#   shifts and masks;
+# * every other field is one head's dense chunk field as a 2-D form with
+#   its longer axis on lanes (``k_b [Dh, r] -> [r, Dh]``, ``v_scale [n_b, 1]
+#   -> [1, n_b]``); sparse indices are stored as exact values of the cache
+#   dtype, in the ``side`` leaf beside their values;
+# * a form ``[a, b]`` is padded to ``w`` lanes (a power of two up to 128,
+#   else a multiple of 128) and folded into 128-lane rows: narrow forms cut
+#   their rows into ``128 / w`` groups of ``ceil(a·w / 128)`` rows, group
+#   ``s`` on lanes ``[s·w, (s+1)·w)``; wide forms cut their lanes into
+#   ``w / 128`` groups, group ``t`` on rows ``[t·a, (t+1)·a)``;
+# * a leaf holds its fields in ``_POOLED_FIELDS`` order, each field's heads
+#   in order, and its rows are padded to whole tiles.
+#
+# Chunks change form only where they enter or leave the pool
+# (:func:`chunks_to_pages`, :func:`pages_to_chunks`): the admission splice,
+# the closing-chunk write of :func:`append_token_paged`, the prefix-hit
+# gather, :func:`paged_to_dense` and the paged oracle.
+
+LANES = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class PageField:
+    """Where one pooled field sits in a page.
+
+    ``chunk_shape`` / ``chunk_dtype`` are one head's dense chunk field (its
+    ``_chunk_row_axes`` rows); ``form`` is the page form ``[a, b]``,
+    ``transposed`` when it swaps the dense field's two axes; ``width`` the
+    lanes a form row is padded to.  Head ``h`` occupies rows ``[offset +
+    h·rows, offset + (h+1)·rows)`` of ``leaf``.
+    """
+    name: str
+    leaf: str
+    offset: int
+    rows: int
+    form: tuple[int, int]
+    width: int
+    transposed: bool
+    chunk_shape: tuple[int, ...]
+    chunk_dtype: str
+
+
+@dataclasses.dataclass(frozen=True)
+class PageLayout:
+    """Page geometry of one layer.  Hashable: it rides ``jit`` static
+    arguments into the paged kernel and its oracle."""
+    heads: int
+    chunk: int
+    bits: int
+    fields: tuple[PageField, ...]
+    codes_rows: int
+    side_rows: int
+    side_dtype: str
+
+    def field(self, name: str) -> PageField | None:
+        return next((f for f in self.fields if f.name == name), None)
+
+    def leaf_shapes(self) -> dict:
+        """``{leaf: (page shape, dtype)}`` of the pool's two leaves."""
+        return {"codes": ((self.codes_rows, LANES), jnp.dtype(jnp.int32)),
+                "side": ((self.side_rows, LANES), jnp.dtype(self.side_dtype))}
+
+
+def _lane_width(b: int) -> int:
+    """Lanes a form row of ``b`` elements takes: a power of two up to 128
+    (so narrow rows share a 128-lane row evenly), else a multiple of 128."""
+    return 1 << (b - 1).bit_length() if b <= LANES else -(-b // LANES) * LANES
+
+
+def _folded_rows(a: int, w: int) -> int:
+    return -(-a * w // LANES) if w <= LANES else a * (w // LANES)
+
+
+def chunk_field_shapes(cfg: CacheConfig, dtype=jnp.bfloat16) -> dict:
+    """Per-field ``(shape, dtype)`` of one chunk of the dense layout, all
+    heads: drop the batch axis, slice the chunk-row axis
+    (``_chunk_row_axes``) to one chunk's rows.  E.g. ``k_packed [1, H, S,
+    Lp] -> (H, n_b, Lp)``, ``k_b [1, H, C, Dh, r] -> (H, 1, Dh, r)``."""
+    abs1 = jax.eval_shape(lambda: init_layer_cache(cfg, dtype))
+    out = {}
+    for field, (rpc, ax) in _chunk_row_axes(cfg).items():
+        leaf = getattr(abs1, field)
+        if leaf is None:
+            continue
+        shape = list(leaf.shape[1:])          # drop the batch axis
+        shape[len(shape) + ax] = rpc          # row axis counted from the end
+        out[field] = (tuple(shape), leaf.dtype)
+    return out
+
+
+@functools.lru_cache(maxsize=64)
+def _page_layout(cfg1: CacheConfig, dtype_name: str) -> PageLayout:
+    dtype = jnp.dtype(dtype_name)
+    if max(cfg1.chunk, cfg1.head_dim) > 2 ** (jnp.finfo(dtype).nmant + 1):
+        raise ValueError(
+            f"sparse indices up to {max(cfg1.chunk, cfg1.head_dim)} are not "
+            f"exact in {dtype.name}, the page's side dtype")
+    per = 32 // cfg1.policy.bits
+    fields, used = [], {"codes": 0, "side": 0}
+    shapes = chunk_field_shapes(cfg1, dtype)
+    for name in (n for n in _POOLED_FIELDS if n in shapes):
+        (heads, *chunk_shape), cdt = shapes[name]
+        if name.endswith("_packed"):
+            leaf, transposed = "codes", False
+            form = (-(-cfg1.chunk // per), cfg1.head_dim)
+        else:
+            two = tuple(chunk_shape[-2:])     # k_b / k_sp: (1, Dh, x) -> (Dh, x)
+            transposed = two[0] > two[1]
+            leaf, form = "side", two[::-1] if transposed else two
+        width = _lane_width(form[1])
+        rows = _folded_rows(form[0], width)
+        fields.append(PageField(name, leaf, used[leaf], rows, form, width,
+                                transposed, tuple(chunk_shape), cdt.name))
+        used[leaf] += heads * rows
+    whole = lambda n, tile: -(-n // tile) * tile
+    return PageLayout(heads=cfg1.kv_heads, chunk=cfg1.chunk,
+                      bits=cfg1.policy.bits,
+                      fields=tuple(fields),
+                      codes_rows=whole(used["codes"], 8),
+                      side_rows=whole(used["side"], 32 // dtype.itemsize),
+                      side_dtype=dtype.name)
+
+
+def page_layout(cfg: CacheConfig, dtype=jnp.bfloat16) -> PageLayout:
+    """The page layout of this layer geometry (see the notes above)."""
+    if not paged_supported(cfg):
+        raise ValueError(f"paged layout requires a GEAR cache, got {cfg.kind!r}")
+    cfg1 = cfg if cfg.batch == 1 else dataclasses.replace(cfg, batch=1)
+    return _page_layout(cfg1, jnp.dtype(dtype).name)
+
+
+def _fold(x: jnp.ndarray, field: PageField) -> jnp.ndarray:
+    """Forms ``[..., a, b]`` of ``field`` -> folded rows ``[..., rows,
+    128]`` (see the layout notes above)."""
+    *lead, a, b = x.shape
+    w = field.width
+    pad = [(0, 0)] * len(lead)
+    if w <= LANES:
+        g = LANES // w
+        n = _folded_rows(a, w)
+        x = jnp.pad(x, pad + [(0, g * n - a), (0, w - b)])
+        x = jnp.moveaxis(x.reshape(*lead, g, n, w), -3, -2)
+        return x.reshape(*lead, n, LANES)
+    m = w // LANES
+    x = jnp.pad(x, pad + [(0, 0), (0, w - b)]).reshape(*lead, a, m, LANES)
+    return jnp.moveaxis(x, -2, -3).reshape(*lead, m * a, LANES)
+
+
+def unfold_rows(x: jnp.ndarray, field: PageField) -> jnp.ndarray:
+    """Folded rows ``[..., rows, 128]`` of ``field`` (one head's) -> its
+    form ``[..., a, b]``; the inverse of :func:`_fold`.  Static slices and
+    concatenations only, so the decode kernel reads pages with it too."""
+    (a, b), w = field.form, field.width
+    if w < LANES:        # rows that shared a 128-lane row: lane groups
+        x = jnp.concatenate([x[..., s * w:(s + 1) * w]
+                             for s in range(LANES // w)], axis=-2)
+    elif w > LANES:      # rows split over 128-lane rows: row groups
+        x = jnp.concatenate([x[..., t * a:(t + 1) * a, :]
+                             for t in range(w // LANES)], axis=-1)
+    return x[..., :a, :b]
+
+
+def unpack_words(words: jnp.ndarray, bits: int, nb: int) -> jnp.ndarray:
+    """Token-major code words ``[..., a, Dh]`` int32 -> codes ``[..., nb,
+    Dh]`` int32: bits ``[j·bits, (j+1)·bits)`` of word row ``r`` hold token
+    ``r + a·j`` (whole-vreg shifts and masks in the decode kernel; the
+    arithmetic shift's sign extension only reaches bits the mask clears)."""
+    parts = [(words >> (bits * j)) & (2**bits - 1) for j in range(32 // bits)]
+    return jnp.concatenate(parts, axis=-2)[..., :nb, :]
+
+
+def _codes_to_words(packed: jnp.ndarray, bits: int, a: int) -> jnp.ndarray:
+    """Channel-packed codes ``[..., n_b, Dh/per]`` (the dense layout) ->
+    token-major words ``[..., a, Dh]`` (inverse of :func:`unpack_words`)."""
+    per = 32 // bits
+    codes = packing.unpack(packed, bits).astype(jnp.uint32)   # [..., n_b, Dh]
+    *lead, nb, d = codes.shape
+    codes = jnp.pad(codes, [(0, 0)] * len(lead) + [(0, a * per - nb), (0, 0)])
+    shifts = (jnp.arange(per, dtype=jnp.uint32) * bits)[:, None, None]
+    words = jnp.sum(codes.reshape(*lead, per, a, d) << shifts, axis=-3,
+                    dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def chunks_to_pages(layout: PageLayout, fields: dict) -> dict:
+    """Dense chunk fields -> page leaves.
+
+    ``fields`` maps every field of ``layout`` to one chunk of every head,
+    ``[..., H, *chunk_shape]`` with any leading dims (a payload of
+    :func:`extract_prefix_chunks` without its batch axis, stacked); returns
+    ``{"codes": [..., codes_rows, 128], "side": [..., side_rows, 128]}``.
+    """
+    parts: dict[str, list] = {"codes": [], "side": []}
+    H = layout.heads
+    for f in layout.fields:
+        x = fields[f.name]
+        lead = x.shape[:x.ndim - 1 - len(f.chunk_shape)]
+        x = x.reshape(*lead, H, *f.chunk_shape[-2:])
+        if f.leaf == "codes":
+            x = _codes_to_words(x.astype(jnp.int32), layout.bits, f.form[0])
+        else:
+            x = jnp.swapaxes(x, -1, -2) if f.transposed else x
+            x = x.astype(layout.side_dtype)
+        parts[f.leaf].append(_fold(x, f).reshape(*lead, H * f.rows, LANES))
+    out = {}
+    for leaf, ((rows, _), _) in layout.leaf_shapes().items():
+        x = jnp.concatenate(parts[leaf], axis=-2)
+        pad = [(0, 0)] * (x.ndim - 2) + [(0, rows - x.shape[-2]), (0, 0)]
+        out[leaf] = jnp.pad(x, pad)
+    return out
+
+
+def pages_to_chunks(layout: PageLayout, leaves: dict) -> dict:
+    """Page leaves ``{"codes", "side": [..., rows, 128]}`` -> every field of
+    ``layout`` as ``[..., H, *chunk_shape]`` (inverse of
+    :func:`chunks_to_pages`, bit for bit)."""
+    H = layout.heads
+    out = {}
+    for f in layout.fields:
+        x = leaves[f.leaf]
+        lead = x.shape[:-2]
+        x = x[..., f.offset:f.offset + H * f.rows, :].reshape(*lead, H, f.rows, LANES)
+        x = unfold_rows(x, f)
+        if f.leaf == "codes":
+            x = packing.pack(unpack_words(x, layout.bits, layout.chunk),
+                             layout.bits)
+        else:
+            x = jnp.swapaxes(x, -1, -2) if f.transposed else x
+            x = x.astype(f.chunk_dtype)
+        out[f.name] = x.reshape(*lead, H, *f.chunk_shape)
+    return out
+
+
+def gather_pages(layout: PageLayout, codes: jnp.ndarray, side: jnp.ndarray,
+                 block_tables: jnp.ndarray) -> dict:
+    """Pool leaves ``[P, rows, 128]`` through ``block_tables [B, C]`` ->
+    every field of ``layout`` in the dense layout's rows ``[B, H, C·r,
+    ...]`` (``r`` rows a chunk).  Shared by :func:`paged_to_dense` and the
+    paged oracle (:func:`repro.kernels.ref.gear_decode_paged_ref`)."""
+    bt = jnp.asarray(block_tables, jnp.int32)
+    chunks = pages_to_chunks(layout, {"codes": codes[bt], "side": side[bt]})
+    out = {}
+    for name, x in chunks.items():             # [B, C, H, r, ...]
+        B, C, H, r, *rest = x.shape
+        out[name] = jnp.moveaxis(x, 1, 2).reshape(B, H, C * r, *rest)
+    return out
 
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=[
-        "k_packed", "k_scale", "k_zero", "v_packed", "v_scale", "v_zero",
-        "k_a", "k_b", "v_a", "v_b",
-        "k_sp_val", "k_sp_idx", "v_sp_val", "v_sp_idx",
-        "buf_k", "buf_v", "length",
-    ],
+    data_fields=["codes", "side", "buf_k", "buf_v", "length", "layer"],
     meta_fields=[],
 )
 @dataclasses.dataclass(frozen=True)
 class PagedGEARLayerCache:
     """GEAR layer cache with pooled chunk storage.
 
-    Pooled fields are ``[P, ...page]`` (P pages shared by every slot); the
+    ``codes`` and ``side`` are the pool's leaves, ``[P, rows, 128]`` (P
+    pages shared by every slot; :func:`page_layout` places the fields); the
     streaming buffer ``[B, H, n_b, Dh]`` and ``length [B]`` remain per-slot.
     The block table addressing the pool is *engine-owned metadata* passed
     alongside (like ``pos``), not cache state — it changes only at
     admission/release, never inside a decode step.
+
+    ``layer`` is None, or an int32 scalar when ``codes`` / ``side`` hold
+    the pools of every repeat of this layer's pattern position, ``[R, P,
+    rows, 128]``, and this cache is repeat ``layer``'s: the decode step's
+    layer loop hands each layer the whole stacked pool so that the layer
+    reads and writes its pages in place, with no per-layer slice of the
+    pool (``models.transformer.decode_tokens``).
     """
-    k_packed: Any; k_scale: Any; k_zero: Any
-    v_packed: Any; v_scale: Any; v_zero: Any
-    k_a: Any; k_b: Any; v_a: Any; v_b: Any
-    k_sp_val: Any; k_sp_idx: Any; v_sp_val: Any; v_sp_idx: Any
-    buf_k: Any; buf_v: Any
+    codes: Any
+    side: Any
+    buf_k: Any
+    buf_v: Any
     length: Any
+    layer: Any = None
 
 
+# the dense layout's chunk fields the pool holds
 _POOLED_FIELDS = ("k_packed", "k_scale", "k_zero", "v_packed", "v_scale",
                   "v_zero", "k_a", "k_b", "v_a", "v_b",
                   "k_sp_val", "k_sp_idx", "v_sp_val", "v_sp_idx")
+_POOL_LEAVES = ("codes", "side")
 
 
 def paged_supported(cfg: CacheConfig) -> bool:
@@ -1215,37 +1484,15 @@ def paged_supported(cfg: CacheConfig) -> bool:
 
 
 def page_field_shapes(cfg: CacheConfig, dtype=jnp.bfloat16) -> dict:
-    """Per-field ``(page_shape, dtype)`` of one pool page.
-
-    Derived from the dense batch-1 geometry: drop the batch axis, slice the
-    chunk-row axis (``_chunk_row_axes``) to one chunk's rows.  E.g.
-    ``k_packed [1, H, S, Lp] -> (H, n_b, Lp)``, ``k_b [1, H, C, Dh, r] ->
-    (H, 1, Dh, r)``.
-    """
-    cfg1 = cfg if cfg.batch == 1 else dataclasses.replace(cfg, batch=1)
-    abs1 = jax.eval_shape(lambda: init_layer_cache(cfg1, dtype))
-    out = {}
-    for field, (rpc, ax) in _chunk_row_axes(cfg).items():
-        leaf = getattr(abs1, field)
-        if leaf is None:
-            out[field] = None
-            continue
-        shape = list(leaf.shape[1:])          # drop the batch axis
-        shape[len(shape) + ax] = rpc          # row axis counted from the end
-        out[field] = (tuple(shape), leaf.dtype)
-    return out
+    """``{leaf: (page shape, dtype)}`` of one pool page: ``codes`` and
+    ``side``, each ``(rows, 128)`` (:func:`page_layout`)."""
+    return page_layout(cfg, dtype).leaf_shapes()
 
 
 def page_nbytes(cfg: CacheConfig, dtype=jnp.bfloat16) -> int:
     """Bytes of one pool page for ONE layer of this geometry."""
-    total = 0
-    for spec in page_field_shapes(cfg, dtype).values():
-        if spec is None:
-            continue
-        shape, dt = spec
-        total += int(jnp.dtype(dt).itemsize) * functools.reduce(
-            lambda a, b: a * b, shape, 1)
-    return total
+    return sum(rows * lanes * dt.itemsize
+               for (rows, lanes), dt in page_field_shapes(cfg, dtype).values())
 
 
 def init_paged_layer_cache(cfg: CacheConfig, n_pages: int,
@@ -1260,18 +1507,26 @@ def init_paged_layer_cache(cfg: CacheConfig, n_pages: int,
     if n_pages < 2:
         raise ValueError(f"need >= 2 pages (page 0 is reserved), got {n_pages}")
     B, H, Dh = cfg.batch, cfg.kv_heads, cfg.head_dim
-    shapes = page_field_shapes(cfg, dtype)
-    fields = {}
-    for field in _POOLED_FIELDS:
-        spec = shapes.get(field)
-        fields[field] = (None if spec is None
-                         else jnp.zeros((n_pages,) + spec[0], spec[1]))
+    pool = {leaf: jnp.zeros((n_pages,) + shape, dt)
+            for leaf, (shape, dt) in page_field_shapes(cfg, dtype).items()}
     return PagedGEARLayerCache(
-        **fields,
+        **pool,
         buf_k=jnp.zeros((B, H, cfg.chunk, Dh), dtype),
         buf_v=jnp.zeros((B, H, cfg.chunk, Dh), dtype),
         length=jnp.zeros((B,), jnp.int32),
     )
+
+
+def _pool_layout(cfg: CacheConfig, pcache: PagedGEARLayerCache) -> PageLayout:
+    return page_layout(cfg, pcache.side.dtype)
+
+
+def layer_pool(pcache: PagedGEARLayerCache) -> dict:
+    """``{"codes", "side"}``: this layer's pool leaves ``[P, rows, 128]``
+    (sliced out of a stacked pool, a copy that only portable paths take)."""
+    if pcache.layer is None:
+        return {leaf: getattr(pcache, leaf) for leaf in _POOL_LEAVES}
+    return {leaf: getattr(pcache, leaf)[pcache.layer] for leaf in _POOL_LEAVES}
 
 
 def paged_to_dense(cfg: CacheConfig, pcache: PagedGEARLayerCache,
@@ -1283,20 +1538,10 @@ def paged_to_dense(cfg: CacheConfig, pcache: PagedGEARLayerCache,
     pages), so the portable decode path is literally ``attend(gather(...))``
     and cache-parity tests can compare arrays directly.
     """
-    bt = jnp.asarray(block_tables, jnp.int32)
-    spec = _chunk_row_axes(cfg)
-    fields = {f: None for f in _POOLED_FIELDS}
-    for field, (rpc, ax) in spec.items():
-        pool = getattr(pcache, field)
-        if pool is None:
-            fields[field] = None
-            continue
-        g = pool[bt]                      # [B, C, ...page]
-        row_axis = g.ndim + ax            # position of the rpc axis in g
-        g = jnp.moveaxis(g, 1, row_axis - 1)
-        shape = list(g.shape)
-        shape[row_axis - 1:row_axis + 1] = [shape[row_axis - 1] * shape[row_axis]]
-        fields[field] = g.reshape(shape)
+    fields = dict.fromkeys(_POOLED_FIELDS)
+    pool = layer_pool(pcache)
+    fields.update(gather_pages(_pool_layout(cfg, pcache), pool["codes"],
+                               pool["side"], block_tables))
     return GEARLayerCache(**fields, buf_k=pcache.buf_k, buf_v=pcache.buf_v,
                           length=pcache.length)
 
@@ -1310,18 +1555,23 @@ def gather_pool_chunks(cfg: CacheConfig, pcache: PagedGEARLayerCache,
     prefix-cache hit gathers its pages straight into the batch-1 scaffold.
     """
     pages = jnp.asarray(pages, jnp.int32)
-    n = pages.shape[0]
-    spec = _chunk_row_axes(cfg)
-    out = []
-    for c in range(n):
-        payload = {}
-        for field in spec:
-            pool = getattr(pcache, field)
-            if pool is None:
-                continue
-            payload[field] = pool[pages[c]][None]       # [1, ...page]
-        out.append(payload)
-    return out
+    chunks = pages_to_chunks(_pool_layout(cfg, pcache),
+                             {leaf: x[pages]
+                              for leaf, x in layer_pool(pcache).items()})
+    return [{name: x[c][None] for name, x in chunks.items()}
+            for c in range(pages.shape[0])]
+
+
+def _set_pages(pcache: PagedGEARLayerCache, pages: jnp.ndarray,
+               leaves: dict) -> PagedGEARLayerCache:
+    """Write page leaves ``[n, rows, 128]`` at ``pages [n]`` (of this
+    layer's pool, in place in a stacked one); out-of-range page ids drop
+    their write."""
+    at = pages if pcache.layer is None else (pcache.layer, pages)
+    return dataclasses.replace(pcache, **{
+        leaf: getattr(pcache, leaf).at[at].set(
+            leaves[leaf].astype(getattr(pcache, leaf).dtype), mode="drop")
+        for leaf in _POOL_LEAVES})
 
 
 def scatter_pool_chunks(cfg: CacheConfig, pcache: PagedGEARLayerCache,
@@ -1334,15 +1584,11 @@ def scatter_pool_chunks(cfg: CacheConfig, pcache: PagedGEARLayerCache,
     """
     if not chunks:
         return pcache
-    pages = jnp.asarray(pages, jnp.int32)
-    upd = {}
-    for field in _chunk_row_axes(cfg):
-        pool = getattr(pcache, field)
-        if pool is None:
-            continue
-        vals = jnp.stack([ch[field][0] for ch in chunks], axis=0)
-        upd[field] = pool.at[pages].set(vals.astype(pool.dtype), mode="drop")
-    return dataclasses.replace(pcache, **upd)
+    layout = _pool_layout(cfg, pcache)
+    fields = {f.name: jnp.stack([ch[f.name][0] for ch in chunks])
+              for f in layout.fields}
+    return _set_pages(pcache, jnp.asarray(pages, jnp.int32),
+                      chunks_to_pages(layout, fields))
 
 
 def zero_pool_pages(cfg: CacheConfig, pcache: PagedGEARLayerCache,
@@ -1351,24 +1597,20 @@ def zero_pool_pages(cfg: CacheConfig, pcache: PagedGEARLayerCache,
     pages so exposed-but-unwritten block-table entries keep gathering the
     dense layout's zeros (the bit-parity invariant; DESIGN.md §5)."""
     pages = jnp.asarray(pages, jnp.int32)
-    upd = {}
-    for field in _chunk_row_axes(cfg):
-        pool = getattr(pcache, field)
-        if pool is None:
-            continue
-        zero = jnp.zeros((pages.shape[0],) + pool.shape[1:], pool.dtype)
-        upd[field] = pool.at[pages].set(zero, mode="drop")
-    return dataclasses.replace(pcache, **upd)
+    return _set_pages(pcache, pages, {
+        leaf: jnp.zeros((pages.shape[0],) + getattr(pcache, leaf).shape[-2:])
+        for leaf in _POOL_LEAVES})
 
 
 def append_token_paged(cfg: CacheConfig, pcache: PagedGEARLayerCache,
                        block_tables: jnp.ndarray, k_t: jnp.ndarray,
                        v_t: jnp.ndarray, key: jax.Array | None = None):
     """Paged twin of :func:`append_token`: same buffer writes and the same
-    slot-invariant compression event, but a closing chunk scatters into the
-    slot's block-table page instead of dense batch rows.  Slots not at a
-    chunk boundary (or past capacity) redirect the page index out of bounds
-    and the scatter drops — one batched write serves every phase mix.
+    slot-invariant compression event, but a closing chunk is converted to
+    page form and scattered into the slot's block-table page instead of
+    dense batch rows.  Slots not at a chunk boundary (or past capacity)
+    redirect the page index out of bounds and the scatter drops — one
+    batched write serves every phase mix.
     """
     pol = cfg.policy
     nb = cfg.chunk
@@ -1384,7 +1626,7 @@ def append_token_paged(cfg: CacheConfig, pcache: PagedGEARLayerCache,
     def compress(c):
         need = (c.length % nb == 0) & (c.length > 0) & (c.length <= cfg.capacity)
         cidx = jnp.clip(jnp.maximum(c.length - 1, 0) // nb, 0, cfg.n_chunks - 1)
-        P = c.k_packed.shape[0]
+        P = c.codes.shape[-3]
         page = jnp.take_along_axis(bt, cidx[:, None], axis=1)[:, 0]
         # page 0 is the reserved zero page: an idle slot (all-zero table
         # row) crossing a buffer boundary must drop its write rather than
@@ -1396,28 +1638,22 @@ def append_token_paged(cfg: CacheConfig, pcache: PagedGEARLayerCache,
         # same slot-/step-invariant key as the dense path: a paged slot's
         # chunk is bit-identical to the dense slot's (splice isolation)
         comp = _compress_chunks(cfg, kc, vc, pol.rank_decode, key)
-        upd = {}
-
-        def put(field, vals):
-            pool = getattr(c, field)
-            upd[field] = pool.at[page].set(vals.astype(pool.dtype), mode="drop")
-
-        put("k_packed", comp["k_packed"].reshape(B, H, nb, -1))
-        put("v_packed", comp["v_packed"].reshape(B, H, nb, -1))
+        fields = {"k_packed": comp["k_packed"].reshape(B, H, nb, -1),
+                  "v_packed": comp["v_packed"].reshape(B, H, nb, -1)}
         for kv in ("k", "v"):
-            put(f"{kv}_scale", _flatten_stat(cfg, comp[f"{kv}_scale"], kv))
-            put(f"{kv}_zero", _flatten_stat(cfg, comp[f"{kv}_zero"], kv))
+            fields[f"{kv}_scale"] = _flatten_stat(cfg, comp[f"{kv}_scale"], kv)
+            fields[f"{kv}_zero"] = _flatten_stat(cfg, comp[f"{kv}_zero"], kv)
             if pol.use_lowrank:
-                put(f"{kv}_a", comp[f"{kv}_a"].reshape(B, H, nb, pol.rank))
-                put(f"{kv}_b", comp[f"{kv}_b"])
+                fields[f"{kv}_a"] = comp[f"{kv}_a"].reshape(B, H, nb, pol.rank)
+                fields[f"{kv}_b"] = comp[f"{kv}_b"]
             if pol.use_sparse:
-                sv, si = comp[f"{kv}_sp_val"], comp[f"{kv}_sp_idx"]
-                if kv == "v" or cfg.k_scheme()[0] != "per_channel":
-                    sv = sv.reshape(B, H, nb, sv.shape[-1])
-                    si = si.reshape(B, H, nb, si.shape[-1])
-                put(f"{kv}_sp_val", sv)
-                put(f"{kv}_sp_idx", si)
-        return dataclasses.replace(c, **upd)
+                for part in ("val", "idx"):
+                    x = comp[f"{kv}_sp_{part}"]
+                    if kv == "v" or cfg.k_scheme()[0] != "per_channel":
+                        x = x.reshape(B, H, nb, x.shape[-1])
+                    fields[f"{kv}_sp_{part}"] = x
+        return _set_pages(c, page,
+                          chunks_to_pages(_pool_layout(cfg, c), fields))
 
     any_boundary = jnp.any((pcache.length % nb == 0) & (pcache.length > 0)
                            & (pcache.length <= cfg.capacity))

@@ -19,8 +19,20 @@ normalizes once.  HBM traffic per step ≈ packed bits + stats + factors
 ≈ (bits/16 + overheads) × the FP16 cache — the memory-roofline win that
 produces the paper's throughput gain on memory-bound decode.
 
-Grid: (BH, C).  Block shapes are MXU/VPU aligned: Dh ∈ {64, 128, 256} maps
-to lane-dim 128 tiles; the chunk dim (n_b = 64/128) is the sublane dim.
+Grid: (BH, C) over the dense layout's rows.  Block shapes are MXU/VPU
+aligned: Dh ∈ {64, 128, 256} maps to lane-dim 128 tiles; the chunk dim
+(n_b = 64/128) is the sublane dim.
+
+**Paged layout.**  :func:`gear_decode_paged` reads the pool's lane-dense
+pages (``repro.core.cache.page_layout``): two leaves, ``codes`` (int32
+token-major code words) and ``side`` (every other field, long axis on
+lanes), ``[rows, 128]`` a page.  Its grid is (B, C): a step DMAs one
+page of each leaf for one slot's chunk and works all of the slot's KV
+heads.  Each head's fields are unfolded from the page rows into the tiles
+and factors the dense layout's de-quantization builds (codes by whole-vreg
+shifts, per-token V rows turned to columns and low-rank factors to the
+dense orientation by small transposes in VMEM); from there both layouts
+run the same online-softmax update (:func:`_attend_chunk`).
 
 **Ragged batches.**  ``n_comp`` may be a scalar (all slots at one extent) or
 a per-row ``[BH]`` vector: each (bh, c) grid program reads its own row's
@@ -47,6 +59,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.cache import LANES, unfold_rows, unpack_words
 
 __all__ = ["gear_decode", "gear_decode_paged"]
 
@@ -86,6 +100,83 @@ def _window_row(ref, c):
     return jnp.sum(jnp.where(sel, w, 0.0), axis=0, keepdims=True)
 
 
+# ---------------------------------------------------------------------------
+# The chunk's math, shared by both layouts: each layout's de-quantization
+# rebuilds the same f32 tiles and factors, then one online-softmax update.
+
+
+def _k_tile(codes, scale, zero, sp):
+    """K tile [nb, Dh] from codes [nb, Dh] f32, per-channel ``scale`` /
+    ``zero`` [1, Dh] and the outliers ``sp = (val, idx)`` [Ks, Dh] (token
+    index of channel d's j-th outlier at (j, d)), or None."""
+    k = codes * scale + zero
+    if sp is not None:
+        val, idx = sp
+        row = jax.lax.broadcasted_iota(jnp.int32, k.shape, 0)
+        for j in range(val.shape[0]):
+            k += jnp.where(row == idx[j:j + 1], val[j:j + 1], 0.0)
+    return k
+
+
+def _v_tile(codes, scale, zero, sp):
+    """V tile [nb, Dh] from codes [nb, Dh] f32, per-token ``scale`` /
+    ``zero`` [nb, Dh] and the outliers ``sp = (val, idx)`` [nb, Kv]
+    (channel index of token t's j-th outlier at (t, j)), or None."""
+    v = codes * scale + zero
+    if sp is not None:
+        val, idx = sp
+        col = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+        for j in range(val.shape[-1]):
+            v += jnp.where(col == idx[:, j][:, None], val[:, j][:, None], 0.0)
+    return v
+
+
+def _attend_chunk(q, k, v, lr, acc, m, l, tok0, n, *, scale_factor: float):
+    """One live chunk's online-softmax update of a (acc [G, Dh], m, l
+    [G, 128]) triple: scores of ``q`` [G, Dh] against the chunk's K tile
+    plus the factored low-rank path, masked past extent ``n`` (the chunk's
+    first token is ``tok0``), accumulated against its V tile.
+
+    ``lr`` is None or the factors ``(k_a, k_b, v_a, v_b)``: ``[nb, r]``,
+    ``[Dh, r]``, ``[nb, r]``, ``[Dh, r]``.
+    """
+    f32 = jnp.float32
+    dot = lambda a, b, ca, cb: jax.lax.dot_general(
+        a, b, (((ca,), (cb,)), ((), ())), preferred_element_type=f32)
+    G, nb = q.shape[0], k.shape[0]
+    s = dot(q, k, 1, 1)                                        # [G, nb]
+    if lr is not None:
+        ka, kb, va, vb = lr
+        qb = dot(q, kb, 1, 0)                                  # [G, r]
+        s += dot(qb, ka, 1, 1)
+    s = s * scale_factor
+    tok = tok0 + jax.lax.broadcasted_iota(jnp.int32, (G, nb), 1)
+    s = jnp.where(tok < n, s, NEG_INF)
+
+    m_prev = m[:, 0]                                           # [G]
+    m_cur = jnp.max(s, axis=-1)
+    m_new = jnp.maximum(m_prev, m_cur)
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new[:, None])                            # [G, nb]
+    l = l * corr[:, None] + jnp.sum(p, axis=-1)[:, None]
+    pv = dot(p, v, 1, 0)
+    if lr is not None:
+        pa = dot(p, va, 1, 0)                                  # [G, r]
+        pv += dot(pa, vb, 1, 1)
+    acc = acc * corr[:, None] + pv
+    return acc, jnp.broadcast_to(m_new[:, None], m.shape), l
+
+
+def _init_triple(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+# ---------------------------------------------------------------------------
+# Dense layout: grid (BH, C), one (slot, head) row's chunk a step
+
+
 def _kernel(n_comp_ref, q_ref, kp_ref, ks_ref, kz_ref, vp_ref, vs_ref, vz_ref,
             ka_ref, kb_ref, va_ref, vb_ref,
             ksv_ref, ksi_ref, vsv_ref, vsi_ref,
@@ -98,75 +189,31 @@ def _kernel(n_comp_ref, q_ref, kp_ref, ks_ref, kz_ref, vp_ref, vs_ref, vz_ref,
 
     @pl.when(c == 0)
     def _init():
-        acc_ref[0] = jnp.zeros_like(acc_ref[0])
-        m_ref[0] = jnp.full_like(m_ref[0], NEG_INF)
-        l_ref[0] = jnp.zeros_like(l_ref[0])
+        _init_triple(acc_ref, m_ref, l_ref)
 
     # live chunks are the first ceil(n / chunk); a chunk past them would
     # add corr = 1, p = 0, so its step runs no body
     @pl.when(c * chunk < n)
     def _chunk():
-        nb = chunk
-        q = q_ref[0].astype(jnp.float32)                       # [G, Dh]
-        G, Dh = q.shape
-
-        # ---- K chunk: dequant + outliers ----------------------------------
-        k_tile = _unpack(kp_ref[0], bits, Dh)                  # [nb, Dh]
-        k_tile = k_tile * _window_row(ks_ref, c) + _window_row(kz_ref, c)
+        f32 = lambda ref: ref.astype(jnp.float32)
+        Dh = q_ref.shape[-1]
+        k_sp = v_sp = lr = None
         if use_sp:
             # [Dh, Ks] -> [Ks, Dh]: channel on lanes, like the tile
-            ksv = ksv_ref[0, 0].astype(jnp.float32).T
-            ksi = ksi_ref[0, 0].T
-            row = jax.lax.broadcasted_iota(jnp.int32, (nb, Dh), 0)
-            for j in range(ksv.shape[0]):
-                k_tile += jnp.where(row == ksi[j:j + 1], ksv[j:j + 1], 0.0)
-
-        s = jax.lax.dot_general(q, k_tile, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [G, nb]
+            k_sp = (f32(ksv_ref[0, 0]).T, ksi_ref[0, 0].T)
+            v_sp = (f32(vsv_ref[0]), vsi_ref[0])               # [nb, Kv]
         if use_lr:
-            kb = kb_ref[0, 0].astype(jnp.float32)              # [Dh, r]
-            ka = ka_ref[0].astype(jnp.float32)                 # [nb, r]
-            qb = jax.lax.dot_general(q, kb, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)  # [G, r]
-            s += jax.lax.dot_general(qb, ka, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-        s = s * scale_factor
-
-        tok = c * nb + jax.lax.broadcasted_iota(jnp.int32, (G, nb), 1)
-        s = jnp.where(tok < n, s, NEG_INF)
-
-        # ---- V chunk --------------------------------------------------------
-        v_tile = _unpack(vp_ref[0], bits, Dh)
+            lr = (f32(ka_ref[0]), f32(kb_ref[0, 0]),           # [nb, r], [Dh, r]
+                  f32(va_ref[0]), f32(vb_ref[0, 0]))
+        k = _k_tile(_unpack(kp_ref[0], bits, Dh), _window_row(ks_ref, c),
+                    _window_row(kz_ref, c), k_sp)
         gv = vs_ref.shape[-1]
-        vsc = jnp.repeat(vs_ref[0].astype(jnp.float32), Dh // gv, axis=-1)
-        vzr = jnp.repeat(vz_ref[0].astype(jnp.float32), Dh // gv, axis=-1)
-        v_tile = v_tile * vsc + vzr
-        if use_sp:
-            vsv = vsv_ref[0].astype(jnp.float32)               # [nb, Kv]
-            vsi = vsi_ref[0]
-            col = jax.lax.broadcasted_iota(jnp.int32, (nb, Dh), 1)
-            for j in range(vsv.shape[-1]):
-                v_tile += jnp.where(col == vsi[:, j][:, None],
-                                    vsv[:, j][:, None], 0.0)
-
-        # ---- online softmax -------------------------------------------------
-        m_prev = m_ref[0][:, 0]                                # [G]
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])                        # [G, nb]
-        l_ref[0] = l_ref[0] * corr[:, None] + jnp.sum(p, axis=-1)[:, None]
-        pv = jax.lax.dot_general(p, v_tile, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if use_lr:
-            va = va_ref[0].astype(jnp.float32)                 # [nb, r]
-            vb = vb_ref[0, 0].astype(jnp.float32)              # [Dh, r]
-            pa = jax.lax.dot_general(p, va, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)  # [G, r]
-            pv += jax.lax.dot_general(pa, vb, (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        acc_ref[0] = acc_ref[0] * corr[:, None] + pv
-        m_ref[0] = jnp.broadcast_to(m_new[:, None], m_ref[0].shape)
+        v = _v_tile(_unpack(vp_ref[0], bits, Dh),
+                    jnp.repeat(f32(vs_ref[0]), Dh // gv, axis=-1),
+                    jnp.repeat(f32(vz_ref[0]), Dh // gv, axis=-1), v_sp)
+        acc_ref[0], m_ref[0], l_ref[0] = _attend_chunk(
+            f32(q_ref[0]), k, v, lr, acc_ref[0], m_ref[0], l_ref[0],
+            c * chunk, n, scale_factor=scale_factor)
 
 
 @functools.partial(
@@ -259,112 +306,141 @@ def gear_decode(
       k_a, k_b, v_a, v_b, k_sp_val, k_sp_idx, v_sp_val, v_sp_idx)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("bits", "chunk", "scale_factor", "interpret"),
-)
-def gear_decode_paged(
-    q, k_packed, k_scale, k_zero, v_packed, v_scale, v_zero, n_comp,
-    block_tables,
-    k_a=None, k_b=None, v_a=None, v_b=None,
-    k_sp_val=None, k_sp_idx=None, v_sp_val=None, v_sp_idx=None,
-    *, bits: int, chunk: int, scale_factor: float, interpret: bool = False,
-):
-    """Paged twin of :func:`gear_decode`: same kernel body, same math, but
-    the compressed operands are *head-flattened pool pages* addressed
-    through scalar-prefetched block tables instead of contiguous rows.
+# ---------------------------------------------------------------------------
+# Paged layout: grid (B, C), one slot's chunk, every KV head, a step
 
-    Pool operands are ``[P*H, ...one-chunk-block]`` (a pool leaf
-    ``[P, H, ...]`` reshaped by the caller): page ``p``, head ``h`` lives at
-    row ``p*H + h``.  ``block_tables [B, C]`` arrives via
-    ``PrefetchScalarGridSpec`` so every BlockSpec index map can compute its
-    DMA source ``row = bt[bh // H, c] * H + bh % H`` before the grid step
-    runs — the gather happens in the DMA engine, not as kernel gather ops.
-    Pages behind table entries past a row's live chunks never reach the
-    math (the body skips them), so the accumulated (acc, m, l) triple is
-    bit-identical to :func:`gear_decode` on the gathered-dense cache
-    whatever those entries point at.  ``n_comp`` handling is unchanged
-    (ragged per-row extents).
+
+def _page_field(blk, f, h: int, transposed: bool):
+    """Head ``h``'s field ``f`` (a :class:`~repro.core.cache.PageField`) out
+    of a page block ``blk`` [rows, 128], transposed against the dense chunk
+    field iff ``transposed``."""
+    x = unfold_rows(blk[f.offset + h * f.rows:f.offset + (h + 1) * f.rows], f)
+    return x.T if transposed != f.transposed else x
+
+
+def _page_codes(words, f, h: int, bits: int, chunk: int):
+    """Head ``h``'s codes [chunk, Dh] f32 from the page's code words."""
+    x = _page_field(words, f, h, False)                        # [a, Dh]
+    return unpack_words(x, bits, chunk).astype(jnp.float32)
+
+
+def _dequant_page(words, side, h: int, layout, dh: int, pin: bool):
+    """Head ``h``'s K and V tiles [chunk, Dh] and low-rank factors (or
+    None) from one page's ``codes`` words and f32 ``side`` rows: the values
+    the dense layout's de-quantization builds, bit for bit."""
+    nb, bits = layout.chunk, layout.bits
+    get = lambda name, t: _page_field(side, layout.field(name), h, t)
+    k_sp = v_sp = lr = None
+    if layout.field("k_sp_val") is not None:
+        k_sp = (get("k_sp_val", True),                          # [Ks, Dh]
+                get("k_sp_idx", True).astype(jnp.int32))
+        v_sp = (get("v_sp_val", False),                         # [nb, Kv]
+                get("v_sp_idx", False).astype(jnp.int32))
+    if layout.field("k_a") is not None:
+        lr = tuple(get(n, False) for n in ("k_a", "k_b", "v_a", "v_b"))
+        if pin:
+            # interpreted, XLA would fold these transposes into the dots and
+            # sum the rank-r contraction in another order than the dense
+            # kernel's; Mosaic folds nothing (and lowers no barrier)
+            lr = jax.lax.optimization_barrier(lr)
+    k = _k_tile(_page_codes(words, layout.field("k_packed"), h, bits, nb),
+                get("k_scale", False), get("k_zero", False), k_sp)
+    vs, vz = get("v_scale", False), get("v_zero", False)       # [nb, gv]
+    rep = dh // vs.shape[-1]
+    v = _v_tile(_page_codes(words, layout.field("v_packed"), h, bits, nb),
+                jnp.repeat(vs, rep, axis=-1), jnp.repeat(vz, rep, axis=-1),
+                v_sp)
+    return k, v, lr
+
+
+def _paged_kernel(bt_ref, n_comp_ref, layer_ref, q_ref, codes_ref, side_ref,
+                  acc_ref, m_ref, l_ref, *, layout, scale_factor: float,
+                  interpret: bool):
+    del bt_ref, layer_ref  # consumed by the index maps
+    b = pl.program_id(0)
+    c = pl.program_id(1)
+    n = n_comp_ref[b]
+    chunk = layout.chunk
+
+    @pl.when(c == 0)
+    def _init():
+        _init_triple(acc_ref, m_ref, l_ref)
+
+    @pl.when(c * chunk < n)
+    def _chunk():
+        words = codes_ref[0]                                   # [Rc, 128]
+        side = side_ref[0].astype(jnp.float32)                 # [Rs, 128]
+        for h in range(layout.heads):
+            q = q_ref[0, h].astype(jnp.float32)                # [G, Dh]
+            k, v, lr = _dequant_page(words, side, h, layout, q.shape[-1],
+                                     pin=interpret)
+            acc_ref[0, h], m_ref[0, h], l_ref[0, h] = _attend_chunk(
+                q, k, v, lr, acc_ref[0, h], m_ref[0, h], l_ref[0, h],
+                c * chunk, n, scale_factor=scale_factor)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("layout", "scale_factor", "interpret"))
+def gear_decode_paged(q, codes, side, n_comp, block_tables, layer=0, *,
+                      layout, scale_factor: float, interpret: bool = False):
+    """Paged twin of :func:`gear_decode`: the same math over lane-dense
+    pool pages addressed through scalar-prefetched block tables.
+
+    ``codes`` and ``side`` are the pool's leaves ``[P, rows, 128]``, or the
+    pools of several layers stacked ``[L, P, rows, 128]`` of which the
+    kernel reads layer ``layer``; ``layout`` places the fields in a page
+    (``repro.core.cache.page_layout``: every field of a page, every KV
+    head, in whole tiles).  ``q`` is ``[B*H, G, Dh]`` (slot-major rows, as
+    :func:`gear_decode` takes them); ``n_comp`` is a scalar or a per-slot
+    ``[B]`` compressed extent; ``block_tables [B, C]`` arrives via
+    ``PrefetchScalarGridSpec`` so each index map computes its DMA source
+    page ``bt[b, c]`` before the grid step runs — the gather happens in the
+    DMA engine, two DMAs a step (codes, side), not kernel gather ops.  One
+    grid step takes one slot's chunk for all its heads.  Pages behind table
+    entries past a slot's live chunks never reach the math (the body skips
+    them), so the (acc, m, l) triple is bit-identical to :func:`gear_decode`
+    on the gathered-dense cache whatever those entries point at.  Returns
+    ``(acc [B*H, G, Dh], m, l [B*H, G, 128])``.
     """
     BH, G, Dh = q.shape
     B, C = block_tables.shape
-    H = BH // B
-    Lp = k_packed.shape[-1]
-    use_lr = k_a is not None
-    use_sp = k_sp_val is not None
-    r = k_a.shape[-1] if use_lr else 1
-    ks2 = k_sp_val.shape[-1] if use_sp else 1
-    kv2 = v_sp_val.shape[-1] if use_sp else 1
-    gv = v_scale.shape[-1]
-    nb = chunk
+    H = layout.heads
     f32 = jnp.float32
-
-    # page-row index map shared by every pool operand: the chunk coordinate
-    # is consumed by the block-table lookup, the block covers the whole page
-    def prow(*tail):
-        return lambda x, c, bt, n: (
-            (bt[x // H, c] * H + x % H).astype(jnp.int32), *tail)
-
-    # dummy single-page operands when the policy has no low-rank / sparse
-    # fields; their index maps pin to row 0 so no table lookup happens
-    zrow = lambda *tail: (lambda x, c, bt, n: (0, *tail))
-    if not use_lr:
-        k_a = jnp.zeros((1, nb, 1), f32); k_b = jnp.zeros((1, 1, Dh, 1), f32)
-        v_a = jnp.zeros((1, nb, 1), f32); v_b = jnp.zeros((1, 1, Dh, 1), f32)
-    if not use_sp:
-        k_sp_val = jnp.zeros((1, 1, Dh, 1), f32)
-        k_sp_idx = jnp.full((1, 1, Dh, 1), -1, jnp.int32)
-        v_sp_val = jnp.zeros((1, nb, 1), f32)
-        v_sp_idx = jnp.full((1, nb, 1), -1, jnp.int32)
-    lr_row = prow if use_lr else zrow
-    sp_row = prow if use_sp else zrow
-
-    n_comp_arr = jnp.broadcast_to(jnp.asarray(n_comp, jnp.int32), (BH,))
+    if codes.ndim == 3:
+        codes, side = codes[None], side[None]
+    n_comp_arr = jnp.broadcast_to(jnp.asarray(n_comp, jnp.int32), (B,))
     bt = jnp.asarray(block_tables, jnp.int32)
-
-    def kernel(bt_ref, *refs):
-        del bt_ref  # consumed by the index maps
-        _kernel(*refs, bits=bits, chunk=chunk, scale_factor=scale_factor,
-                use_lr=use_lr, use_sp=use_sp)
-
-    bh = lambda x, c, bt, n: (x, 0, 0)
+    lay = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+    kernel = functools.partial(_paged_kernel, layout=layout,
+                               scale_factor=scale_factor, interpret=interpret)
+    slot = lambda b, c, bt, n, lay: (b, 0, 0, 0)
+    page = lambda b, c, bt, n, lay: (lay[0], bt[b, c], 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(BH, C),
+        num_scalar_prefetch=3,
+        grid=(B, C),
         in_specs=[
-            pl.BlockSpec((1, G, Dh), bh),                          # q
-            pl.BlockSpec((1, chunk, Lp), prow(0, 0)),              # k_packed
-            pl.BlockSpec((1, 1, Dh), prow(0, 0)),                  # k_scale
-            pl.BlockSpec((1, 1, Dh), prow(0, 0)),                  # k_zero
-            pl.BlockSpec((1, chunk, Lp), prow(0, 0)),              # v_packed
-            pl.BlockSpec((1, chunk, gv), prow(0, 0)),              # v_scale
-            pl.BlockSpec((1, chunk, gv), prow(0, 0)),              # v_zero
-            pl.BlockSpec((1, chunk, r), lr_row(0, 0)),             # k_a
-            pl.BlockSpec((1, 1, Dh, r), lr_row(0, 0, 0)),          # k_b
-            pl.BlockSpec((1, chunk, r), lr_row(0, 0)),             # v_a
-            pl.BlockSpec((1, 1, Dh, r), lr_row(0, 0, 0)),          # v_b
-            pl.BlockSpec((1, 1, Dh, ks2), sp_row(0, 0, 0)),        # k_sp_val
-            pl.BlockSpec((1, 1, Dh, ks2), sp_row(0, 0, 0)),        # k_sp_idx
-            pl.BlockSpec((1, chunk, kv2), sp_row(0, 0)),           # v_sp_val
-            pl.BlockSpec((1, chunk, kv2), sp_row(0, 0)),           # v_sp_idx
+            pl.BlockSpec((1, H, G, Dh), slot),                     # q
+            pl.BlockSpec((None, 1) + codes.shape[2:], page),       # codes
+            pl.BlockSpec((None, 1) + side.shape[2:], page),        # side
         ],
         out_specs=(
-            pl.BlockSpec((1, G, Dh), bh),
-            pl.BlockSpec((1, G, 128), bh),
-            pl.BlockSpec((1, G, 128), bh),
+            pl.BlockSpec((1, H, G, Dh), slot),
+            pl.BlockSpec((1, H, G, LANES), slot),
+            pl.BlockSpec((1, H, G, LANES), slot),
         ),
     )
     out_shape = (
-        jax.ShapeDtypeStruct((BH, G, Dh), f32),
-        jax.ShapeDtypeStruct((BH, G, 128), f32),
-        jax.ShapeDtypeStruct((BH, G, 128), f32),
+        jax.ShapeDtypeStruct((B, H, G, Dh), f32),
+        jax.ShapeDtypeStruct((B, H, G, LANES), f32),
+        jax.ShapeDtypeStruct((B, H, G, LANES), f32),
     )
-    return pl.pallas_call(
+    acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=_PARAMS,
         interpret=interpret,
         name="gear_decode_paged",
-    )(bt, n_comp_arr, q, k_packed, k_scale, k_zero, v_packed, v_scale,
-      v_zero, k_a, k_b, v_a, v_b, k_sp_val, k_sp_idx, v_sp_val, v_sp_idx)
+    )(bt, n_comp_arr, lay, q.reshape(B, H, G, Dh), codes, side)
+    return (acc.reshape(BH, G, Dh), m.reshape(BH, G, LANES),
+            l.reshape(BH, G, LANES))

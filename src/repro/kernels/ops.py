@@ -16,8 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.cache import (CacheConfig, PagedGEARLayerCache,
-                              streaming_supported)
+from repro.core.cache import (CacheConfig, PagedGEARLayerCache, layer_pool,
+                              page_layout, streaming_supported)
 from repro.launch.mesh import MODEL, dp_axes
 from repro.kernels import ref as ref_ops
 from repro.kernels.flash_prefill import flash_prefill, flash_prefill_block
@@ -120,31 +120,6 @@ def _gear_operands(cfg: CacheConfig, cache, BH: int):
     return arrays, lr, sp
 
 
-def _pool_flat(x):
-    """Pool leaf [P, H, ...] -> kernel row layout [P*H, ...] (page p, head
-    h at row p*H + h — the addressing ``gear_decode_paged`` index maps and
-    ``gear_decode_paged_ref`` both assume)."""
-    return None if x is None else x.reshape((-1,) + x.shape[2:])
-
-
-def _paged_operands(cfg: CacheConfig, pcache: PagedGEARLayerCache):
-    """Paged twin of :func:`_gear_operands`: head-flattened pool pages in
-    the ``gear_decode_paged`` operand order."""
-    pol = cfg.policy
-    lr = dict(
-        k_a=_pool_flat(pcache.k_a), k_b=_pool_flat(pcache.k_b),
-        v_a=_pool_flat(pcache.v_a), v_b=_pool_flat(pcache.v_b),
-    ) if pol.use_lowrank else {}
-    sp = dict(
-        k_sp_val=_pool_flat(pcache.k_sp_val), k_sp_idx=_pool_flat(pcache.k_sp_idx),
-        v_sp_val=_pool_flat(pcache.v_sp_val), v_sp_idx=_pool_flat(pcache.v_sp_idx),
-    ) if pol.use_sparse else {}
-    arrays = (_pool_flat(pcache.k_packed), _pool_flat(pcache.k_scale),
-              _pool_flat(pcache.k_zero), _pool_flat(pcache.v_packed),
-              _pool_flat(pcache.v_scale), _pool_flat(pcache.v_zero))
-    return arrays, lr, sp
-
-
 def _merge_buffer(cfg: CacheConfig, cache, qf, acc, m, l, n_buf, scale):
     """Merge the FP16 streaming-buffer region into a history (acc, m, l)
     triple and normalize — the XLA tail both decode paths (dense
@@ -173,8 +148,7 @@ def gear_attend_block(cfg: CacheConfig, cache, q: jnp.ndarray,
                       n_comp, blk_len, scale: float,
                       force_kernel: bool = False,
                       interpret: bool = False,
-                      force_oracle: bool = False,
-                      block_tables: jnp.ndarray | None = None) -> jnp.ndarray:
+                      force_oracle: bool = False) -> jnp.ndarray:
     """Streaming-prefill attention of one query block: compressed history
     + in-flight FP16 block, merged with a two-piece online softmax.
 
@@ -187,11 +161,6 @@ def gear_attend_block(cfg: CacheConfig, cache, q: jnp.ndarray,
     ``fused="off"`` escape hatch) with the chunk's T·G query rows sharing
     one extent mask; the block piece is ``flash_prefill_block`` with causal
     masking.  Returns [B, Hq, T, Dh] in q's dtype.
-
-    A :class:`~repro.core.cache.PagedGEARLayerCache` history (pool pages +
-    ``block_tables [B, C]``) takes the same contract: the fused path runs
-    :func:`gear_decode_paged`, the oracle path gathers the pool rows and
-    runs the identical dense history math.
     """
     pol = cfg.policy
     B, Hq, T, Dh = q.shape
@@ -203,38 +172,18 @@ def gear_attend_block(cfg: CacheConfig, cache, q: jnp.ndarray,
     qf = q.astype(f32).reshape(B, H, G, T, Dh)
     use_kernel = (on_tpu() or force_kernel) and not force_oracle
     run_interp = interpret or not on_tpu()
-    paged = isinstance(cache, PagedGEARLayerCache)
-    if paged and block_tables is None:
-        raise ValueError("paged history needs block_tables")
 
     # --- compressed history: unnormalized (acc, m, l) over T·G query rows --
     kwargs = dict(bits=pol.bits, chunk=nb, scale_factor=scale)
-    if paged:
-        arrays, lr, sp = _paged_operands(cfg, cache)
-    else:
-        arrays, lr, sp = _gear_operands(cfg, cache, BH)
+    arrays, lr, sp = _gear_operands(cfg, cache, BH)
     n_comp_bh = jnp.broadcast_to(jnp.asarray(n_comp, jnp.int32), (BH,))
     q_rows = qf.reshape(BH, G * T, Dh)
     common = (q_rows, *arrays, n_comp_bh)
     if use_kernel:
-        if paged:
-            acc_h, m_h, l_h = gear_decode_paged(
-                *common, jnp.asarray(block_tables, jnp.int32),
-                interpret=run_interp, **kwargs, **lr, **sp)
-        else:
-            acc_h, m_h, l_h = gear_decode(*common, interpret=run_interp,
-                                          **kwargs, **lr, **sp)
+        acc_h, m_h, l_h = gear_decode(*common, interpret=run_interp,
+                                      **kwargs, **lr, **sp)
         m_h, l_h = m_h[..., 0], l_h[..., 0]
     else:
-        if paged:
-            names = ("k_packed", "k_scale", "k_zero",
-                     "v_packed", "v_scale", "v_zero")
-            g = ref_ops.gather_paged_operands(
-                block_tables, BH, dict(zip(names, arrays)) | lr | sp)
-            arrays = tuple(g[n] for n in names)
-            lr = {n: g[n] for n in lr}
-            sp = {n: g[n] for n in sp}
-            common = (q_rows, *arrays, n_comp_bh)
         acc_h, m_h, l_h = ref_ops.gear_hist_block_ref(*common, **kwargs,
                                                       **lr, **sp)
     acc_h = acc_h.reshape(B, H, G, T, Dh)
@@ -335,37 +284,35 @@ def gear_attend_paged(cfg: CacheConfig, pcache: PagedGEARLayerCache,
     history lives in pool pages addressed through ``block_tables [B, C]``.
 
     The fused path is :func:`gear_decode_paged` (scalar-prefetched tables,
-    page gather in the DMA engine); off-TPU the
-    :func:`~repro.kernels.ref.gear_decode_paged_ref` oracle gathers the
-    pool and defers to the dense oracle.  The FP16 streaming buffer is
+    page gather in the DMA engine, a slot's heads in one grid step); off-TPU
+    the :func:`~repro.kernels.ref.gear_decode_paged_ref` oracle converts the
+    pages back and defers to the dense oracle.  The FP16 streaming buffer is
     per-slot (not paged) and merges through the same
     :func:`_merge_buffer` tail as the dense path, so a paged slot's output
     is bit-identical to the dense slot's for the same history.
     """
-    pol = cfg.policy
     B, Hq, Dh = q.shape
     H = cfg.kv_heads
     G = Hq // H
-    BH = B * H
-    qf = q.astype(jnp.float32).reshape(BH, G, Dh)
+    qf = q.astype(jnp.float32).reshape(B * H, G, Dh)
     nb = cfg.chunk
     length = jnp.broadcast_to(jnp.asarray(pcache.length, jnp.int32), (B,))
-    len_bh = jnp.repeat(length, H)
-    n_comp = (len_bh // nb) * nb
-    n_buf = len_bh - n_comp
+    n_comp = (length // nb) * nb              # [B] compressed extent per slot
+    n_buf = jnp.repeat(length - n_comp, H)    # [BH] streaming-buffer fill
+    layout = page_layout(cfg, pcache.side.dtype)
     bt = jnp.asarray(block_tables, jnp.int32)
-
-    kwargs = dict(bits=pol.bits, chunk=nb, scale_factor=scale)
-    arrays, lr, sp = _paged_operands(cfg, pcache)
-    common = (qf, *arrays, n_comp, bt)
     if on_tpu() or force_kernel:
-        acc, m, l = gear_decode_paged(*common,
-                                      interpret=interpret or not on_tpu(),
-                                      **kwargs, **lr, **sp)
+        # a stacked pool goes to the kernel whole: its DMAs index the layer
+        layer = 0 if pcache.layer is None else pcache.layer
+        acc, m, l = gear_decode_paged(
+            qf, pcache.codes, pcache.side, n_comp, bt, layer, layout=layout,
+            scale_factor=scale, interpret=interpret or not on_tpu())
         m, l = m[..., 0], l[..., 0]
     else:
-        acc, m, l = ref_ops.gear_decode_paged_ref(*common, **kwargs,
-                                                  **lr, **sp)
+        pool = layer_pool(pcache)
+        acc, m, l = ref_ops.gear_decode_paged_ref(
+            qf, pool["codes"], pool["side"], n_comp, bt, layout=layout,
+            scale_factor=scale)
 
     out = _merge_buffer(cfg, pcache, qf, acc, m, l, n_buf, scale)
     return out.reshape(B, Hq, Dh).astype(q.dtype)

@@ -14,10 +14,11 @@ import jax.numpy as jnp
 from repro.core import outlier as ol
 from repro.core import packing
 from repro.core import quant as q_lib
+from repro.core.cache import gather_pages
 
 __all__ = ["quant_pack_ref", "gear_decode_ref", "gear_decode_paged_ref",
            "gear_hist_block_ref", "flash_prefill_ref", "gear_compress_ref",
-           "flash_block_ref", "gather_paged_operands"]
+           "flash_block_ref"]
 
 NEG_INF = -1e30
 
@@ -113,58 +114,29 @@ def gear_decode_ref(
     return acc, m, l
 
 
-def gear_decode_paged_ref(
-    q, k_packed, k_scale, k_zero, v_packed, v_scale, v_zero, n_comp,
-    block_tables, *,
-    bits: int, chunk: int, scale_factor: float,
-    k_a=None, k_b=None, v_a=None, v_b=None,
-    k_sp_val=None, k_sp_idx=None, v_sp_val=None, v_sp_idx=None,
-):
+def gear_decode_paged_ref(q, codes, side, n_comp, block_tables, *,
+                          layout, scale_factor: float):
     """Oracle for :func:`repro.kernels.gear_decode.gear_decode_paged` and
     the portable CPU/GPU paged-decode fallback.
 
-    Takes the *same* operands as the paged kernel — head-flattened pool
-    pages ``[P*H, ...one-chunk]`` plus ``block_tables [B, C]`` — gathers
-    them back to the dense row layout (page ``bt[b, c]``, head ``h`` →
-    row ``bt[b, c]*H + h``), and defers to :func:`gear_decode_ref`.  Under
-    the pool's zero-page invariant the gathered operands are bitwise equal
-    to the dense cache's, so this oracle is exact, not approximate.
+    Takes the *same* operands as the paged kernel — the pool's ``codes`` /
+    ``side`` leaves placed by ``layout``, per-slot extents and
+    ``block_tables [B, C]`` — converts the pages back to the dense row
+    layout (:func:`repro.core.cache.gather_pages`) and defers to
+    :func:`gear_decode_ref`.  Under the pool's zero-page invariant the
+    gathered operands are bitwise equal to the dense cache's, so this
+    oracle is exact, not approximate.
     """
     BH = q.shape[0]
-    g = gather_paged_operands(
-        block_tables, BH,
-        dict(k_packed=k_packed, k_scale=k_scale, k_zero=k_zero,
-             v_packed=v_packed, v_scale=v_scale, v_zero=v_zero,
-             k_a=k_a, k_b=k_b, v_a=v_a, v_b=v_b,
-             k_sp_val=k_sp_val, k_sp_idx=k_sp_idx,
-             v_sp_val=v_sp_val, v_sp_idx=v_sp_idx))
+    B = block_tables.shape[0]
+    g = gather_pages(layout, codes, side, block_tables)     # [B, H, C·r, ...]
+    g = {name: x.reshape((BH,) + x.shape[2:]) for name, x in g.items()}
+    n_comp = jnp.broadcast_to(jnp.asarray(n_comp, jnp.int32), (B,))
     return gear_decode_ref(
-        q, g["k_packed"], g["k_scale"], g["k_zero"],
-        g["v_packed"], g["v_scale"], g["v_zero"], n_comp,
-        bits=bits, chunk=chunk, scale_factor=scale_factor,
-        k_a=g["k_a"], k_b=g["k_b"], v_a=g["v_a"], v_b=g["v_b"],
-        k_sp_val=g["k_sp_val"], k_sp_idx=g["k_sp_idx"],
-        v_sp_val=g["v_sp_val"], v_sp_idx=g["v_sp_idx"])
-
-
-def gather_paged_operands(block_tables, BH: int, pools: dict) -> dict:
-    """Gather head-flattened pool operands ``[P*H, pg0, ...]`` back to the
-    dense ``[BH, C*pg0, ...]`` row layout through ``block_tables [B, C]``
-    (None leaves pass through).  Shared by the paged oracles and the
-    portable paged-history path of ``gear_attend_block``."""
-    bt = jnp.asarray(block_tables, jnp.int32)
-    B, C = bt.shape
-    H = BH // B
-    # [B, H, C] flat pool rows, flattened to [BH, C] in bh-major order
-    rows = (bt[:, None, :] * H + jnp.arange(H)[None, :, None]).reshape(BH, C)
-
-    def gather(pool):
-        if pool is None:
-            return None
-        g = pool[rows]                               # [BH, C, pg0, ...]
-        return g.reshape((BH, C * g.shape[2]) + g.shape[3:])
-
-    return {name: gather(pool) for name, pool in pools.items()}
+        q, *(g.pop(n) for n in ("k_packed", "k_scale", "k_zero",
+                                "v_packed", "v_scale", "v_zero")),
+        jnp.repeat(n_comp, BH // B), bits=layout.bits, chunk=layout.chunk,
+        scale_factor=scale_factor, **g)
 
 
 def flash_prefill_ref(q, k, v, positions, *, causal: bool = True,

@@ -16,6 +16,7 @@ Modes: ``train`` (full seq, no cache), ``prefill`` (full seq → caches),
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any
 
@@ -484,19 +485,44 @@ def decode_tokens(cfg: ModelConfig, params, token_batch: dict, caches,
     mixed tree ignore it.  Returns (logits [B, 1, ...], new caches)."""
     x = embed_tokens(cfg, params, token_batch)
     B = x.shape[0]
+    # a paged position's pools [R, P, ...] ride the loop's carry whole, and
+    # each layer reads and writes its own pages in place through its
+    # ``layer`` index; slicing them per layer (scan inputs / outputs) would
+    # copy every layer's pool twice a step, and the pool once more at the
+    # loop's end
+    paged = tuple(isinstance(c, cache_lib.PagedGEARLayerCache) for c in caches)
+    pools = tuple((c.codes, c.side) if p else None
+                  for c, p in zip(caches, paged))
+    per_layer = tuple(dataclasses.replace(c, codes=None, side=None) if p else c
+                      for c, p in zip(caches, paged))
 
-    def unit_body(x, xs):
+    def unit_body(carry, xs):
+        x, pools, r = carry
         unit_params, unit_caches = xs
-        new_caches = []
+        new_caches, new_pools = [], []
         for i, kind in enumerate(cfg.layer_pattern):
+            c = unit_caches[i]
+            if paged[i]:
+                c = dataclasses.replace(c, codes=pools[i][0],
+                                        side=pools[i][1], layer=r)
             x, nc = _apply_block_decode(cfg, unit_params[i], x, kind, pos,
-                                        unit_caches[i], policy, B, capacity,
+                                        c, policy, B, capacity,
                                         fused=fused,
                                         block_tables=block_tables)
+            if paged[i]:
+                new_pools.append((nc.codes, nc.side))
+                nc = dataclasses.replace(nc, codes=None, side=None, layer=None)
+            else:
+                new_pools.append(None)
             new_caches.append(nc)
-        return x, tuple(new_caches)
+        return (x, tuple(new_pools), r + 1), tuple(new_caches)
 
-    x, new_caches = jax.lax.scan(unit_body, x, (params["blocks"], caches))
+    (x, pools, _), new_caches = jax.lax.scan(
+        unit_body, (x, pools, jnp.zeros((), jnp.int32)),
+        (params["blocks"], per_layer))
+    new_caches = tuple(
+        dataclasses.replace(c, codes=p[0], side=p[1]) if p is not None else c
+        for c, p in zip(new_caches, pools))
     with jax.named_scope("logits"):
         x = apply_norm(x, params["final_norm"], cfg.norm)
         logits = logits_from_hidden(cfg, params, x)

@@ -132,17 +132,18 @@ class Observability:
     def decode_grid(self, lengths, grid: tuple[int, int, int]) -> None:
         """One decode step's walk of the GEAR decode kernel, per layer call:
         ``lengths`` [B] are the slots' cache lengths the step attends over,
-        ``grid`` is ``Engine.decode_kernel_grid``.  Each row (slot x KV
-        head) walks every capacity chunk; a step does work only on the
-        row's ``ceil(n_comp / chunk)`` live chunks (``n_comp``, the
-        compressed extent, is ``length`` rounded down to a chunk)."""
+        ``grid`` is ``Engine.decode_kernel_grid``.  Each of a slot's rows
+        (one a KV head, or one for the paged kernel) walks every capacity
+        chunk; a step does work only on the row's ``ceil(n_comp / chunk)``
+        live chunks (``n_comp``, the compressed extent, is ``length``
+        rounded down to a chunk)."""
         if self._m:
-            chunk, n_chunks, heads = grid
+            chunk, n_chunks, rows = grid
             live = sum(min(int(n) // chunk, n_chunks) for n in lengths)
             self.registry.get("serving_decode_grid_steps_total").inc(
-                len(lengths) * n_chunks * heads)
+                len(lengths) * n_chunks * rows)
             self.registry.get("serving_decode_live_steps_total").inc(
-                live * heads)
+                live * rows)
 
     def queue_depth(self, n: int) -> None:
         if self._m:

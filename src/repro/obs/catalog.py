@@ -59,11 +59,12 @@ METRICS = (
     MetricSpec("serving_tokens_generated_total", "counter",
                "Tokens sampled across all slots (decode only)."),
     MetricSpec("serving_decode_grid_steps_total", "counter",
-               "GEAR decode kernel grid steps per layer call: slots x KV "
-               "heads x capacity chunks, summed over decode steps."),
+               "GEAR decode kernel grid steps per layer call: slots x rows "
+               "a slot (KV heads; 1 paged) x capacity chunks, summed over "
+               "decode steps."),
     MetricSpec("serving_decode_live_steps_total", "counter",
                "Those grid steps that hold a live chunk (the kernel skips "
-               "the rest): KV heads x sum of ceil(n_comp / chunk) per slot."),
+               "the rest): rows a slot x sum of ceil(n_comp / chunk) per slot."),
     MetricSpec("serving_queue_depth", "gauge",
                "Requests waiting in the scheduler queue."),
     MetricSpec("serving_prefill_seconds", "histogram",

@@ -489,13 +489,17 @@ class Engine:
 
     @property
     def decode_kernel_grid(self) -> tuple[int, int, int] | None:
-        """(chunk, capacity chunks, KV heads): the GEAR decode kernel's
-        grid per slot, one row per KV head walking the capacity's chunks;
-        None when no decode layer runs it (``attend_path`` "xla")."""
+        """(chunk, capacity chunks, rows a slot): the GEAR decode kernel's
+        grid per slot, each row walking the capacity's chunks — one row per
+        KV head for the dense layout's ``gear_decode``, one row for the
+        paged layout's ``gear_decode_paged`` (a grid step takes all of a
+        slot's heads); None when no decode layer runs it (``attend_path``
+        "xla")."""
         if self.attend_path == "xla":
             return None
         nb = self.ecfg.policy.buffer_size
-        return nb, self._cap() // nb, self.cfg.num_kv_heads
+        rows = 1 if self.layout is CacheLayout.PAGED else self.cfg.num_kv_heads
+        return nb, self._cap() // nb, rows
 
     # ------------------------------------------------------------------
     def attach_faults(self, injector) -> None:

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.core import CacheConfig, named_policy, init_layer_cache, prefill_layer_cache
+from repro.core.cache import chunks_to_pages, page_layout
 from repro.kernels.quant_pack import quant_pack
 from repro.kernels.gear_decode import gear_decode, gear_decode_paged
 from repro.kernels.flash_prefill import flash_prefill
@@ -223,23 +224,32 @@ def test_gear_decode_never_reads_dead_chunks(polname, rng):
     _assert_matches_oracle(got, want, live_rows=[1, 2, 3])
 
 
-def _paged_pool(ops: dict, B: int, H: int, live, n_chunks: int):
-    """Dense kernel rows [B*H, X, ...] -> head-flattened pool pages
-    [P*H, X / n_chunks, ...] and block tables [B, n_chunks].  Each live
-    chunk gets a page of its own; every dead table entry points at page 0,
-    which holds NaN / junk."""
+def _paged_pool(cfg, ops: dict, B: int, H: int, live, n_chunks: int):
+    """Dense kernel rows [B*H, X, ...] -> the pool's lane-dense page leaves
+    ``{"codes", "side": [P, rows, 128]}`` (``cache.chunks_to_pages``) and
+    block tables [B, n_chunks].  Each live chunk gets a page of its own;
+    every dead table entry points at page 0, which holds NaN / junk."""
+    layout = page_layout(cfg)
     bt = np.zeros((B, n_chunks), np.int32)
     owners = [(b, c) for b in range(B) for c in range(int(live[b]))]
     for page, (b, c) in enumerate(owners, start=1):
         bt[b, c] = page
-    pools = {}
+    slot_of = np.asarray([b for b, _ in owners], np.int32)
+    chunk_of = np.asarray([c for _, c in owners], np.int32)
+    fields = {}
     for name, x in ops.items():
         rows = x.shape[1] // n_chunks
         xs = x.reshape((B, H, n_chunks, rows) + x.shape[2:])
-        junk = jnp.full(xs.shape[1:2] + xs.shape[3:], _junk(x))
-        pages = jnp.stack([junk] + [xs[b, :, c] for b, c in owners])
-        pools[name] = pages.reshape((-1, rows) + x.shape[2:])
-    return pools, jnp.asarray(bt)
+        fields[name] = xs[slot_of, :, chunk_of]          # [pages, H, rows, ...]
+    pools = {leaf: jnp.concatenate([jnp.full((1,) + x.shape[1:], _junk(x)), x])
+             for leaf, x in chunks_to_pages(layout, fields).items()}
+    return layout, pools, jnp.asarray(bt)
+
+
+def _paged_call(fn, q, layout, pools, n_slot, bt, **kwargs):
+    return fn(q, pools["codes"], pools["side"], n_slot, bt, layout=layout,
+              scale_factor=kwargs["scale_factor"],
+              **({"interpret": True} if fn is gear_decode_paged else {}))
 
 
 @pytest.mark.parametrize("polname", ["gear_kcvt4", "gear_kivi2", "kivi2"])
@@ -251,11 +261,11 @@ def test_gear_decode_paged_never_reads_dead_pages(polname, rng):
     cfg, ops, kwargs = _decode_case(polname, B=B, nb=nb)
     n_slot = jnp.asarray(DEAD_EXTENTS, jnp.int32)           # one per slot
     n_comp = jnp.repeat(n_slot, H)
-    pools, bt = _paged_pool(ops, B, H, -(-n_slot // nb), C)
+    layout, pools, bt = _paged_pool(cfg, ops, B, H, -(-n_slot // nb), C)
     q = jax.random.normal(rng, (B * H, 3, 64))
 
-    got = _call(gear_decode_paged, q, pools, n_comp, bt, interpret=True,
-                **kwargs)
+    got = _paged_call(gear_decode_paged, q, layout, pools, n_slot, bt,
+                      **kwargs)
     dense = _call(gear_decode, q, ops, n_comp, interpret=True, **kwargs)
     for a, b in zip(got, dense):
         assert (a == b).all()
@@ -270,18 +280,19 @@ def test_gear_decode_empty_extent_triple(polname, rng):
     nb, C, B, H = 32, 8, 2, 2
     cfg, ops, kwargs = _decode_case(polname, B=B, nb=nb)
     n_comp = jnp.zeros((B * H,), jnp.int32)
-    pools, bt = _paged_pool(ops, B, H, [0] * B, C)
+    n_slot = jnp.zeros((B,), jnp.int32)
+    layout, pools, bt = _paged_pool(cfg, ops, B, H, [0] * B, C)
     q = jax.random.normal(rng, (B * H, 3, 64))
     for acc, m, l in (
             _call(gear_decode, q, ops, n_comp, interpret=True, **kwargs),
-            _call(gear_decode_paged, q, pools, n_comp, bt, interpret=True,
-                  **kwargs)):
+            _paged_call(gear_decode_paged, q, layout, pools, n_slot, bt,
+                        **kwargs)):
         _assert_empty_triple(acc, m, l)
     zero_page = {k: jnp.zeros_like(v) for k, v in pools.items()}
     for triple in (_call(ref.gear_decode_ref, q, ops, n_comp, **kwargs),
                    _call(ref.gear_hist_block_ref, q, ops, n_comp, **kwargs),
-                   _call(ref.gear_decode_paged_ref, q, zero_page, n_comp, bt,
-                         **kwargs)):
+                   _paged_call(ref.gear_decode_paged_ref, q, layout,
+                               zero_page, n_slot, bt, **kwargs)):
         _assert_empty_triple(*triple)
 
 

@@ -537,11 +537,13 @@ def test_decode_grid_counts_live_chunks():
 
 def test_decode_grid_counters_follow_the_cache(monkeypatch):
     """Over a continuous run, the counters equal what each decode step's
-    cache lengths (read back from the device) give."""
+    cache lengths (read back from the device) give.  The engine is paged:
+    a grid step of its kernel takes all of a slot's KV heads, so a slot is
+    one row of the grid."""
     from repro.core.cache import PagedGEARLayerCache
     eng = _obs_engine()
-    chunk, n_chunks, heads = eng.decode_kernel_grid
-    assert (chunk, heads) == (8, TINY.num_kv_heads) and n_chunks == 6
+    chunk, n_chunks, rows = eng.decode_kernel_grid
+    assert (chunk, rows) == (8, 1) and n_chunks == 6
     reg = eng.obs.registry
     grid0 = reg.get("serving_decode_grid_steps_total").value()
     live0 = reg.get("serving_decode_live_steps_total").value()
@@ -563,10 +565,10 @@ def test_decode_grid_counters_follow_the_cache(monkeypatch):
     sched.run_continuous()
     assert len(seen) == sched.last_stats["decode_steps"] > 0
     n_comp = np.minimum(np.stack(seen) // chunk * chunk, n_chunks * chunk)
-    want_live = heads * int(np.sum(-(-n_comp // chunk)))
+    want_live = rows * int(np.sum(-(-n_comp // chunk)))
     grid = reg.get("serving_decode_grid_steps_total").value() - grid0
     live = reg.get("serving_decode_live_steps_total").value() - live0
-    assert grid == len(seen) * 2 * heads * n_chunks
+    assert grid == len(seen) * 2 * rows * n_chunks
     assert live == want_live > 0
 
 

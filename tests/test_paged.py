@@ -373,3 +373,64 @@ def test_pages_needed():
     assert pages_needed(1, 8) == 1
     assert pages_needed(8, 8) == 1
     assert pages_needed(9, 8) == 2
+
+
+# ---------------------------------------------------------------------------
+# Page layout: lane-dense pages, converted only where chunks enter or leave
+
+
+def _chunk_fields(cfg, n, seed=0):
+    """Random dense chunk fields [n, H, *chunk shape] of every pooled field:
+    codes over all 32 bits, indices anywhere in their range."""
+    rng = np.random.RandomState(seed)
+    out = {}
+    for name, (shape, dtype) in cache_lib.chunk_field_shapes(cfg).items():
+        full = (n,) + shape
+        if name.endswith("_packed"):
+            x = rng.randint(-2**31, 2**31 - 1, size=full, dtype=np.int64)
+        elif name.endswith("_idx"):
+            x = rng.randint(0, max(cfg.chunk, cfg.head_dim), size=full)
+        else:
+            x = rng.standard_normal(full)
+        out[name] = jnp.asarray(x).astype(dtype)
+    return out
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("polname", ["gear_kcvt4", "gear_kivi2", "gear_l_kcvt4",
+                                     "outlier_kivi2", "kcvt4"])
+def test_chunk_page_roundtrip_bit_exact(polname, head_dim):
+    """Dense chunk -> page -> dense is bit-exact for every pooled field, and
+    a page is lane-dense: both leaves [rows, 128] in whole tiles."""
+    cfg = cache_lib.CacheConfig(batch=1, kv_heads=2, head_dim=head_dim,
+                                capacity=256, policy=named_policy(polname))
+    layout = cache_lib.page_layout(cfg)
+    fields = _chunk_fields(cfg, 3)
+    pages = cache_lib.chunks_to_pages(layout, fields)
+    for leaf, ((rows, lanes), dtype) in layout.leaf_shapes().items():
+        assert pages[leaf].shape == (3, rows, lanes) and lanes == 128
+        assert pages[leaf].dtype == dtype and rows % (32 // dtype.itemsize) == 0
+    back = cache_lib.pages_to_chunks(layout, pages)
+    assert back.keys() == fields.keys()
+    for name, x in fields.items():
+        assert back[name].dtype == x.dtype and back[name].shape == x.shape
+        np.testing.assert_array_equal(
+            np.asarray(back[name]).view(np.uint8),
+            np.asarray(x).view(np.uint8), err_msg=name)
+
+
+def test_page_bytes_at_the_served_geometry():
+    """starcoder2-3b's GEAR layer (2 KV heads of 128, chunks of 64,
+    GEAR-KCVT-4bit): a layer-page costs no more than the per-field layout's
+    30,208 B, and the benchmark cell's ``pool_bytes`` over 30 layers still
+    gives the 513 pages its 16 slots x 2048 tokens need."""
+    from repro.configs import get_config
+    cfg = get_config("starcoder2-3b")
+    ccfg = cache_cfg_for(cfg, "global", named_policy("gear_kcvt4"), 1, 2048)
+    nbytes = cache_lib.page_nbytes(ccfg)
+    assert nbytes <= 30_208
+    assert cache_lib.page_field_shapes(ccfg) == {
+        "codes": ((32, 128), jnp.dtype(jnp.int32)),
+        "side": ((48, 128), jnp.dtype(jnp.bfloat16))}
+    n_pages = 463_994_880 // (cfg.num_layers * nbytes) + 1   # Engine's sizing
+    assert n_pages >= 16 * 2048 // 64 + 1 == 513

@@ -7,21 +7,26 @@ split lanes.  These tests compile each kernel of the serving path for a
 (head_dim 64, 36 kv heads) and head_dim 128 — with the GEAR-KCVT-4bit cache
 geometry of an 8-slot, 1088-token engine, and the decode kernels also at
 starcoder2-3b's GQA shape (12 query rows per kv head, 2 kv heads of 128,
-16 slots of 2048 tokens).
+16 slots of 2048 tokens).  One more test compiles the whole served paged
+decode step at starcoder2-3b's widths and checks that XLA copies no pool
+leaf around the kernel.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and pytest-xdist
 workers all import this file.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.cache import CacheConfig, init_layer_cache, page_field_shapes
+from repro.core.cache import (CacheConfig, init_layer_cache, page_field_shapes,
+                              page_layout)
 from repro.core.outlier import outlier_count
 from repro.core.policy import named_policy
 from repro.kernels.flash_prefill import flash_prefill, flash_prefill_block
@@ -113,18 +118,16 @@ def test_gear_decode_paged_compiles(one_chip, dh, heads, g, slots, capacity):
     cfg = CacheConfig(batch=1, kv_heads=heads, head_dim=dh,
                       capacity=capacity, policy=POL)
     pages = slots * cfg.n_chunks + 1
-    pool = {n: None if spec is None
-            else jax.ShapeDtypeStruct((pages,) + spec[0], spec[1])
-            for n, spec in page_field_shapes(cfg).items()}
-    head, extras = _split(_flat_operands(pool, pages * heads, one_chip))
+    pool = {n: jax.ShapeDtypeStruct((pages,) + shape, dtype, sharding=one_chip)
+            for n, (shape, dtype) in page_field_shapes(cfg).items()}
     bh = slots * heads
     q = jax.ShapeDtypeStruct((bh, g, dh), jnp.float32, sharding=one_chip)
-    n_comp = jax.ShapeDtypeStruct((bh,), jnp.int32, sharding=one_chip)
+    n_comp = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
     tables = jax.ShapeDtypeStruct((slots, cfg.n_chunks), jnp.int32,
                                   sharding=one_chip)
-    _compile(lambda q, head, n, bt, extras: gear_decode_paged(
-        q, *head, n, bt, bits=POL.bits, chunk=NB, scale_factor=dh**-0.5,
-        **extras), q, head, n_comp, tables, extras)
+    _compile(lambda q, codes, side, n, bt: gear_decode_paged(
+        q, codes, side, n, bt, layout=page_layout(cfg),
+        scale_factor=dh**-0.5), q, pool["codes"], pool["side"], n_comp, tables)
 
 
 @pytest.mark.parametrize("kind", ORIENTATIONS)
@@ -152,3 +155,47 @@ def test_flash_prefill_block_compiles(one_chip, dh, heads):
     n = jax.ShapeDtypeStruct((heads,), jnp.int32, sharding=one_chip)
     _compile(lambda q, k, v, n: flash_prefill_block(q, k, v, n,
                                                     scale=dh**-0.5), s, s, s, n)
+
+
+def _pool_copies(text: str, pages: int) -> list[str]:
+    """HLO lines of copies whose shape has a dimension of ``pages``."""
+    out = []
+    for line in text.splitlines():
+        m = re.search(r"= \(?(\w+\[[\d,]*\])[^=]*? (copy|copy-start)\(", line)
+        if m and str(pages) in m.group(1)[m.group(1).index("[") + 1:-1].split(","):
+            out.append(line.strip()[:160])
+    return out
+
+
+def test_paged_decode_step_copies_no_pool_leaf(one_chip, monkeypatch):
+    """The served paged decode step at starcoder2-3b's widths (2 layers, 16
+    slots x 2048 tokens, a 513-page pool, GEAR-KCVT-4bit), compiled for a
+    v5e with the kernel path: no copy in the compiled program has a pool
+    leaf's page axis in its shape.  A pool stored in another layout than
+    the one ``gear_decode_paged`` reads is relaid out, page axis and all,
+    before every layer's kernel call."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models.model import build_model
+    from repro.models.transformer import decode_tokens, init_caches
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("starcoder2-3b"), num_layers=2)
+    slots, capacity, pages = 16, 2048, 513
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    params = jax.tree.map(like, jax.eval_shape(
+        build_model(cfg).init, jax.random.PRNGKey(0)))
+    caches = jax.tree.map(like, jax.eval_shape(lambda: init_caches(
+        cfg, POL, slots, capacity, layout="paged", pool_pages=pages)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+
+    def gear_decode_step(p, tok, caches, pos, bt):
+        return decode_tokens(cfg, p, tok, caches, pos, POL, capacity,
+                             fused="auto", block_tables=bt)
+
+    text = jax.jit(gear_decode_step, donate_argnums=(2,)).lower(
+        params, {"tokens": i32(slots, 1)}, caches, i32(slots),
+        i32(slots, capacity // NB)).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") >= 1
+    assert _pool_copies(text, pages) == []
