@@ -53,7 +53,7 @@ def drift_curves(cfg, model, params, policies: dict, gen: int = 24, prompt: int 
 def _rollout(cfg, model, params, batch, policy, gen):
     eng = Engine(model, params, EngineConfig(batch=batch["tokens"].shape[0],
                                              capacity=128, policy=policy))
-    logits, caches = eng.prefill(batch)
+    logits, caches = model.prefill(params, batch, policy, eng._cap())
     tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
     toks, logit_list = [tok], [logits[:, -1]]
     pos = batch["tokens"].shape[1]

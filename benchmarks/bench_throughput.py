@@ -1,24 +1,17 @@
-"""Paper Fig 3a (time breakdown) + Fig 3c (throughput) + scheduler modes.
+"""Paper Fig 3c (throughput) + fused-vs-XLA decode-attend + smokes.
 
-Wall-clock GPU throughput is not reproducible on CPU, so this bench reports
-BOTH:
+Wall-clock GPU throughput is not reproducible on CPU, so this bench reports:
   (1) the roofline-model predicted decode throughput — decode on a V100 is
       HBM-bandwidth-bound, so tokens/s ≈ batch / ((weights + batch·KV)/BW);
       GEAR's gain comes from the larger feasible batch at equal memory —
       exactly the mechanism behind the paper's 2.1×–5.07×;
-  (2) measured CPU-relative step times for the compression components
-      (Fig 3a): quantization / low-rank / sparse vs model forward;
-  (3) wave vs slot-level continuous batching on a mixed-length workload —
-      relative tokens/s of the two scheduler modes (CPU-relative but the
-      ratio is scheduling-structural: waves decode every slot to the wave's
-      max budget, continuous splices the next request the moment a slot
-      frees).  ``--smoke --json`` runs (3) + (4) for the CI artifact;
-  (4) fused vs XLA decode-attend on the same mixed-budget continuous
-      workload (``--fused`` runs only this) — the ragged fused ``gear_attend``
-      path against the portable jnp ``cache.attend`` path.  On CPU the fused
-      path runs the jnp oracle, so the number is layout-relative only; on
-      TPU it is the Pallas kernel and the gap is the paper's fused-dequant
-      decode win.
+  (2) fused vs XLA decode-attend on a mixed-budget continuous-batching
+      workload (``--smoke`` / ``--fused`` run only this) — the ragged fused
+      ``gear_attend`` path against the portable jnp ``cache.attend`` path.
+      On CPU the fused path runs the jnp oracle, so the number is
+      layout-relative only; on TPU it is the Pallas kernel and the gap is
+      the paper's fused-dequant decode win.
+``--chaos`` and ``--obs`` run the resilience and telemetry smokes.
 
 Rows that the CI regression gate (benchmarks/check_regression.py) diffs
 against benchmarks/baseline.json carry a machine-readable ``value``.
@@ -27,16 +20,13 @@ against benchmarks/baseline.json carry a machine-readable ``value``.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, kv_like, timeit
-from benchmarks.bench_memory import kv_bytes_per_seq, max_batch, N_IN, N_GEN, GB
+from benchmarks.common import emit
+from benchmarks.bench_memory import kv_bytes_per_seq, max_batch, N_IN, N_GEN
 from repro.configs import get_config, smoke_config
-from repro.core import gear, lowrank, outlier, quant
 from repro.core.policy import FP16, named_policy
 from repro.models.model import build_model
 from repro.serving.engine import Engine, EngineConfig
@@ -64,52 +54,6 @@ def fig3c(cfg):
     return ratio
 
 
-def fig3a_breakdown(key):
-    """Component timings of one compression event (CPU-relative)."""
-    x = kv_like(key, (1, 8, 64, 128))
-    pol = named_policy("gear_kivi2")
-    scheme, group = pol.scheme_for("k")
-    t_quant = timeit(lambda: quant.dequantize(quant.quantize(x, 2, scheme, group)))
-    t_low = timeit(lambda: lowrank.power_iteration(x, 4, 4))
-    t_sparse = timeit(lambda: outlier.filter_outliers(x, 0.02, "token"))
-    # model forward step for scale (small model decode)
-    cfg = smoke_config("llama2-7b")
-    m = build_model(cfg)
-    params = m.init(key)
-    eng = Engine(m, params, EngineConfig(
-        batch=1, capacity=96, policy=dataclasses.replace(pol, buffer_size=16, group=16)))
-    batch = {"tokens": jnp.zeros((1, 24), jnp.int32)}
-    _, caches = eng.prefill(batch)
-    tok = {"tokens": jnp.zeros((1, 1), jnp.int32)}
-    t_fwd = timeit(lambda: eng.decode(tok, eng.init_caches(), 24))
-    total = t_quant + t_low + t_sparse + t_fwd
-    for name, t in (("quant", t_quant), ("lowrank", t_low), ("sparse", t_sparse),
-                    ("forward_other", t_fwd)):
-        emit(f"fig3a_breakdown/{name}", t, f"{100*t/total:.1f}%")
-    return {"quant": t_quant, "lowrank": t_low, "sparse": t_sparse, "fwd": t_fwd}
-
-
-def cpu_relative_decode(key):
-    """Measured CPU decode step: fp16 vs GEAR caches (relative only)."""
-    cfg = smoke_config("llama2-7b")
-    m = build_model(cfg)
-    params = m.init(key)
-    batch = {"tokens": jax.random.randint(key, (2, 24), 0, cfg.vocab_size)}
-    times = {}
-    for name, pol in (("fp16", FP16),
-                      ("gear4", dataclasses.replace(named_policy("gear_kcvt4"),
-                                                    buffer_size=16))):
-        eng = Engine(m, params, EngineConfig(batch=2, capacity=96, policy=pol))
-        _, caches = eng.prefill(batch)
-        tok = {"tokens": jnp.zeros((2, 1), jnp.int32)}
-        eng.decode(tok, caches, 24)  # compile
-        _, caches = eng.prefill(batch)
-        times[name] = timeit(lambda c=caches: eng._decode(eng.params, tok, c, 24),
-                             iters=1, warmup=0)
-        emit(f"cpu_decode_us/{name}", times[name], "CPU-relative only")
-    return times
-
-
 def _mixed_requests(n_reqs: int, max_prompt: int, vocab: int, seed: int = 0):
     """Mixed-length synthetic workload: raw prompt lengths 4..max_prompt
     (no scheduler padding), budgets cycling 8..64."""
@@ -120,47 +64,6 @@ def _mixed_requests(n_reqs: int, max_prompt: int, vocab: int, seed: int = 0):
                     tokens=rng.randint(1, vocab, size=rng.randint(4, max_prompt + 1)),
                     max_new_tokens=budgets[i % len(budgets)])
             for i in range(n_reqs)]
-
-
-def wave_vs_continuous(key, n_reqs: int = 12, batch: int = 4):
-    """Tokens/s of wave vs slot-level continuous batching (same workload)."""
-    from repro.serving.scheduler import Scheduler
-    cfg = smoke_config("llama2-7b")
-    m = build_model(cfg)
-    params = m.init(key)
-    pol = dataclasses.replace(named_policy("gear_kcvt4"),
-                              buffer_size=16, rank=2, rank_decode=2)
-    max_prompt = 16
-    eng = Engine(m, params, EngineConfig(batch=batch, capacity=96, policy=pol,
-                                         eos_id=-1))
-
-    def drive(mode: str) -> float:
-        sched = Scheduler(eng)
-        for r in _mixed_requests(n_reqs, max_prompt, cfg.vocab_size):
-            sched.submit(r)
-        t0 = time.time()
-        results = getattr(sched, mode)()
-        wall = time.time() - t0
-        return sum(len(r.tokens) for r in results) / wall
-
-    out = {}
-    for mode, tag in (("run", "wave"), ("run_continuous", "continuous")):
-        # warmup drives the IDENTICAL workload (same seed): prompts are
-        # raw-length now, so every distinct prompt length is its own jit
-        # prefill program and all of them must compile before timing
-        drive(mode)
-        out[tag] = drive(mode)
-        emit(f"throughput_sched/{tag}", 0.0, f"tok_per_s={out[tag]:.1f}",
-             value=out[tag])
-    ratio = out["continuous"] / out["wave"]
-    emit("throughput_sched/continuous_over_wave", 0.0,
-         f"{ratio:.2f}x (mixed budgets 8-64, batch={batch}, n={n_reqs})",
-         value=ratio)
-    nbytes = Engine.cache_nbytes(eng.init_caches())
-    emit("cache_nbytes/bench_engine_gear", 0.0,
-         f"{nbytes} bytes (batch={batch}, cap={eng._cap()})", value=nbytes)
-    assert ratio >= 1.0, f"continuous batching slower than waves: {ratio:.2f}x"
-    return ratio
 
 
 def fused_vs_xla(key, n_reqs: int = 8, batch: int = 4):
@@ -196,6 +99,9 @@ def fused_vs_xla(key, n_reqs: int = 8, batch: int = 4):
         out[tag] = tok_s
         emit(f"throughput_fused/decode_tok_per_s_{tag}", 0.0,
              f"{tok_s:.1f} tok/s attend_path={path}", value=tok_s)
+    nbytes = Engine.cache_nbytes(eng.init_caches())
+    emit("cache_nbytes/bench_engine_gear", 0.0,
+         f"{nbytes} bytes (batch={batch}, cap={eng._cap()})", value=nbytes)
     ratio = out["fused"] / out["xla"]
     emit("throughput_fused/fused_over_xla", 0.0,
          f"{ratio:.2f}x (CPU oracle vs XLA attend; on TPU = Pallas kernel)",
@@ -368,17 +274,11 @@ def obs_smoke(key, n_reqs: int = 8, batch: int = 4):
 
 def run(key=None, smoke: bool = False, fused_only: bool = False):
     key = key if key is not None else jax.random.PRNGKey(0)
-    if fused_only:
-        return fused_vs_xla(key)
-    sched_ratio = wave_vs_continuous(key)
-    fused_vs_xla(key)
-    if smoke:
-        return sched_ratio
-    cfg = get_config("llama2-7b")
-    ratio = fig3c(cfg)
+    fused_ratio = fused_vs_xla(key)
+    if smoke or fused_only:
+        return fused_ratio
+    ratio = fig3c(get_config("llama2-7b"))
     assert 1.5 < ratio < 8.0, ratio
-    fig3a_breakdown(key)
-    cpu_relative_decode(key)
     return ratio
 
 
@@ -387,7 +287,7 @@ if __name__ == "__main__":
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="scheduler + fused-attend comparisons only")
+                    help="the fused-vs-XLA decode-attend comparison only")
     ap.add_argument("--fused", action="store_true",
                     help="only the fused-vs-XLA decode-attend comparison")
     ap.add_argument("--chaos", action="store_true",

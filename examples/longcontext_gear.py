@@ -6,13 +6,14 @@ under the same byte budget, and watch compression events stream.
 import dataclasses
 
 import jax
-import jax.numpy as jnp
+import numpy as np
 
 from repro.configs import smoke_config
-from repro.core.policy import FP16, named_policy
+from repro.core.policy import named_policy
 from repro.core import metrics
 from repro.models.model import build_model
 from repro.serving.engine import Engine, EngineConfig
+from repro.serving.scheduler import Request, Scheduler
 
 
 def main():
@@ -22,10 +23,15 @@ def main():
     pol = dataclasses.replace(named_policy("gear_kivi2"), buffer_size=16, group=16)
 
     eng = Engine(model, params, EngineConfig(batch=1, capacity=512, policy=pol))
-    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (1, 64), 0,
-                                          cfg.vocab_size)}
-    toks, stats = eng.generate(batch, 128)
-    print(f"generated {toks.shape[1]} tokens; cache {stats['cache_bytes']/1e6:.2f} MB")
+    sched = Scheduler(eng)
+    prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (64,), 0,
+                                           cfg.vocab_size))
+    sched.submit(Request(rid=0, tokens=prompt, max_new_tokens=128))
+    (res,) = sched.run_continuous()
+    # the cache tree's size is fixed when the engine is built: a fresh tree
+    # holds exactly the bytes of the one the request was served from
+    nbytes = Engine.cache_nbytes(eng.init_caches())
+    print(f"generated {len(res.tokens)} tokens; cache {nbytes/1e6:.2f} MB")
 
     frac = metrics.kv_size_fraction(pol, 512, cfg.num_kv_heads * cfg.head_dim,
                                     num_heads=cfg.num_kv_heads, head_dim=cfg.head_dim)

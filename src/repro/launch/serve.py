@@ -1,14 +1,14 @@
-"""Serving launcher: batched generation with a GEAR-compressed cache.
+"""Serving launcher: mixed-length requests through a GEAR-compressed cache.
 
   PYTHONPATH=src python -m repro.launch.serve --arch llama2-7b --smoke \
       --policy gear_kcvt4 --batch 4 --prompt 64 --gen 32
 
-Built entirely on the public :mod:`repro.serving` API.  ``--mode wave``
-drives :meth:`Engine.generate` lockstep; ``--mode continuous`` submits
-per-prompt :class:`Request` objects to :class:`Scheduler.run_continuous`.
-``--layout paged`` serves from the pooled compressed-chunk page layout
-(continuous mode only — pages are reserved per request, so concurrency is
-pool-bytes-limited instead of slot-count-limited).
+Built entirely on the public :mod:`repro.serving` API: submits per-prompt
+:class:`Request` objects to :meth:`Scheduler.run_continuous` (slot-level
+continuous batching over ``--batch`` slots).  ``--layout paged`` serves
+from the pooled compressed-chunk page layout (pages are reserved per
+request, so concurrency is pool-bytes-limited instead of
+slot-count-limited).
 """
 
 from __future__ import annotations
@@ -19,7 +19,6 @@ import os
 from pathlib import Path
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, smoke_config
@@ -61,13 +60,12 @@ def main():
     ap.add_argument("--buffer", type=int, default=0, help="override n_b")
     ap.add_argument("--mesh", default="")
     ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--mode", default="wave", choices=["wave", "continuous"])
     ap.add_argument("--layout", default="dense", choices=["dense", "paged"])
     ap.add_argument("--pool-bytes", type=int, default=0,
                     help="paged: pool device-byte budget (default: dense-"
                          "equivalent batch*n_chunks pages)")
     ap.add_argument("--requests", type=int, default=0,
-                    help="continuous: queued requests (default 2*batch)")
+                    help="queued requests (default 2*batch)")
     ap.add_argument("--obs", action="store_true",
                     help="enable serving telemetry (metrics + traces)")
     ap.add_argument("--fidelity-every", type=int, default=0,
@@ -89,17 +87,13 @@ def main():
         pol = dataclasses.replace(pol, buffer_size=args.buffer,
                                   group=min(pol.group, args.buffer))
     layout = CacheLayout(args.layout)
-    if layout is CacheLayout.PAGED and args.mode == "wave":
-        args.mode = "continuous"   # paged serves through continuous batching
-    if args.obs and args.mode == "wave":
-        args.mode = "continuous"   # traces span the request lifecycle
     mesh = None
     if args.mesh:
         dims = [int(v) for v in args.mesh.split("x")]
         mesh = make_test_mesh(*dims)
 
     params = model.init(jax.random.PRNGKey(0))
-    cap = args.prompt + args.gen + (cfg.num_prefix_tokens if cfg.modality == "vlm" else 0)
+    cap = args.prompt + args.gen
     obs_cfg = (ObsConfig(fidelity_every_n=max(args.fidelity_every, 0))
                if args.obs else None)
     eng = Engine(model, params,
@@ -109,57 +103,39 @@ def main():
                  mesh=mesh)
     key = jax.random.PRNGKey(1)
 
-    if args.mode == "continuous":
-        if cfg.modality != "text":
-            raise SystemExit("continuous mode drives text tokens")
-        sched = Scheduler(eng)
-        n_req = args.requests or 2 * args.batch
-        # genuinely mixed-length raw prompts (--prompt is the longest): the
-        # engine length-buckets each one internally, no scheduler padding
-        lo = max(1, args.prompt // 2)
-        for rid in range(n_req):
-            plen = lo + rid % (args.prompt - lo + 1)
-            toks = np.asarray(jax.random.randint(
-                jax.random.fold_in(key, rid), (plen,), 0, cfg.vocab_size))
-            sched.submit(Request(rid=rid, tokens=toks, max_new_tokens=args.gen))
-        results = sched.run_continuous()
-        st = sched.last_stats
-        line = (f"served {len(results)} requests ({st['tokens']} tokens) in "
-                f"{st['wall_s']:.2f}s; attend={st['attend_path']} "
-                f"layout={st['layout']}")
-        if "pool" in st:
-            p = st["pool"]
-            line += (f"; pool {p['used_pages']}/{p['used_pages'] + p['free_pages']}"
-                     f" pages used, {p['shared_pages']} shared")
+    sched = Scheduler(eng)
+    n_req = args.requests or 2 * args.batch
+    # genuinely mixed-length raw prompts (--prompt is the longest): the
+    # engine length-buckets each one internally, no scheduler padding
+    lo = max(1, args.prompt // 2)
+    for rid in range(n_req):
+        plen = lo + rid % (args.prompt - lo + 1)
+        toks = np.asarray(jax.random.randint(
+            jax.random.fold_in(key, rid), (plen,), 0, cfg.vocab_size))
+        sched.submit(Request(rid=rid, tokens=toks, max_new_tokens=args.gen))
+    results = sched.run_continuous()
+    st = sched.last_stats
+    line = (f"served {len(results)} requests ({st['tokens']} tokens) in "
+            f"{st['wall_s']:.2f}s; attend={st['attend_path']} "
+            f"layout={st['layout']}")
+    if "pool" in st:
+        p = st["pool"]
+        line += (f"; pool {p['used_pages']}/{p['used_pages'] + p['free_pages']}"
+                 f" pages used, {p['shared_pages']} shared")
+    print(line)
+    if eng.obs is not None:
+        cov = eng.obs.tracer.coverage([r.rid for r in results])
+        line = (f"obs: traces {len(cov['statuses'])}/{len(results)} rids"
+                f" complete={cov['complete']}")
+        if eng.obs.fidelity is not None:
+            line += f", fidelity probes {len(eng.obs.fidelity.reports)}"
         print(line)
-        if eng.obs is not None:
-            cov = eng.obs.tracer.coverage([r.rid for r in results])
-            line = (f"obs: traces {len(cov['statuses'])}/{len(results)} rids"
-                    f" complete={cov['complete']}")
-            if eng.obs.fidelity is not None:
-                line += f", fidelity probes {len(eng.obs.fidelity.reports)}"
-            print(line)
-            if args.metrics_json:
-                eng.obs.write_metrics_json(args.metrics_json)
-                print(f"obs: metrics snapshot -> {args.metrics_json}")
-            if args.trace_out:
-                eng.obs.write_trace(args.trace_out)
-                print(f"obs: chrome trace -> {args.trace_out}")
-        return
-
-    if cfg.modality == "audio":
-        batch = {"tokens": jax.random.randint(key, (args.batch, args.prompt,
-                                                    cfg.num_codebooks), 0, cfg.vocab_size)}
-    elif cfg.modality == "vlm":
-        batch = {"tokens": jax.random.randint(key, (args.batch, args.prompt), 0, cfg.vocab_size),
-                 "img_embeds": jax.random.normal(key, (args.batch, cfg.num_prefix_tokens,
-                                                       cfg.d_model), jnp.bfloat16)}
-    else:
-        batch = {"tokens": jax.random.randint(key, (args.batch, args.prompt), 0, cfg.vocab_size)}
-    toks, stats = eng.generate(batch, args.gen)
-    print(f"generated {toks.shape}; prefill {stats['prefill_s']:.2f}s, "
-          f"decode {stats['decode_s']:.2f}s ({stats['decode_tok_per_s']:.1f} tok/s), "
-          f"cache {stats['cache_bytes']/1e6:.1f} MB")
+        if args.metrics_json:
+            eng.obs.write_metrics_json(args.metrics_json)
+            print(f"obs: metrics snapshot -> {args.metrics_json}")
+        if args.trace_out:
+            eng.obs.write_trace(args.trace_out)
+            print(f"obs: chrome trace -> {args.trace_out}")
 
 
 if __name__ == "__main__":

@@ -7,8 +7,9 @@ from here rather than from the submodules:
 * :class:`Engine` / :class:`EngineConfig` with the typed knobs
   :class:`AttendPath`, :class:`PrefillMode`, :class:`CacheLayout`
   (plain strings still coerce, as a deprecation shim);
-* :class:`Scheduler` with :class:`Request` / :class:`Result` — wave and
-  continuous batching;
+* :class:`Scheduler` with :class:`Request` / :class:`Result` — slot-level
+  continuous batching (:meth:`Scheduler.run_continuous`), the one way the
+  engine serves requests;
 * :class:`CacheView` (:class:`DenseCacheView` / :class:`PagedCacheView`)
   — the slot-protocol facade the scheduler drives;
 * the paged pool primitives (:class:`PagePool`, :class:`PagePoolStore`,

@@ -2,14 +2,13 @@
 
 The engine owns the jitted prefill/decode programs (cache donated across
 steps so decode is allocation-free), token sampling, and the byte-level
-cache accounting the memory benchmarks read.  Two batching modes sit on
-top (:mod:`repro.serving.scheduler`):
-
-* wave mode — :meth:`Engine.generate` drives the whole batch in lockstep;
-* continuous mode — the scheduler drives :meth:`Engine.decode` one step at
-  a time with per-slot position vectors, and :meth:`Engine.prefill_slot`
-  splices a fresh request's batch-1 cache into a live batch slot (the cache
-  tree is donated, so the splice is an in-place batch-row write).
+cache accounting the memory benchmarks read.  It serves text models
+(token-id prompts) through one batching mode, slot-level continuous
+batching (:meth:`repro.serving.scheduler.Scheduler.run_continuous`): the
+scheduler drives :meth:`Engine.decode` one step at a time with per-slot
+position vectors, and :meth:`Engine.prefill_slot` splices a fresh request's
+batch-1 cache into a live batch slot (the cache tree is donated, so the
+splice is an in-place batch-row write).
 
 Two cache layouts (:class:`CacheLayout`):
 
@@ -30,7 +29,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import enum
-import time
 from typing import Any
 
 import jax
@@ -239,6 +237,10 @@ def prefix_cache_unsupported_reason(cfg, policy: CompressionPolicy,
 class Engine:
     def __init__(self, model: Model, params: Any, ecfg: EngineConfig, mesh=None,
                  clock=None):
+        if model.cfg.modality != "text":
+            raise ValueError(
+                f"modality {model.cfg.modality!r} cannot be served: the engine "
+                "serves token-id prompts through Scheduler.run_continuous")
         self.model = model
         self.cfg = model.cfg
         self.ecfg = ecfg
@@ -382,10 +384,10 @@ class Engine:
         # streaming pipeline discarded; the probe reads each sampled
         # request's batch-1 tree BEFORE the donating splice, so it can
         # never perturb serving state (probe-parity sweep in
-        # tests/test_cache.py).  GEAR engines on text models only — other
-        # modalities/policies have nothing to compare.
+        # tests/test_cache.py).  GEAR engines only — an fp16 policy has
+        # nothing to compare.
         if (self.obs is not None and ecfg.obs.fidelity_every_n > 0
-                and not ecfg.policy.is_fp16 and self.cfg.modality == "text"):
+                and not ecfg.policy.is_fp16):
             def gear_fidelity_prefill(p, b):
                 return model.prefill(p, b, FP16, cap)
             ref_jit = jax.jit(gear_fidelity_prefill)
@@ -647,17 +649,6 @@ class Engine:
         with self.mesh_context():
             return self._decode.lower(*args).compile().as_text()
 
-    def prefill(self, batch: dict):
-        if self.layout is CacheLayout.PAGED:
-            raise NotImplementedError(
-                "full-batch wave prefill is dense-only; paged engines serve "
-                "through Engine.prefill_slot / Scheduler.run_continuous")
-        with self.mesh_context():
-            logits, caches = self._prefill(self.params, batch)
-        if self._cache_shard is not None:
-            caches = jax.device_put(caches, self._cache_shard)
-        return logits, caches
-
     def _cold_prefill(self, batch1: dict):
         """Batch-1 prompt prefill at bucketed length.
 
@@ -790,7 +781,7 @@ class Engine:
     def _prefill_slot_paged(self, batch1, caches, slot, admit, reserve_tokens):
         nb = self.ecfg.policy.buffer_size
         cap = self._cap()
-        plen = self._prompt_len(batch1)
+        plen = int(batch1["tokens"].shape[1])
         n_closed = plen // nb                 # chunks the prompt closes
         reserve = cap if reserve_tokens is None else min(int(reserve_tokens), cap)
         n_total = max(pages_needed(max(reserve, plen), nb), n_closed)
@@ -1000,71 +991,6 @@ class Engine:
             return 0
         freed = self.prefix_cache.evict_bytes(n_pages * self.pool.page_bytes)
         return freed // self.pool.page_bytes
-
-    # ------------------------------------------------------------------
-    def generate(self, batch: dict, max_new_tokens: int, key=None, active=None):
-        """Greedy/sampled wave generation.  Returns (tokens [B, T], stats).
-
-        ``active``: optional bool mask [B] of slots holding real requests;
-        padded copy slots are excluded from the throughput accounting.
-        Dense-layout only — paged engines serve through continuous batching
-        (:meth:`repro.serving.scheduler.Scheduler.run_continuous`).
-        """
-        key = key if key is not None else jax.random.PRNGKey(0)
-        cfg, ecfg = self.cfg, self.ecfg
-        t0 = time.time()
-        logits, caches = self.prefill(batch)
-        t_prefill = time.time() - t0
-        prompt_len = self._prompt_len(batch)
-        B = logits.shape[0]
-
-        tok, key = self.sample_next(logits, key)
-        out = [tok]
-        done = jnp.zeros(tok.shape[:1], bool)
-        t1 = time.time()
-        for t in range(max_new_tokens - 1):
-            tb = {"tokens": tok[:, None] if cfg.modality != "audio" else tok[:, None, :]}
-            # per-slot position vector: the same decode program serves the
-            # continuous-batching path, where positions genuinely differ.
-            pos = jnp.full((B,), prompt_len + t, jnp.int32)
-            logits, caches = self.decode(tb, caches, pos)
-            tok, key = self.sample_next(logits, key, t)
-            if ecfg.eos_id >= 0:
-                done = done | (tok == ecfg.eos_id) if cfg.modality != "audio" else done
-                tok = jnp.where(done, ecfg.eos_id, tok) if cfg.modality != "audio" else tok
-            out.append(tok)
-            if ecfg.eos_id >= 0 and bool(done.all()):
-                break
-        toks = jnp.stack(out, axis=1)
-        t_decode = time.time() - t1
-        stats = {
-            "prefill_s": t_prefill,
-            "decode_s": t_decode,
-            "decode_tok_per_s": self._decode_tok_per_s(toks, t_decode, active),
-            "cache_bytes": self.cache_nbytes(caches),
-        }
-        return toks, stats
-
-    def _decode_tok_per_s(self, toks, t_decode: float, active) -> float:
-        """Decode throughput over USEFUL tokens only: padded copy slots
-        (``active`` False) and post-EOS / early-exit filler are excluded, so
-        bench numbers aren't inflated by throwaway work."""
-        tnp = np.asarray(toks)
-        B, T = tnp.shape[0], tnp.shape[1]
-        act = np.ones(B, bool) if active is None else np.asarray(active, bool)
-        n_use = np.full(B, T)
-        if self.ecfg.eos_id >= 0 and self.cfg.modality != "audio":
-            hit = tnp == self.ecfg.eos_id
-            has = hit.any(axis=1)
-            n_use[has] = hit.argmax(axis=1)[has] + 1  # keep the EOS itself
-        useful_decode = int(np.maximum(n_use - 1, 0)[act].sum())  # 1st tok = prefill
-        return useful_decode / max(t_decode, 1e-9)
-
-    def _prompt_len(self, batch) -> int:
-        n = batch["tokens"].shape[1]
-        if self.cfg.modality == "vlm":
-            n += self.cfg.num_prefix_tokens
-        return n
 
     def init_caches(self):
         if self.layout is CacheLayout.PAGED:

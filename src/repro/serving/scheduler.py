@@ -1,44 +1,32 @@
-"""Request-level batching scheduler on top of the engine.
+"""Request-level scheduler on top of the engine: slot-level continuous
+batching over the engine's static batch of B *slots*.
 
-Two scheduling modes over the engine's static batch of B *slots*:
+:meth:`Scheduler.run_continuous` runs a step loop that decodes all B slots
+each step with per-slot position/done/budget vectors; the moment a slot's
+request hits its own EOS or budget, the next queued request is spliced into
+that slot (batch-1 prefill → :meth:`Engine.prefill_slot` batch-row write)
+while the other slots keep decoding undisturbed.  Splice isolation — a
+spliced request produces bit-identical greedy tokens to a solo run — is
+guaranteed by the per-slot cache layout and batch-invariant compression
+(see DESIGN.md).  The fused GEAR decode kernel is ragged-aware (per-slot
+masking inside the kernel), so mixed-length batches run the fused
+``gear_attend`` path — ``last_stats["attend_path"]`` reports which path the
+engine compiled.
 
-* :meth:`Scheduler.run` — wave batching: pending requests are padded into
-  fixed-size waves, each wave generates until every member hits EOS or the
-  wave's max budget, then the next wave starts.  Simple, but every slot is
-  held hostage by the slowest request in its wave.
-
-* :meth:`Scheduler.run_continuous` — slot-level continuous batching: a
-  step-loop decodes all B slots each step with per-slot position/done/budget
-  vectors; the moment a slot's request hits its own EOS or budget, the next
-  queued request is spliced into that slot (batch-1 prefill →
-  :meth:`Engine.prefill_slot` batch-row write) while the other slots keep
-  decoding undisturbed.  Splice isolation — a spliced request produces
-  bit-identical greedy tokens to a solo run — is guaranteed by the per-slot
-  cache layout and batch-invariant compression (see DESIGN.md).  Since the
-  fused GEAR decode kernel is ragged-aware (per-slot masking inside the
-  kernel), mixed-length continuous batches run the same fused
-  ``gear_attend`` path as wave mode — ``last_stats["attend_path"]`` reports
-  which path the engine compiled.
-
-Both modes trim each request's results at its own first EOS and report
+Each request's results are trimmed at its own first EOS and carry
 per-request prefill/decode latency.  When the engine has a prefix cache
-(``EngineConfig.prefix_cache``), continuous mode threads the scheduler's
-admission policy into every slot prefill and reports ``prefix_hit_rate`` /
+(``EngineConfig.prefix_cache``), the scheduler threads its admission
+policy into every slot prefill and reports ``prefix_hit_rate`` /
 ``prefill_toks_saved`` in ``last_stats``.
 
-**Raw prompts, no scheduler padding.**  Continuous mode hands each
-request's RAW token list to :meth:`Engine.prefill_slot`: the engine
-length-buckets the prompt up to the next ``n_b`` multiple internally
-(bounding jit recompilation to one program per bucket) while cache
-lengths, logits, and prefix-trie keys all reflect the true length.  The
-trie therefore keys on raw ``n_b``-aligned token chunks, so requests of
-*different* lengths sharing a chunk-aligned prefix (the mixed-length
-shared-system-prompt workload) hit each other's chunks — see
-docs/serving.md and DESIGN.md §4.  Wave mode still left-pads, but only to
-the longest raw prompt *within each wave* (a whole wave shares one prefill
-program); mixed-length waves therefore shift chunk boundaries per wave —
-use continuous mode when prefix reuse or per-request numeric
-reproducibility across batch compositions matters.
+**Raw prompts, no scheduler padding.**  Each request's RAW token list goes
+to :meth:`Engine.prefill_slot`: the engine length-buckets the prompt up to
+the next ``n_b`` multiple internally (bounding jit recompilation to one
+program per bucket) while cache lengths, logits, and prefix-trie keys all
+reflect the true length.  The trie therefore keys on raw ``n_b``-aligned
+token chunks, so requests of *different* lengths sharing a chunk-aligned
+prefix (the mixed-length shared-system-prompt workload) hit each other's
+chunks — see docs/serving.md and DESIGN.md §4.
 """
 
 from __future__ import annotations
@@ -94,10 +82,9 @@ class Scheduler:
     """Request queue + batching policy over one :class:`Engine`.
 
     Construct with the engine and (optionally) the prefix-cache admission
-    policy, :meth:`submit` requests, then drain with :meth:`run` (wave
-    batching) or :meth:`run_continuous` (slot-level continuous batching —
-    the recommended mode; see the module docstring).  Per-run aggregate
-    metrics land in :attr:`last_stats`.
+    policy, :meth:`submit` requests, then drain with :meth:`run_continuous`
+    (slot-level continuous batching; see the module docstring).  Per-run
+    aggregate metrics land in :attr:`last_stats`.
 
     ``prefix_admission`` is threaded to :meth:`Engine.prefill_slot` when
     the engine has a prefix cache: "all" inserts every request's newly
@@ -157,13 +144,11 @@ class Scheduler:
 
     def _need_tokens(self, req: Request) -> int:
         """Cache tokens a request's whole lifetime holds: its raw prompt
-        (+ VLM prefix) plus one appended token per decode step (the first
-        generated token comes from prefill).  True lifetime — paged
-        admission reserves exactly these pages, so shorter prompts really
-        do cost fewer pages."""
-        prefix = (self.engine.cfg.num_prefix_tokens
-                  if self.engine.cfg.modality == "vlm" else 0)
-        return len(req.tokens) + prefix + req.max_new_tokens - 1
+        plus one appended token per decode step (the first generated token
+        comes from prefill).  True lifetime — paged admission reserves
+        exactly these pages, so shorter prompts really do cost fewer
+        pages."""
+        return len(req.tokens) + req.max_new_tokens - 1
 
     def submit(self, req: Request) -> None:
         # A request's whole lifetime must fit the engine's cache capacity:
@@ -229,52 +214,6 @@ class Scheduler:
         return {"ok": not issues, "issues": issues, "engine": eng_report}
 
     # ------------------------------------------------------------------
-    # Wave mode
-    def run(self) -> list[Result]:
-        """Drain the queue in engine-batch-sized waves.
-
-        Each wave shares ONE full-batch prefill program, so its prompts are
-        left-padded to the wave's longest raw prompt.  Left-padding shifts
-        chunk boundaries, so a request's numerics depend on its wave's
-        composition — use :meth:`run_continuous` when per-request
-        reproducibility or prefix-cache reuse matters.
-        """
-        results: list[Result] = self._drain_shed()
-        B = self.engine.ecfg.batch
-        eos = self.engine.ecfg.eos_id
-        t_all = time.time()
-        while self.queue:
-            wave = [self.queue.popleft() for _ in range(min(B, len(self.queue)))]
-            while len(wave) < B:                      # pad with a copy slot
-                wave.append(Request(rid=-1, tokens=wave[0].tokens,
-                                    max_new_tokens=wave[0].max_new_tokens))
-            wave_pad = max(len(r.tokens) for r in wave)
-            prompts = np.stack([_pad(r.tokens, wave_pad) for r in wave])
-            budget = max(r.max_new_tokens for r in wave)
-            toks, stats = self.engine.generate(
-                {"tokens": jnp.asarray(prompts, jnp.int32)}, budget,
-                active=np.array([r.rid >= 0 for r in wave]))
-            toks = np.asarray(toks)
-            for i, r in enumerate(wave):
-                if r.rid < 0:
-                    continue
-                res = Result(
-                    rid=r.rid,
-                    tokens=_truncate_eos(toks[i, : r.max_new_tokens], eos),
-                    prefill_s=stats["prefill_s"],
-                    decode_s=stats["decode_s"])
-                results.append(res)
-                if self.obs is not None:
-                    self.obs.result(str(res.status))
-                    self.obs.tracer.finish(r.rid, str(res.status))
-        self.last_stats = {"wall_s": time.time() - t_all,
-                           "tokens": int(sum(len(r.tokens) for r in results)),
-                           "statuses": dict(Counter(str(r.status)
-                                                    for r in results))}
-        return results
-
-    # ------------------------------------------------------------------
-    # Continuous mode
     def run_continuous(self) -> list[Result]:
         """Drain the queue with slot-level continuous batching.
 
@@ -293,8 +232,6 @@ class Scheduler:
         whatever tokens existed at the cutoff.
         """
         eng = self.engine
-        if eng.cfg.modality == "audio":
-            raise NotImplementedError("continuous batching drives text tokens")
         B = eng.ecfg.batch
         eos = eng.ecfg.eos_id
         key = jax.random.PRNGKey(0)
@@ -493,7 +430,7 @@ class Scheduler:
             reqs[s] = r
             toks_buf[s] = [first]
             cur[s] = first
-            pos[s] = eng._prompt_len({"tokens": prompt})
+            pos[s] = len(r.tokens)
             budget[s] = r.max_new_tokens
             decode_s[s] = 0.0
             done[s] = False
@@ -659,10 +596,3 @@ def _truncate_eos(tokens: np.ndarray, eos_id: int) -> np.ndarray:
     hits = np.nonzero(tokens == eos_id)[0]
     return tokens[: hits[0] + 1] if hits.size else tokens
 
-
-def _pad(tokens: np.ndarray, length: int) -> np.ndarray:
-    """Left-pad (or left-truncate) to ``length`` — wave mode's per-wave
-    prompt alignment; continuous mode sends raw prompts instead."""
-    if len(tokens) >= length:
-        return tokens[-length:]
-    return np.pad(tokens, (length - len(tokens), 0))

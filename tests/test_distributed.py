@@ -203,17 +203,23 @@ def test_serving_on_mesh(tmp_path):
         from repro.models.model import build_model
         from repro.core.policy import named_policy
         from repro.launch.mesh import make_test_mesh
+        import numpy as np
         from repro.serving.engine import Engine, EngineConfig
+        from repro.serving.scheduler import Request, Scheduler
         cfg = smoke_config("minicpm-2b")
         m = build_model(cfg)
         params = m.init(jax.random.PRNGKey(0))
         pol = dataclasses.replace(named_policy("gear_kcvt4"), buffer_size=16)
         mesh = make_test_mesh(2, 2)
-        with mesh:
-            eng = Engine(m, params, EngineConfig(batch=4, capacity=96, policy=pol), mesh=mesh)
-            batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 24), 0, cfg.vocab_size)}
-            toks, stats = eng.generate(batch, 8)
-        assert toks.shape == (4, 8)
+        eng = Engine(m, params, EngineConfig(batch=4, capacity=96, policy=pol), mesh=mesh)
+        sched = Scheduler(eng)
+        prompts = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (4, 24), 0,
+                                                cfg.vocab_size))
+        for rid, toks in enumerate(prompts):
+            sched.submit(Request(rid=rid, tokens=toks, max_new_tokens=8))
+        res = sched.run_continuous()
+        assert sorted(r.rid for r in res) == [0, 1, 2, 3]
+        assert all(len(r.tokens) == 8 for r in res), [len(r.tokens) for r in res]
         print("SERVE_MESH_OK")
     """)
 
@@ -245,7 +251,10 @@ def test_mesh_kernels_run_per_head_shard(data, model):
         engs = {{f: Engine(m, params, EngineConfig(batch=4, capacity=96, policy=pol,
                                                   fused=f), mesh=mesh)
                 for f in ("auto", "interpret")}}
-        _, caches = engs["auto"].prefill(batch)
+        caches = engs["auto"].init_caches()
+        for s in range(4):
+            _, caches = engs["auto"].prefill_slot(
+                {{"tokens": batch["tokens"][s:s + 1]}}, caches, s)
         dec = {{f: np.asarray(e.decode(nxt, jax.tree.map(jnp.copy, caches), 40)[0])
                for f, e in engs.items()}}
         assert engs["interpret"].attend_path == "fused-interpret"

@@ -25,7 +25,6 @@ from repro.serving import (AttendPath, CacheLayout, CacheView, DenseCacheView,
                            Engine, EngineConfig, PagedCacheView, PagePool,
                            PoolExhausted, PrefillMode, Request, Scheduler,
                            pages_needed)
-from repro.serving.scheduler import _pad
 
 EOS = 3
 PROMPT_PAD = 8
@@ -62,6 +61,11 @@ def _requests(n=5, seed=0):
                     tokens=rng.randint(4, 64, size=rng.randint(2, PROMPT_PAD + 1)),
                     max_new_tokens=b)
             for i, b in enumerate(budgets)]
+
+
+def _prompt1() -> np.ndarray:
+    """One batch-1 prompt of exactly PROMPT_PAD tokens."""
+    return np.random.RandomState(0).randint(4, 64, size=(1, PROMPT_PAD))
 
 
 def _run(engine):
@@ -114,7 +118,7 @@ def test_paged_cache_and_logits_bitwise():
     eng_d = Engine(model, params, ecfg)
     eng_p = Engine(model, params, dataclasses.replace(ecfg, layout="paged"))
     cd, cp = eng_d.init_caches(), eng_p.init_caches()
-    prompt = _pad(_requests()[0].tokens, PROMPT_PAD)[None]
+    prompt = _prompt1()
     b1 = {"tokens": jnp.asarray(prompt, jnp.int32)}
     ld, cd = eng_d.prefill_slot(b1, cd, 1)
     lp, cp = eng_p.prefill_slot(b1, cp, 1, reserve_tokens=PROMPT_PAD + 20)
@@ -272,7 +276,7 @@ def test_cache_view_facade():
     assert vd.can_admit(10**9)                 # slot-count-limited
     assert vp.can_admit(CAP) and not vp.can_admit(10**9)
 
-    prompt = _pad(_requests()[0].tokens, PROMPT_PAD)[None]
+    prompt = _prompt1()
     b1 = {"tokens": jnp.asarray(prompt, jnp.int32)}
     lv = vd.prefill_slot(b1, 0)
     caches = eng_d.init_caches()

@@ -1,5 +1,6 @@
-"""Slot-level continuous batching: splice isolation, EOS truncation,
-throughput accounting, cache byte accounting, and pspec legality.
+"""Slot-level continuous batching: splice isolation, agreement with the bare
+model's greedy loop, EOS truncation, cache byte accounting, and pspec
+legality.
 
 The archetype test is splice isolation: a request spliced into a live batch
 mid-decode must produce bit-identical greedy tokens to running it alone —
@@ -20,7 +21,7 @@ from repro.core.outlier import outlier_count
 from repro.core.policy import FP16, named_policy
 from repro.models.model import build_model
 from repro.serving.engine import Engine, EngineConfig
-from repro.serving.scheduler import Request, Scheduler, _pad
+from repro.serving.scheduler import Request, Scheduler
 
 EOS = 3
 PROMPT_PAD = 8
@@ -97,24 +98,44 @@ def test_splice_isolation_bit_identical(kind):
             err_msg=f"{kind}: rid {r.rid} diverged from its solo run")
 
 
-@pytest.mark.parametrize("kind", ["gear", "fp16", "window"])
-def test_wave_and_continuous_agree(kind):
-    """Both scheduling modes return the same per-request greedy tokens.
+def _model_greedy(eng: Engine, req: Request) -> np.ndarray:
+    """The request decoded by a plain batch-1 greedy loop over the bare
+    model (``Model.prefill`` then ``Model.decode_step``, argmax each step),
+    cut at its first EOS: no engine, slot, splice or scheduler involved."""
+    model, pol, cap = eng.model, eng.ecfg.policy, eng._cap()
+    prefill = jax.jit(lambda p, b: model.prefill(p, b, pol, cap))
+    step = jax.jit(lambda p, b, c, pos: model.decode_step(p, b, c, pos, pol,
+                                                          cap))
+    logits, caches = prefill(eng.params,
+                             {"tokens": jnp.asarray(req.tokens[None],
+                                                    jnp.int32)})
+    toks = [int(jnp.argmax(logits[0, -1]))]
+    pos = len(req.tokens)
+    while len(toks) < req.max_new_tokens and toks[-1] != EOS:
+        logits, caches = step(eng.params,
+                              {"tokens": jnp.asarray([[toks[-1]]], jnp.int32)},
+                              caches, jnp.asarray([pos], jnp.int32))
+        toks.append(int(jnp.argmax(logits[0, -1])))
+        pos += 1
+    return np.asarray(toks, np.int32)
 
-    Equal-length prompts: wave mode pads each wave to its longest raw
-    prompt, so only equal lengths give both modes the same prefill
-    program (the mixed-length caveat in the scheduler module docstring).
-    """
+
+@pytest.mark.parametrize("kind", ["gear", "fp16", "window"])
+def test_continuous_matches_model_greedy(kind):
+    """The batch-3 engine's continuous-batched tokens == the bare model's
+    batch-1 greedy loop, request by request: the slot machinery (splice,
+    per-slot positions, reset, EOS bookkeeping) adds nothing to what the
+    model computes."""
     eng, _ = _engines(kind)
-    reqs = _requests(length=6)
-    outs = []
-    for mode in ("run", "run_continuous"):
-        sched = Scheduler(eng)
-        for r in reqs:
-            sched.submit(r)
-        outs.append({r.rid: r.tokens for r in getattr(sched, mode)()})
-    for rid in outs[0]:
-        np.testing.assert_array_equal(outs[0][rid], outs[1][rid])
+    sched = Scheduler(eng)
+    reqs = _requests()
+    for r in reqs:
+        sched.submit(r)
+    out = {r.rid: r.tokens for r in sched.run_continuous()}
+    for r in reqs:
+        np.testing.assert_array_equal(
+            out[r.rid], _model_greedy(eng, r),
+            err_msg=f"{kind}: rid {r.rid} diverged from the model's greedy loop")
 
 
 def test_continuous_per_request_latency_and_budgets():
@@ -195,35 +216,38 @@ def test_decode_dispatches_fused_gear_attend(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Wave-mode satellite fixes
+# EOS truncation
 
 
 def test_wave_results_truncated_at_own_eos():
+    """Each request's result is its uninterrupted token stream cut at its
+    own first EOS (kept), under continuous batching.  An engine that never
+    stops early (``eos_id=-1``) serves the full streams; the stop token is
+    then taken from inside one of them, so at least one request really
+    ends early."""
     eng, _ = _engines("gear")
-    sched = Scheduler(eng)
-    for r in _requests():
-        sched.submit(r)
-    for res in sched.run():
-        assert EOS not in res.tokens[:-1], (
-            f"rid {res.rid} kept tokens after its own EOS")
 
+    def serve(eos_id):
+        e = Engine(eng.model, eng.params,
+                   dataclasses.replace(eng.ecfg, eos_id=eos_id))
+        sched = Scheduler(e)
+        for r in _requests():
+            sched.submit(r)
+        return {res.rid: res.tokens for res in sched.run_continuous()}
 
-def test_decode_tok_per_s_excludes_copy_slots_and_post_eos():
-    eng, _ = _engines("gear")
-    prompt = np.tile(_pad(_requests()[0].tokens, PROMPT_PAD), (3, 1))
-    batch = {"tokens": jnp.asarray(prompt, jnp.int32)}
-    budget = 6
-    eng.generate(batch, budget)   # warmup: keep jit compile out of decode_s
-    toks, stats_all = eng.generate(batch, budget)
-    active = np.array([True, False, False])          # 2 padded copy slots
-    _, stats_one = eng.generate(batch, budget, active=active)
-    # identical prompts => identical decode work, but only 1/3 of it useful
-    assert stats_one["decode_tok_per_s"] < stats_all["decode_tok_per_s"]
-    tnp = np.asarray(toks)
-    hits = np.nonzero(tnp[0] == EOS)[0]
-    n_use = (hits[0] + 1 if hits.size else tnp.shape[1]) - 1
-    assert stats_one["decode_tok_per_s"] == pytest.approx(
-        n_use / stats_one["decode_s"], rel=0.5)
+    full = serve(-1)
+    longest = max(full, key=lambda rid: len(full[rid]))
+    stop = int(full[longest][2])
+    cut = serve(stop)
+    hit = 0
+    for rid, stream in full.items():
+        at = np.nonzero(stream == stop)[0]
+        want = stream[: at[0] + 1] if at.size else stream
+        hit += int(len(want) < len(stream))
+        np.testing.assert_array_equal(cut[rid], want, err_msg=f"rid {rid}")
+        assert stop not in cut[rid][:-1], (
+            f"rid {rid} kept tokens after its own EOS")
+    assert hit, "no request ended early at EOS: the truncation went untested"
 
 
 # ---------------------------------------------------------------------------
@@ -318,7 +342,7 @@ def test_cache_pspecs_legal_and_splice_runs_under_mesh():
                  EngineConfig(batch=2, capacity=48, policy=pol, eos_id=EOS),
                  mesh=mesh)
     caches = eng.init_caches()
-    prompt = _pad(_requests()[0].tokens, PROMPT_PAD)[None]
+    prompt = _requests(length=PROMPT_PAD)[0].tokens[None]
     _, caches = eng.prefill_slot({"tokens": jnp.asarray(prompt, jnp.int32)},
                                  caches, 1)
     tb = {"tokens": jnp.zeros((2, 1), jnp.int32)}
@@ -362,14 +386,12 @@ def test_streaming_and_monolithic_engine_caches_agree():
     cfg, pol = KINDS["gear"]
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    prompt = _pad(_requests()[0].tokens, PROMPT_PAD)[None]
+    prompt = _requests(length=PROMPT_PAD)[0].tokens[None]
     batch = {"tokens": jnp.asarray(prompt, jnp.int32)}
     caches = {}
     for mode in ("monolithic", "streaming"):
-        ecfg = EngineConfig(batch=1, capacity=48, policy=pol, eos_id=-1,
-                            prefill_mode=mode)
-        eng = Engine(model, params, ecfg)
-        _, caches[mode] = eng.prefill(batch)
+        _, caches[mode] = model.prefill(params, batch, pol, 48,
+                                        prefill_mode=mode)
     assert (Engine.cache_nbytes(caches["monolithic"])
             == Engine.cache_nbytes(caches["streaming"]))
     # Leaf-wise bit comparison would be unstable (outlier *selection* is
@@ -386,6 +408,21 @@ def test_streaming_and_monolithic_engine_caches_agree():
             diff = np.abs(np.asarray(m_side) - np.asarray(s_side))
             assert float(diff.mean()) < 0.01         # jitter, not divergence
             assert float((diff > 0.05).mean()) < 0.01
+
+
+@pytest.mark.parametrize("modality,arch", [("vlm", "paligemma-3b"),
+                                           ("audio", "musicgen-medium")])
+def test_engine_refuses_non_text_modality(modality, arch):
+    """The engine serves token-id prompts only: a VLM or audio model is
+    refused when the engine is built, naming its modality."""
+    from repro.configs import smoke_config
+    cfg = smoke_config(arch)
+    assert cfg.modality == modality
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match=f"modality '{modality}'.*run_continuous"):
+        Engine(model, params, EngineConfig(batch=1, capacity=48,
+                                           policy=GEAR_POL))
 
 
 def test_engine_config_rejects_unknown_prefill_mode():
@@ -512,13 +549,16 @@ def test_streaming_engine_falls_back_for_unsupported_layout():
                               group=4, rank=2, rank_decode=2)  # group != chunk
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    prompt = _pad(_requests()[0].tokens, PROMPT_PAD)[None]
-    batch = {"tokens": jnp.asarray(prompt, jnp.int32)}
+    req = Request(rid=0, tokens=_requests(length=PROMPT_PAD)[0].tokens,
+                  max_new_tokens=6)
     outs = {}
     for mode in ("monolithic", "streaming"):
         eng = Engine(model, params, EngineConfig(batch=1, capacity=48,
                                                  policy=pol, eos_id=-1,
                                                  prefill_mode=mode))
-        toks, _ = eng.generate(batch, 6)
-        outs[mode] = np.asarray(toks)
+        sched = Scheduler(eng)
+        sched.submit(req)
+        (res,) = sched.run_continuous()
+        assert len(res.tokens) == 6
+        outs[mode] = res.tokens
     np.testing.assert_array_equal(outs["monolithic"], outs["streaming"])

@@ -26,13 +26,24 @@ def dense_model():
     return cfg, m, params
 
 
+def _serve(eng, prompts, max_new_tokens: int) -> np.ndarray:
+    """Serve each prompt row as one request through continuous batching;
+    the generated tokens, one row per prompt."""
+    sched = Scheduler(eng)
+    for rid, toks in enumerate(np.asarray(prompts)):
+        sched.submit(Request(rid=rid, tokens=toks,
+                             max_new_tokens=max_new_tokens))
+    res = sorted(sched.run_continuous(), key=lambda r: r.rid)
+    return np.stack([r.tokens for r in res])
+
+
 def test_generate_shapes_and_determinism(dense_model):
     cfg, m, params = dense_model
     pol = dataclasses.replace(named_policy("gear_kcvt4"), buffer_size=16)
     eng = Engine(m, params, EngineConfig(batch=2, capacity=96, policy=pol))
-    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0, cfg.vocab_size)}
-    t1, _ = eng.generate(batch, 12)
-    t2, _ = eng.generate(batch, 12)
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0, cfg.vocab_size)
+    t1 = _serve(eng, prompts, 12)
+    t2 = _serve(eng, prompts, 12)
     assert t1.shape == (2, 12)
     assert (t1 == t2).all()  # greedy decode is deterministic
 
@@ -41,27 +52,32 @@ def test_gear_vs_fp16_generation_close(dense_model):
     """4-bit GEAR generation tracks FP16 generation for many steps —
     the error-compounding claim (paper Fig 1b) at small scale."""
     cfg, m, params = dense_model
-    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(2), (2, 24), 0, cfg.vocab_size)}
+    prompts = jax.random.randint(jax.random.PRNGKey(2), (2, 24), 0, cfg.vocab_size)
     pol = dataclasses.replace(named_policy("gear_kcvt4"), buffer_size=16)
     eng_g = Engine(m, params, EngineConfig(batch=2, capacity=96, policy=pol))
     eng_f = Engine(m, params, EngineConfig(batch=2, capacity=96, policy=FP16))
-    tg, _ = eng_g.generate(batch, 10)
-    tf, _ = eng_f.generate(batch, 10)
+    tg = _serve(eng_g, prompts, 10)
+    tf = _serve(eng_f, prompts, 10)
     agree = float((tg == tf).mean())
     assert agree >= 0.5, f"4-bit GEAR diverged too fast: agreement {agree}"
 
 
 def test_gear_cache_smaller_than_fp16(dense_model):
     cfg, m, params = dense_model
-    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(2), (2, 24), 0, cfg.vocab_size)}
+    prompts = jax.random.randint(jax.random.PRNGKey(2), (2, 24), 0, cfg.vocab_size)
     pol = dataclasses.replace(named_policy("gear_kivi2"), buffer_size=16, group=16)
-    eng_g = Engine(m, params, EngineConfig(batch=2, capacity=96, policy=pol))
-    eng_f = Engine(m, params, EngineConfig(batch=2, capacity=96, policy=FP16))
-    _, sg = eng_g.generate(batch, 4)
-    _, sf = eng_f.generate(batch, 4)
+    nbytes = {}
+    for name, p in (("gear", pol), ("fp16", FP16)):
+        eng = Engine(m, params, EngineConfig(batch=2, capacity=96, policy=p))
+        view = eng.new_view()          # the live tree a scheduler serves from
+        for slot, toks in enumerate(np.asarray(prompts)):
+            view.prefill_slot({"tokens": toks[None]}, slot)
+        view.decode({"tokens": jnp.zeros((2, 1), jnp.int32)},
+                    np.full(2, prompts.shape[1], np.int32))
+        nbytes[name] = Engine.cache_nbytes(view.caches)
     # packed int32 carriers count 4 bytes; the bit-level size is what the
     # metrics module reports — structural check only here.
-    assert sg["cache_bytes"] < sf["cache_bytes"]
+    assert nbytes["gear"] < nbytes["fp16"]
 
 
 def test_scheduler_drains_queue(dense_model):
@@ -72,7 +88,7 @@ def test_scheduler_drains_queue(dense_model):
     for i in range(3):
         sched.submit(Request(rid=i, tokens=np.arange(5 + i) % cfg.vocab_size,
                              max_new_tokens=6))
-    res = sched.run()
+    res = sched.run_continuous()
     assert sorted(r.rid for r in res) == [0, 1, 2]
     assert all(r.tokens.shape == (6,) for r in res)
 
